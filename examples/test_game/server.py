@@ -98,7 +98,7 @@ class Avatar(Entity):
 
     # DELIBERATE DEVIATION from the reference: Avatar.go:217-231 keeps
     # every mail forever; under a mail-enabled soak that rides EVERY
-    # migration (measured 400+ KB/avatar, BENCH_NOTES round 5), so this
+    # migration (measured 400+ KB/avatar in round 5), so this
     # server keeps only the newest MAILBOX_CAP mails (see OnGetMails).
     # Class constant so a deploy (or parity audit) can subclass/override
     # it — set very large to approximate keep-everything.

@@ -327,6 +327,8 @@ def cmd_reload(args) -> int:
         print(f"  {name}: freezed")
     configfile = os.path.abspath(args.configfile) if args.configfile else ""
     cfg_argv = ["-configfile", configfile] if configfile else []
+    # Every frozen game has exited (loop above) before any restore spawns:
+    # a chip belongs to one process, and the old game holds it until exit.
     # Spawn ALL restores first, then wait for every tag: the restart cost
     # (interpreter + imports + engine warmup, seconds per game) overlaps
     # instead of serializing, shrinking the window clients must ride out.

@@ -52,9 +52,8 @@ class GameConfig:
     log_file: str = ""
     log_level: str = "info"
     position_sync_interval: float = 0.1  # server→client cadence (read_config.go:328)
-    # Per-game override of [aoi] platform ("" = inherit): on single-client
-    # TPU transports exactly ONE game process may hold the chip — set
-    # aoi_platform=tpu on that game and cpu on the rest.
+    # Per-game override of [aoi] platform ("" = inherit): a chip belongs to
+    # ONE process, so set aoi_platform=tpu on that game and cpu on the rest.
     aoi_platform: str = ""
 
 
@@ -168,10 +167,9 @@ class AOIConfig:
 
     backend: str = "auto"  # auto | xzlist | tpu
     # JAX platform for the batched engine: "auto" keeps jax's default
-    # (the TPU when one is attached). MUST be "cpu" for CPU-only deploys on
-    # TPU-image hosts: the TPU plugin ignores the JAX_PLATFORMS env var, so
-    # a game process would otherwise silently grab the chip (and on
-    # single-client transports, fight other processes for it).
+    # (the TPU when one is attached); "cpu" keeps the game off the chip,
+    # which another process may hold; "tpu" fails at game start when no
+    # TPU is found.
     platform: str = "auto"  # auto | cpu | tpu
     cell_capacity: int = 64
     max_entities: int = 16384  # padded capacity of the batched engine
@@ -211,8 +209,9 @@ class AOIConfig:
     multihost_coordinator: str = ""  # "" = disabled
     multihost_processes: int = 0  # 0 = len(games)
     # Persistent XLA compilation cache for the batched engine's jits:
-    # "auto" = <process cwd>/.goworld_jax_cache (the cwd already hosts
-    # freeze files), "off" = disabled, anything else = explicit dir. The
+    # "auto" = <checkout>/.jax_cache (one fixed path for every process and
+    # run; a set JAX_COMPILATION_CACHE_DIR overrides it and any explicit
+    # dir), "off" = disabled, anything else = explicit dir. The
     # point is the RESPAWN path: a freeze->restore restart re-compiles
     # every step jit from scratch (~4-6 s on a small host) inside the
     # 5 s RPC window buffered clients are waiting out; with the cache the

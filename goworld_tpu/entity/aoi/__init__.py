@@ -9,15 +9,14 @@ entities). Two implementations:
 - ``BatchAOIService`` + ``BatchSpaceAOIManager`` — the TPU path: all spaces'
   positions batched into one NeighborEngine launch per tick; enter/leave
   diffs delivered at tick boundaries (SURVEY.md §7.1).
+
+The batched path lives in ``goworld_tpu.entity.aoi.batched`` and is not
+imported here: it loads JAX, which an xzlist game never needs (importing
+it inside the dispatch that creates the first AOI space froze the loop
+for seconds).
 """
 
 from goworld_tpu.entity.aoi.base import AOIManagerBase
 from goworld_tpu.entity.aoi.xzlist import XZListAOIManager
-from goworld_tpu.entity.aoi.batched import BatchAOIService, BatchSpaceAOIManager
 
-__all__ = [
-    "AOIManagerBase",
-    "XZListAOIManager",
-    "BatchAOIService",
-    "BatchSpaceAOIManager",
-]
+__all__ = ["AOIManagerBase", "XZListAOIManager"]

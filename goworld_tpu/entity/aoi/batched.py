@@ -1178,10 +1178,9 @@ class BatchAOIService:
     def in_flight_age(self) -> float:
         """Seconds the in-flight step has been dispatched without being
         observed ready; 0.0 when idle or ready. The game loop watchdogs this:
-        with wait=False a step that NEVER becomes ready (wedged device — a
-        single-client TPU transport failure mode this project has hit) would
-        otherwise frame-skip forever while AOI silently stops delivering
-        (ADVICE r3)."""
+        with wait=False a step that NEVER becomes ready (a wedged device)
+        would otherwise frame-skip forever while AOI silently stops
+        delivering (ADVICE r3)."""
         prev = self._pending
         if prev is None or self._turnaround_stamped or prev[0].is_ready():
             return 0.0

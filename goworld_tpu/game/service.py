@@ -24,7 +24,7 @@ from goworld_tpu.entity.game_client import GameClient
 from goworld_tpu.netutil.packet import Packet
 from goworld_tpu.proto.conn import unpack_sync_records
 from goworld_tpu.proto.msgtypes import MsgType
-from goworld_tpu.telemetry import tracing
+from goworld_tpu.telemetry import sentinel, tracing
 from goworld_tpu.utils import async_jobs, crontab, gwlog, gwutils, post
 
 # Sync fan-out per-hop attribution (shared family with the dispatcher's
@@ -52,35 +52,59 @@ def freeze_filename(gameid: int) -> str:
     return f"game{gameid}_freezed.dat"
 
 
+# The checkout this package runs from: "auto" keeps the compile cache at a
+# fixed path inside it, so every process of every run shares one cache.
+AUTO_COMPILATION_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
+
 def apply_compilation_cache(value: str) -> Optional[str]:
-    """Point jax's persistent XLA compilation cache at ``value`` ([aoi]
-    compilation_cache: "auto" = <cwd>/.goworld_jax_cache, "off" = None).
+    """Point jax's persistent XLA compilation cache per ``[aoi]
+    compilation_cache``: "auto" = ``AUTO_COMPILATION_CACHE``, "off" =
+    disabled, anything else = that directory. A set
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside and wins
+    over all but "off": jax reads it itself, and no code sets another.
 
     The payoff is the freeze->restore respawn: the restarted process
     would otherwise re-run every step-jit compile inside the 5 s window
     buffered client RPCs are waiting out; with the cache it LOADS the
-    executables compiled at original boot (measured 6.0 s -> 2.5 s
-    boot-to-warm on the verify rig). Returns the resolved directory."""
+    executables compiled at original boot. Returns the resolved
+    directory."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
     if value == "off":
+        jax.config.update("jax_enable_compilation_cache", False)
         return None
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache:
+        cache = AUTO_COMPILATION_CACHE if value == "auto" else value
+        jax.config.update("jax_compilation_cache_dir", cache)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    # jax latches "no cache" on the first compile; anything compiled
+    # before this config landed would otherwise leave the dir ignored.
+    compilation_cache.reset_cache()
+    return cache
+
+
+def aoi_engine_info() -> dict:
+    """Which engine the batched AOI service runs, on which device, and its
+    launch / retrace / compile-cache counts (the /vars ``AOIEngine`` probe:
+    proof that the tick runs on the chip, warm, from the cache)."""
     import jax
 
-    cache = (os.path.join(os.getcwd(), ".goworld_jax_cache")
-             if value == "auto" else value)
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    try:
-        # jax latches "no cache" on the first compile; if ANYTHING
-        # compiled before this config landed (warmup ordering drift, test
-        # harnesses), the new dir would be silently ignored without a
-        # reset. Private API, so best-effort.
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:  # pragma: no cover - jax-internal drift
-        pass
-    return cache
+    eng = entity_manager.runtime.get_aoi_service().engine
+    dev = jax.devices()[0]
+    return {
+        "engine": type(eng).__name__,
+        "backend": eng.backend,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "jit_launches": sentinel.launches_total(),
+        "steady_state_retraces": sentinel.steady_state_retraces(),
+        "compile_cache_hits": sentinel.compile_cache_hits(),
+    }
 
 
 class GameService:
@@ -185,21 +209,24 @@ class GameService:
 
             rt.aoi_params = params_from_config(self.cfg.aoi)
         if rt.aoi_backend != "xzlist":
-            # Per-game aoi_platform overrides the global [aoi] platform: on
-            # single-client TPU transports exactly one game may hold the
-            # chip (read_config.py GameConfig.aoi_platform).
+            # Per-game aoi_platform overrides the global [aoi] platform: a
+            # chip belongs to one process, so exactly one game may hold it
+            # (read_config.py GameConfig.aoi_platform).
             platform = (
                 (game_cfg.aoi_platform if game_cfg else "")
                 or self.cfg.aoi.platform
             )
-            if platform == "cpu":
-                # Must happen before the first jax use: the TPU plugin
-                # ignores JAX_PLATFORMS, so only jax.config reliably keeps a
-                # CPU-deploy game process off the chip (read_config.py).
-                # ("tpu"/"auto" leave jax's default, which prefers the chip.)
-                import jax
+            import jax
 
+            if platform == "cpu":
+                # Before the first jax use: keeps a CPU-deploy game off the
+                # chip. ("auto" leaves jax's default, which prefers it.)
                 jax.config.update("jax_platforms", "cpu")
+            elif platform == "tpu" and jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    f"game{self.gameid}: [aoi] platform = tpu but no TPU "
+                    f"was found (jax backend is {jax.default_backend()!r})"
+                )
             # Persistent XLA compilation cache — the respawn-path fix
             # (apply_compilation_cache docstring).
             apply_compilation_cache(self.cfg.aoi.compilation_cache)
@@ -234,6 +261,8 @@ class GameService:
             # the first dispatch otherwise freezes the loop for the whole
             # jit compile (seconds) right as the first clients log in.
             rt.get_aoi_service().warmup()
+            gwlog.infof("game %d AOI engine: %s", self.gameid,
+                        json.dumps(aoi_engine_info()))
         if not storage.initialized():
             storage.initialize(self.cfg.storage)
         rt.storage = storage.SyncStorageAdapter()
@@ -327,6 +356,8 @@ class GameService:
                     out[e.typename] = out.get(e.typename, 0) + 1
                 return out
             gwvar.set_var("EntityCounts", _counts)
+            if rt.aoi_service is not None:
+                gwvar.set_var("AOIEngine", aoi_engine_info)
             from goworld_tpu.utils import debug_http
 
             debug_http.set_health_provider(self._health)
@@ -384,6 +415,7 @@ class GameService:
             # of MB of entity state alive through the gwvar registry.
             gwvar.unset("MigrateIn")
             gwvar.unset("FattestEntity")
+            gwvar.unset("AOIEngine")
             # Same closure-capture reasoning as the gwvar.unset calls.
             telemetry.gauge("game_entities", labelnames=("gameid",)).remove(
                 str(self.gameid))

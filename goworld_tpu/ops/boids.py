@@ -277,10 +277,8 @@ class BoidsEngine:
     # is a full interval old, so int()-ing it never stalls the pipeline.
     DROP_CHECK_INTERVAL = 64
 
-    def __init__(self, params: BoidsParams, interpret: bool | None = None):
+    def __init__(self, params: BoidsParams, interpret: bool):
         self.params = params
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         self._step_fn = _jitted_step(params, interpret)
         # Device scalar: active agents whose cell overflowed LANES this tick
         # (they get zero steering — densest clusters are exactly where this

@@ -508,19 +508,18 @@ def _event_kernel(p: NeighborParams, dual: bool, drain_inline: int,
     lane's SLOT id and OWN flag, and the kernel appends the (query slot,
     other slot) pair of every own-row event to a compacted pairs output
     through SMEM cursors — exact because the TPU grid executes
-    SEQUENTIALLY on a core, so the cursors are plain scalar state. Region
-    layout of the pairs block (i32[2, cap+1], row 0 = query, row 1 =
-    other, sentinel ``capacity``): enters fill [0, drain_inline) and, when
-    dual, leaves fill [drain_inline, 2*drain_inline); writes past a
-    region's budget land in the trailing trash column, and the caller's
-    authoritative popcount header detects the overflow and repages the
-    whole tick from rank 0 (emission is cell-major, not the XLA drain's
-    row-major rank order, so a partial inline window cannot be resumed).
-    Per-event selection is VPU-shaped: masked-reduction scalar selects and
-    prefix-compare bit ranking — no gathers. Validated under interpret;
-    the scalar dynamic stores follow the TPU guide's dynamic-ref-store
-    idiom but have not been Mosaic-compiled on real hardware yet (the
-    kernel tier's standing honesty note).
+    SEQUENTIALLY on a core, so the cursors are plain scalar state. The
+    pairs block is (8, LANES) tiles (``pair_tiles``/``untile_pairs``,
+    sentinel ``capacity``): enters fill events [0, drain_inline) and, when
+    dual, leaves fill [drain_inline, 2*drain_inline). Events past a
+    region's budget are not emitted; the caller's authoritative popcount
+    header detects the overflow and repages the whole tick from rank 0
+    (emission is cell-major, not the XLA drain's row-major rank order, so
+    a partial inline window cannot be resumed). Per-event selection is
+    VPU-shaped — a masked min-reduce for the next set bit, masked sums for
+    the slot scalars, a read-modify-write of one tile for the store —
+    because Mosaic lowers no cumsum and stores no scalar to VMEM
+    (tests/test_v5e_compile.py compiles it for v5e).
 
     The halo DMA is double-buffered across grid steps: ~7.7k sequential
     73 KB copies at the headline config are latency-bound, and the serial
@@ -577,9 +576,7 @@ def _event_kernel(p: NeighborParams, dual: bool, drain_inline: int,
         def _():
             cur_ref[0, 0] = 0
             cur_ref[1, 0] = drain_inline
-            pairs_ref[:, :] = jnp.full(
-                pairs_ref.shape, p.capacity, jnp.int32
-            )
+            pairs_ref[...] = jnp.full(pairs_ref.shape, p.capacity, jnp.int32)
 
     halo_copy(lin, slot).wait()
     c = scratch[slot]  # [3, 3, F, LANES]
@@ -643,54 +640,70 @@ def _event_kernel(p: NeighborParams, dual: bool, drain_inline: int,
 
     if drain_inline:
         so_copy.wait()
-        ctr = so_scratch[1, 1]  # [2, LANES]: this cell's slot ids + own flags
-        q_slots = ctr[0:1]  # [1, LANES]
-        own_col = jnp.transpose(ctr[1:2]) > 0  # [LANES, 1] query ownership
-        slots9 = so_scratch[:, :, 0].reshape(9, LANES)  # candidate slot ids
+        q_slots = so_scratch[1, 1, 0:1]  # [1, LANES] this cell's slot ids
+        # [LANES, 1] query ownership (a lane-to-sublane move Mosaic takes
+        # as a square transpose).
+        own_col = jnp.transpose(jnp.broadcast_to(
+            so_scratch[1, 1, 1:2], (LANES, LANES)))[:, 0:1] > 0
+        slots9 = jnp.concatenate(
+            [so_scratch[a, b, 0:1] for a in range(3) for b in range(3)]
+        )  # [9, LANES] candidate slot ids, halo cell row-major
         il = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
         i9 = jax.lax.broadcasted_iota(jnp.int32, (9, LANES), 0)
         l9 = jax.lax.broadcasted_iota(jnp.int32, (9, LANES), 1)
-        irow = jax.lax.broadcasted_iota(jnp.int32, (LANES, 9 * LANES), 0)
-        trash = pairs_ref.shape[1] - 1
+        ic = jax.lax.broadcasted_iota(jnp.int32, (LANES, 9 * LANES), 1)
+        # Bit (query lane r, candidate c) at row-major rank r * 9*LANES + c.
+        rank = lane * (9 * LANES) + ic
+        tsub = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
+        tlane = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 1)
+        none = jnp.int32(LANES * 9 * LANES)
 
         def emit(mask, ci, lim):
-            """Append every set bit of ``mask`` (pre-masked to OWN query
-            lanes) as a (query slot, other slot) pair: row by prefix-count
-            over the per-lane inclusive cumsum, bit by prefix-count within
-            the selected row, scalars by masked reductions."""
-            mi = mask.astype(jnp.int32)
-            rcnt = jnp.transpose(
-                jnp.sum(mi, axis=1, keepdims=True)
-            )  # [1, LANES]
-            rcum = jnp.cumsum(rcnt, axis=1)  # inclusive
-            count = jnp.sum(mi)
+            """Append every set bit of ``mask`` whose query lane is OWN, in
+            row-major order, as a (query slot, other slot) pair, up to the
+            region's budget ``lim``."""
+            key = jnp.where(mask & own_col, rank, none)
+            count = jnp.sum(jnp.where(key < none, 1, 0))
+            cur = cur_ref[ci, 0]
+            n_emit = jnp.clip(lim - cur, 0, count)
 
-            def body(jj, carry):
-                row = jnp.sum(jnp.where(rcum <= jj, 1, 0))
-                kk = jj - jnp.sum(jnp.where(il == row, rcum - rcnt, 0))
-                mrow = jnp.sum(
-                    jnp.where(irow == row, mi, 0), axis=0, keepdims=True
-                )  # [1, 9*LANES]
-                ccum = jnp.cumsum(mrow, axis=1)
-                col = jnp.sum(jnp.where(ccum <= kk, 1, 0))
-                hc = col // LANES
-                lane = jax.lax.rem(col, LANES)
-                other = jnp.sum(
-                    jnp.where((i9 == hc) & (l9 == lane), slots9, 0)
-                )
+            def body(jj, prev):
+                nxt = jnp.min(jnp.where(key > prev, key, none))
+                row = nxt // (9 * LANES)
+                col = jax.lax.rem(nxt, 9 * LANES)
                 qs = jnp.sum(jnp.where(il == row, q_slots, 0))
-                cur = cur_ref[ci, 0]
-                idx = jnp.where(cur < lim, cur, trash)
-                pl.store(pairs_ref, (jnp.int32(0), idx), qs)
-                pl.store(pairs_ref, (jnp.int32(1), idx), other)
-                cur_ref[ci, 0] = cur + 1
-                return carry
+                other = jnp.sum(jnp.where(
+                    (i9 == col // LANES) & (l9 == jax.lax.rem(col, LANES)),
+                    slots9, 0))
+                e = cur + jj
+                t = e // (4 * LANES)
+                k2 = 2 * jax.lax.rem(e // LANES, 4)
+                at = tlane == jax.lax.rem(e, LANES)
+                pairs_ref[t] = jnp.where(
+                    at & (tsub == k2), qs,
+                    jnp.where(at & (tsub == k2 + 1), other, pairs_ref[t]))
+                return nxt
 
-            jax.lax.fori_loop(0, count, body, 0)
+            jax.lax.fori_loop(0, n_emit, body, jnp.int32(-1))
+            cur_ref[ci, 0] = cur + n_emit
 
-        emit(v_a & ~v_b & own_col, 0, drain_inline)
+        emit(v_a & ~v_b, 0, drain_inline)
         if dual:
-            emit(v_b & ~v_a & own_col, 1, 2 * drain_inline)
+            emit(v_b & ~v_a, 1, 2 * drain_inline)
+
+
+def pair_tiles(cap: int) -> int:
+    """(8, LANES) tiles of the in-kernel drain's pairs block for ``cap``
+    events: event e sits in tile e // 512, lane e % 128, query on sublane
+    2 * (e // 128 % 4) and other on the sublane after it."""
+    return -(-cap // (4 * LANES))
+
+
+def untile_pairs(tiles: jax.Array, cap: int) -> jax.Array:
+    """The kernel's pairs tiles as i32[2, cap] (row 0 query, row 1 other)."""
+    t = tiles.shape[0]
+    flat = tiles.reshape(t, 4, 2, LANES).transpose(2, 0, 1, 3)
+    return flat.reshape(2, t * 4 * LANES)[:, :cap]
 
 
 @functools.lru_cache(maxsize=None)
@@ -707,9 +720,9 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
     ``drain_inline`` adds the in-kernel event drain (see _event_kernel): a
     second input (the i32 slot/own plane, cells geometry with 2 planes in
     place of the F features) and a second output, the compacted pairs
-    block i32[2, cap+1] with cap = drain_inline * (2 if dual else 1); its
-    constant index map keeps the block VMEM-resident across the whole
-    sequential grid."""
+    tiles i32[pair_tiles(cap), 8, LANES] with cap = drain_inline * (2 if
+    dual else 1); its constant index map keeps the block VMEM-resident
+    across the whole sequential grid."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -740,7 +753,7 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
             ],
             interpret=interpret,
         )
-    cap = drain_inline * (2 if dual else 1)
+    tiles = pair_tiles(drain_inline * (2 if dual else 1))
     return pl.pallas_call(
         kernel,
         grid=(p.space_slots, rows, cols),
@@ -751,13 +764,13 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
         out_specs=(
             words_spec,
             pl.BlockSpec(
-                (2, cap + 1), lambda s, i, j: (0, 0),
+                (tiles, 8, LANES), lambda s, i, j: (0, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
         ),
         out_shape=(
             words_shape,
-            jax.ShapeDtypeStruct((2, cap + 1), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 8, LANES), jnp.int32),
         ),
         scratch_shapes=[
             pltpu.VMEM((2, 3, 3, _F, LANES), jnp.float32),

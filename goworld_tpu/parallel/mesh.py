@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from goworld_tpu.parallel.compat import resolve_shard_map
 from goworld_tpu.telemetry import sentinel
 from goworld_tpu.ops.neighbor import (
     LANES,
@@ -95,23 +94,18 @@ def make_mesh(n_devices: int | None = None, devices: list | None = None) -> Mesh
     """Build a 1-D mesh over the entity-shard axis.
 
     Prefers explicitly passed devices; otherwise takes the first n of
-    jax.devices(). For CPU-hosted multi-device testing, set
-    ``--xla_force_host_platform_device_count`` (tests/conftest.py does).
+    jax.devices(), and too few is an error. For CPU-hosted multi-device
+    testing, set ``--xla_force_host_platform_device_count``
+    (tests/conftest.py does).
     """
     if devices is None:
         devices = jax.devices()
         if n_devices is not None:
             if len(devices) < n_devices:
-                # Fall back to virtual CPU devices when the default platform
-                # has too few chips (e.g. one real TPU during development).
-                cpu = jax.devices("cpu")
-                if len(cpu) >= n_devices:
-                    devices = cpu
-                else:
-                    raise ValueError(
-                        f"need {n_devices} devices, have {len(devices)} "
-                        f"{devices[0].platform} and {len(cpu)} cpu"
-                    )
+                raise ValueError(
+                    f"need {n_devices} devices, have {len(devices)} "
+                    f"{devices[0].platform}"
+                )
             devices = devices[:n_devices]
     return Mesh(np.asarray(devices), (SHARD_AXIS,))
 
@@ -377,13 +371,11 @@ def _jitted_sharded_step_fused(
     params: NeighborParams, mesh: Mesh, events_inline: int,
     programs: tuple, n_cols: int,
 ):
-    shard_map = resolve_shard_map()
-
     body = functools.partial(
         _sharded_step_fused, params, events_inline, programs
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * (12 + n_cols),
@@ -394,11 +386,9 @@ def _jitted_sharded_step_fused(
 
 @functools.lru_cache(maxsize=None)
 def _jitted_sharded_step(params: NeighborParams, mesh: Mesh, events_inline: int):
-    shard_map = resolve_shard_map()
-
     body = functools.partial(_sharded_step, params, events_inline)
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 8,
@@ -416,14 +406,12 @@ def _jitted_sharded_step(params: NeighborParams, mesh: Mesh, events_inline: int)
 def _jitted_sharded_step_pallas(
     params: NeighborParams, mesh: Mesh, events_inline: int, interpret: bool
 ):
-    shard_map = resolve_shard_map()
-
     body = functools.partial(
         _sharded_step_pallas, params, events_inline, interpret,
         mesh.devices.size,
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 8,
@@ -440,11 +428,9 @@ def _jitted_sharded_step_pallas(
 def _jitted_sharded_drain(
     params: NeighborParams, mesh: Mesh, events_inline: int, chunk: int
 ):
-    shard_map = resolve_shard_map()
-
     body = functools.partial(_sharded_drain, params, events_inline, chunk)
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)
     )
     return sentinel.SentinelJit("sharded_drain", jax.jit(mapped))
@@ -454,11 +440,9 @@ def _jitted_sharded_drain(
 def _jitted_sharded_drain_bits(
     params: NeighborParams, mesh: Mesh, events_inline: int
 ):
-    shard_map = resolve_shard_map()
-
     body = functools.partial(_sharded_drain_bits, params, events_inline)
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec, spec)
     )
     return sentinel.SentinelJit("sharded_drain_bits", jax.jit(mapped))

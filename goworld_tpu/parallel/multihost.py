@@ -30,10 +30,8 @@ params. Tested by spawning real OS processes over the Gloo CPU backend
 (tests/test_multihost.py) — the localhost analog of a multi-host pod,
 mirroring how the reference CI tests its multi-process cluster.
 
-shard_map itself resolves through parallel/compat.py (stable
-``jax.shard_map`` or the experimental export, whichever this jax build
-has) via the jitted step/drain builders shared with parallel/mesh.py —
-this module constructs on jax 0.4.x images too. The spatially sharded
+The jitted step/drain builders (``jax.shard_map``) are shared with
+parallel/mesh.py. The spatially sharded
 engine (parallel/spatial.py) is single-controller only for now: its
 host-side strip planner assumes one process owns the whole slot space.
 """

@@ -102,8 +102,8 @@ from goworld_tpu.ops.neighbor import (
     check_radius,
     check_space_ids,
     sorted_ranks_by,
+    untile_pairs,
 )
-from goworld_tpu.parallel.compat import resolve_shard_map
 from goworld_tpu.telemetry import sentinel
 from goworld_tpu.parallel.mesh import (
     SHARD_AXIS,
@@ -443,13 +443,12 @@ def _jitted_spatial_step_fused(
     params: NeighborParams, mesh: Mesh, events_inline: int, halo_cap: int,
     programs: tuple, n_cols: int,
 ):
-    shard_map = resolve_shard_map()
     body = functools.partial(
         _spatial_step_fused_impl, params, events_inline, halo_cap,
         mesh.devices.size, programs,
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * (15 + n_cols),
@@ -462,13 +461,12 @@ def _jitted_spatial_step_fused(
 def _jitted_spatial_step(
     params: NeighborParams, mesh: Mesh, events_inline: int, halo_cap: int
 ):
-    shard_map = resolve_shard_map()
     body = functools.partial(
         _spatial_step_impl, params, events_inline, halo_cap,
         mesh.devices.size,
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 11,
@@ -481,10 +479,9 @@ def _jitted_spatial_step(
 def _jitted_spatial_drain(
     params: NeighborParams, mesh: Mesh, events_inline: int, chunk: int
 ):
-    shard_map = resolve_shard_map()
     body = functools.partial(_spatial_drain, params, events_inline, chunk)
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=(spec, spec),
     )
@@ -671,11 +668,11 @@ def _spatial_step_pallas_impl(
         so_c = _scatter_slotown(p, dst_c, order_c, slot_all, chunk, gxe)
 
         def fast_fn():
-            pk2, prs = kernel_dual(cells_c, so_c)
+            pk2, tiles = kernel_dual(cells_c, so_c)
+            prs = untile_pairs(tiles, 2 * drain_inline)
             return (pk2[..., :w_words], pk2[..., w_words:],
                     lxc, czc, smc, tpos_c, table_c,
-                    prs[:, :drain_inline],
-                    prs[:, drain_inline:2 * drain_inline])
+                    prs[:, :drain_inline], prs[:, drain_inline:])
 
         def slow_fn():
             pk_e, prs_e = kernel(cells_c, so_c)
@@ -686,7 +683,8 @@ def _spatial_step_pallas_impl(
             # (valid_prev ∧ ¬valid_cur) IS the leave set.
             pk_l, prs_l = kernel(cells_p, so_p)
             return (pk_e, pk_l, lxp, czp, smp, tpos_p, table_p,
-                    prs_e[:, :drain_inline], prs_l[:, :drain_inline])
+                    untile_pairs(prs_e, drain_inline),
+                    untile_pairs(prs_l, drain_inline))
 
         (pk_e, pk_l, l_lx, l_cz, l_sm, l_tpos, l_table, prs_e, prs_l
          ) = jax.lax.cond(fast, fast_fn, slow_fn)
@@ -811,13 +809,12 @@ def _jitted_spatial_step_pallas(
     interpret: bool, cols_cap: int, drain_inline: int = 0,
 ):
     assert drain_inline in (0, events_inline)
-    shard_map = resolve_shard_map()
     body = functools.partial(
         _spatial_step_pallas_impl, params, events_inline, halo_cap,
         mesh.devices.size, interpret, cols_cap, drain_inline,
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 12,
@@ -837,13 +834,12 @@ def _jitted_spatial_step_pallas_fused(
     drain_inline: int = 0,
 ):
     assert drain_inline in (0, events_inline)
-    shard_map = resolve_shard_map()
     body = functools.partial(
         _spatial_step_pallas_fused_impl, params, events_inline, halo_cap,
         mesh.devices.size, interpret, cols_cap, drain_inline, programs,
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * (16 + n_cols),
@@ -857,12 +853,11 @@ def _jitted_spatial_step_pallas_fused(
 def _jitted_spatial_drain_bits(
     params: NeighborParams, mesh: Mesh, events_inline: int, cols_cap: int
 ):
-    shard_map = resolve_shard_map()
     body = functools.partial(
         _spatial_drain_bits, params, events_inline, cols_cap
     )
     spec = P(SHARD_AXIS)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 7, out_specs=(spec, spec),
     )
     return sentinel.SentinelJit("spatial_drain_bits", jax.jit(mapped))

@@ -86,10 +86,11 @@ class InterestOracle:
 
 
 def make_engine(config: Dict[str, Any], engine: str) -> Any:
-    """Build the AOI engine a scenario runs on. ``batched`` | ``sharded``."""
+    """Build the AOI engine a scenario runs on. ``batched`` | ``sharded``.
+    Runs on JAX's default platform: a caller that wants the CPU selects it
+    (tests/conftest.py, ``bench.py --scenario``)."""
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from goworld_tpu.ops import NeighborEngine, NeighborParams
 
     params = NeighborParams(

@@ -226,8 +226,15 @@ def steady_state_retraces() -> float:
     return sum(child.value for _, child in fam.children())
 
 
-def launches_total(fn: str) -> float:
+def launches_total(fn: str | None = None) -> float:
+    """Launches of one instrumented jit, or of all of them (``fn=None``)."""
+    if fn is None:
+        return sum(child.value for _, child in _LAUNCHES.children())
     return float(_LAUNCHES.labels(fn).value)
+
+
+def compile_cache_hits() -> float:
+    return float(_CACHE_HITS.value)
 
 
 def traces_total(fn: str) -> float:

@@ -28,7 +28,7 @@ def make_world(p, n_active, seed=0, speed=3.0):
 def test_accel_matches_oracle():
     p = make_params()
     pos, vel, active = make_world(p, 300, seed=1)
-    eng = BoidsEngine(p)
+    eng = BoidsEngine(p, interpret=True)
     _, _, accel = eng.step(pos, vel, active)
     want = reference_accel(p, pos, vel, active)
     got = np.asarray(accel, np.float64)
@@ -44,7 +44,7 @@ def test_accel_matches_oracle_dense_wrap():
     vel = rng.normal(0, 3.0, (p.capacity, 2)).astype(np.float32)
     active = np.ones(p.capacity, bool)
     active[400:] = False
-    eng = BoidsEngine(p)
+    eng = BoidsEngine(p, interpret=True)
     _, _, accel = eng.step(pos, vel, active)
     want = reference_accel(p, pos, vel, active)
     np.testing.assert_allclose(
@@ -58,7 +58,7 @@ def test_accel_matches_oracle_supercells():
     3x3 halo over-covers and the r2 predicate prunes."""
     p = make_params(cell_size=250.0, grid_x=4, grid_z=4, radius=100.0)
     pos, vel, active = make_world(p, 400, seed=5)
-    eng = BoidsEngine(p)
+    eng = BoidsEngine(p, interpret=True)
     _, _, accel = eng.step(pos, vel, active)
     want = reference_accel(p, pos, vel, active)
     np.testing.assert_allclose(
@@ -77,7 +77,7 @@ def test_isolated_agent_no_force():
     vel = np.zeros((p.capacity, 2), np.float32)
     active = np.zeros(p.capacity, bool)
     active[:2] = True
-    eng = BoidsEngine(p)
+    eng = BoidsEngine(p, interpret=True)
     _, _, accel = eng.step(pos, vel, active)
     np.testing.assert_allclose(np.asarray(accel)[:2], 0.0, atol=1e-6)
 
@@ -85,7 +85,7 @@ def test_isolated_agent_no_force():
 def test_speed_clamped_and_world_wrapped():
     p = make_params(max_speed=5.0)
     pos, vel, active = make_world(p, 400, seed=3, speed=20.0)
-    eng = BoidsEngine(p)
+    eng = BoidsEngine(p, interpret=True)
     pos2, vel2, _ = eng.step(pos, vel, active)
     pos2, vel2 = np.asarray(pos2), np.asarray(vel2)
     speeds = np.linalg.norm(vel2, axis=1)
@@ -102,7 +102,7 @@ def test_alignment_converges_headings():
     pos = np.mod(rng.normal(300.0, 80.0, (p.capacity, 2)), p.world_x).astype(np.float32)
     vel = rng.normal(0, 4.0, (p.capacity, 2)).astype(np.float32)
     active = np.ones(p.capacity, bool)
-    eng = BoidsEngine(p)
+    eng = BoidsEngine(p, interpret=True)
     var0 = np.var(np.asarray(vel)[active], axis=0).sum()
     for _ in range(25):
         pos, vel, _ = eng.step(pos, vel, active)

@@ -6,6 +6,7 @@ Mirrors the reference test strategy (SURVEY.md §4.1): attr tree behavior
 client ownership, and AOI interest with both backends.
 """
 
+import os
 import time
 
 import pytest
@@ -387,12 +388,6 @@ def test_batched_aoi_destroy_delivers_leaves():
     assert not a.is_interested_in(b)
 
 
-@pytest.mark.skipif(
-    not __import__(
-        "goworld_tpu.parallel.compat", fromlist=["shard_map_available"]
-    ).shard_map_available(),
-    reason="no shard_map in this jax build (parallel.mesh needs it)",
-)
 def test_batched_aoi_sharded_engine_wired():
     """[aoi] mesh_shards>1 must actually build the multi-device engine and
     drive the same interest semantics through the entity layer (VERDICT r2
@@ -441,12 +436,6 @@ def test_batched_aoi_inkernel_drain_knob_threaded():
     assert svc.engine.inkernel_drain is True
 
 
-@pytest.mark.skipif(
-    not __import__(
-        "goworld_tpu.parallel.compat", fromlist=["shard_map_available"]
-    ).shard_map_available(),
-    reason="no shard_map in this jax build (parallel.mesh needs it)",
-)
 def test_batched_aoi_entity_shard_mode_wired():
     """[aoi] shard_mode = entity keeps the all-gather engine reachable
     (the Pallas-kernel tier on real chips)."""
@@ -475,7 +464,7 @@ def test_respawn_compilation_cache_no_fresh_compile(tmp_path):
     cache-hit events. jax.clear_caches() stands in for the process
     restart (same in-memory state loss, one process, test stays fast)."""
     import jax
-    from jax._src import monitoring
+    from jax import monitoring
 
     import numpy as np
 
@@ -518,7 +507,43 @@ def test_respawn_compilation_cache_no_fresh_compile(tmp_path):
         jax.config.update("jax_compilation_cache_dir", None)
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", saved_min)
-        monitoring._unregister_event_listener_by_callback(listener)
+        monitoring.unregister_event_listener(listener)
+
+
+@pytest.mark.parametrize("env_dir,value", [
+    (True, "auto"), (True, "explicit"), (False, "auto"), (False, "explicit"),
+], ids=["env-auto", "env-explicit", "auto", "explicit"])
+def test_compilation_cache_placement(tmp_path, monkeypatch, env_dir, value):
+    """A set JAX_COMPILATION_CACHE_DIR is the cache and no code sets
+    another; unset, "auto" is the fixed <checkout>/.jax_cache, never a
+    path derived from the cwd."""
+    import jax
+
+    from goworld_tpu.game import service
+
+    monkeypatch.chdir(tmp_path)
+    env = str(tmp_path / "env_cache")
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    explicit = str(tmp_path / "explicit_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = service.apply_compilation_cache(
+            explicit if value == "explicit" else value)
+        if env_dir:
+            assert got == env
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            want = (service.AUTO_COMPILATION_CACHE if value == "auto"
+                    else explicit)
+            assert got == want == jax.config.jax_compilation_cache_dir
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert service.AUTO_COMPILATION_CACHE == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_aoi_backends_agree_on_random_trace():

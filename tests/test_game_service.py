@@ -12,6 +12,7 @@ import asyncio
 import pytest
 
 from goworld_tpu.config.read_config import (
+    AOIConfig,
     DeploymentConfig,
     DispatcherConfig,
     GameConfig,
@@ -308,3 +309,20 @@ def test_handshake_entity_list_filtered_per_dispatcher(clean_entities, tmp_path)
     assert not (per_index[1] & per_index[2])
     assert not (per_index[0] & per_index[2])
     assert len(eids) == 40  # sanity: the partition had real members
+
+
+@pytest.mark.parametrize("where", ["aoi", "game"])
+def test_tpu_platform_without_a_tpu_fails_at_start(clean_entities, tmp_path,
+                                                    where):
+    """``[aoi] platform = tpu`` (or the game's ``aoi_platform = tpu``)
+    refuses to start a game on a host without a TPU, instead of running
+    its AOI elsewhere."""
+    cfg = make_cfg(0, tmp_path)
+    cfg.aoi = AOIConfig(backend="tpu", max_entities=256)
+    if where == "aoi":
+        cfg.aoi.platform = "tpu"
+    else:
+        cfg.games[1].aoi_platform = "tpu"
+    svc = GameService(1, cfg, restore=False)
+    with pytest.raises(RuntimeError, match="no TPU was found"):
+        asyncio.run(svc.run_async())

@@ -3,22 +3,8 @@ single-device engine on identical inputs — run on the virtual 8-device CPU
 mesh (conftest.py), the analog of the reference testing its multi-process
 cluster on localhost (SURVEY.md §4.3)."""
 
-import jax
 import numpy as np
 import pytest
-
-from goworld_tpu.parallel.compat import shard_map_available
-
-if not shard_map_available():
-    # parallel/mesh.py resolves shard_map through parallel/compat.py
-    # (stable jax.shard_map OR jax.experimental.shard_map); only a build
-    # with NEITHER cannot construct the engine — skip cleanly then, so
-    # the suite's pass/fail stays a usable regression signal.
-    pytest.skip(
-        "no shard_map in this jax build "
-        f"({jax.__version__}); parallel.mesh needs it",
-        allow_module_level=True,
-    )
 
 from goworld_tpu.ops import NeighborEngine, NeighborParams
 from goworld_tpu.parallel import ShardedNeighborEngine, make_mesh

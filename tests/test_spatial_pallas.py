@@ -10,15 +10,6 @@ import jax
 import numpy as np
 import pytest
 
-from goworld_tpu.parallel.compat import shard_map_available
-
-if not shard_map_available():
-    pytest.skip(
-        "no shard_map in this jax build "
-        f"({jax.__version__}); parallel.spatial needs it",
-        allow_module_level=True,
-    )
-
 from goworld_tpu.ops import NeighborEngine, NeighborParams
 from goworld_tpu.parallel import make_mesh
 from goworld_tpu.parallel.spatial import (

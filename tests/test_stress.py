@@ -344,7 +344,7 @@ def test_bot_army_kcp_fec(cluster):
 def test_kcp_fleet_double_reload(cluster):
     """Strict KCP+FEC+snappy fleet held through TWO live reloads — the
     round-5 endurance shape that found the single-core harness decoding
-    ceiling (BENCH_NOTES round 5). Pinned at 24 bots (verified clean up
+    ceiling (round 5). Pinned at 24 bots (verified clean up
     to 40 with the C control block; 60 trips strict budgets on the
     one-core fleet process, a harness bound, not a server one)."""
     d, gates = cluster

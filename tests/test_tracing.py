@@ -188,6 +188,8 @@ def test_recv_seam_strips_trailer_ignored_compatible():
         assert p1.trace.span_id == 0x1234 and p1.trace.born is not None
         assert p2.trace is None
         writer.close()
+        # Python 3.12's wait_closed waits for every accepted connection.
+        sender.close()
         server.close()
         await server.wait_closed()
 

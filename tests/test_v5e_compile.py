@@ -1,0 +1,87 @@
+"""Ahead-of-time compiles, for one v5e chip, of the Pallas kernels of the
+main path at headline widths (on-chip-measurement guide, section 2).
+
+The TPU compiler is installed here, so Mosaic refuses what the chip would
+refuse — interpret-mode tests cannot see that. Nothing runs: these say
+nothing about results or times. The topology is described in a module
+fixture, never at import, so only the worker given this file loads the
+TPU library. Each test compiles one kernel (about a second), asserts the
+kernel is in the executable and prints its ``memory_analysis()``.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from goworld_tpu.ops import boids, neighbor
+from goworld_tpu.ops.neighbor import LANES, NeighborParams, _F
+
+# __graft_entry__.entry() / chip_smoke.py headline config.
+HEADLINE = NeighborParams(capacity=102400, cell_size=300.0, grid_x=44,
+                          grid_z=44, space_slots=4, cell_capacity=128,
+                          max_events=262144)
+# The 4-chip strip: SpatialShardedNeighborEngine's default strip_cols at
+# grid 44 over 4 devices, and its per-shard inline event budget.
+STRIP_COLS = 22
+EVENTS_INLINE = HEADLINE.max_events // 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # A compile for a described chip cannot be read back from the
+    # persistent cache without one: keep these out of it.
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
+def compile_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    print(compiled.memory_analysis())
+    return compiled
+
+
+def cells(sharding, rows, cols, planes, dtype):
+    return jax.ShapeDtypeStruct(
+        (HEADLINE.space_slots, rows + 2, cols, planes, LANES), dtype,
+        sharding=sharding)
+
+
+@pytest.mark.parametrize("dual", [False, True], ids=["plain", "dual"])
+def test_event_kernel_headline(one_chip, dual):
+    kernel = neighbor._compiled_event_kernel(HEADLINE, False, dual=dual)
+    gz, gx = HEADLINE.grid_z, HEADLINE.grid_x
+    compile_kernel(kernel, cells(one_chip, gz, gx + 2, _F, jnp.float32))
+
+
+@pytest.mark.parametrize("dual", [False, True], ids=["plain", "dual"])
+def test_strip_kernel_inkernel_drain(one_chip, dual):
+    qcols = STRIP_COLS + 2
+    kernel = neighbor._compiled_event_kernel(
+        HEADLINE, False, rows=HEADLINE.grid_z, cols=qcols, dual=dual,
+        drain_inline=EVENTS_INLINE)
+    gz, gxe = HEADLINE.grid_z, STRIP_COLS + 4
+    compile_kernel(kernel, cells(one_chip, gz, gxe, _F, jnp.float32),
+                   cells(one_chip, gz, gxe, 2, jnp.int32))
+
+
+def test_boids_kernel(one_chip):
+    p = boids.BoidsParams(capacity=51200, cell_size=200.0, grid_x=32,
+                          grid_z=32, radius=100.0)
+    kernel = boids._compiled_accel(p, False)
+    compile_kernel(kernel, jax.ShapeDtypeStruct(
+        (p.grid_z + 2, p.grid_x + 2, boids._F, boids.LANES), jnp.float32,
+        sharding=one_chip))
