@@ -1684,129 +1684,6 @@ def bench_boids_tuned() -> dict:
     return result
 
 
-def bench_phase_profile(n: int = 102400, cell: float = 300.0,
-                        grid: int = 44) -> dict:
-    """Attribute the tick budget: time each stage of the Pallas step in
-    isolation (VERDICT r2 #8 — name the phase that owns the p99 gap).
-    space_slots=1 matches the headline config (one space, no empty
-    slabs)."""
-    import jax
-    import jax.numpy as jnp
-
-    from goworld_tpu.ops import neighbor as nb
-
-    p = nb.NeighborParams(
-        capacity=n, cell_size=cell, grid_x=grid, grid_z=grid,
-        space_slots=1, cell_capacity=128, max_events=131072,
-    )
-    rng = np.random.default_rng(0)
-    world = grid * cell
-    pos = jnp.asarray(rng.uniform(0, world, (n, 2)).astype(np.float32))
-    ppos = jnp.asarray(
-        np.asarray(pos) + rng.normal(0, 3, (n, 2)).astype(np.float32)
-    )
-    act = jnp.ones(n, bool)
-    spc = jnp.zeros(n, jnp.int32)
-    rad = jnp.full(n, 100.0, jnp.float32)
-
-    def t(fn, *args, iters=3):
-        jax.block_until_ready(fn(*args))  # compile + warm
-        best = None
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            dt = time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        return round(best * 1000.0, 2)
-
-    @jax.jit
-    def phase_table(pos, act, spc):
-        cx, cz, sm = nb._bins(p, pos, spc)
-        buc = (sm * p.grid_z + cz) * p.grid_x + cx
-        return nb._build_table(p, buc, act, nb.LANES)
-
-    out = {}
-    out["table_ms"] = t(phase_table, pos, act, spc)
-    table, slot, _, order, dst = jax.block_until_ready(
-        phase_table(pos, act, spc)
-    )
-
-    @jax.jit
-    def phase_feats(dst, order, pos, ppos, spc, rad, slot):
-        xs = jnp.where(slot >= 0, pos[:, 0], jnp.nan)
-        xsp = jnp.where(slot >= 0, ppos[:, 0], jnp.nan)
-        return nb._scatter_feats(
-            p, dst, order, (xs, pos[:, 1], spc, rad),
-            (xsp, ppos[:, 1], spc, rad),
-        )
-
-    out["feats_ms"] = t(phase_feats, dst, order, pos, ppos, spc, rad, slot)
-    cells = jax.block_until_ready(
-        phase_feats(dst, order, pos, ppos, spc, rad, slot)
-    )
-
-    kernel = jax.jit(nb._compiled_event_kernel(p, False, dual=True))
-    out["kernel_ms"] = t(kernel, cells)
-    packed_cells2 = jax.block_until_ready(kernel(cells))
-    w = 9 * nb.LANES // nb._PACK
-    packed_cells = packed_cells2[..., :w]
-
-    @jax.jit
-    def phase_gather(packed_cells, slot):
-        flat = packed_cells.reshape(-1, w)
-        safe = jnp.maximum(slot, 0)
-        pe = jnp.where((slot >= 0)[:, None], flat[safe], 0)
-        return pe, jnp.sum(jax.lax.population_count(pe))
-
-    out["gather_ms"] = t(phase_gather, packed_cells, slot)
-    packed_e, cnt = jax.block_until_ready(phase_gather(packed_cells, slot))
-    out["events_in_mask"] = int(cnt)
-    cx, cz, sm = nb._bins(p, pos, spc)
-
-    @jax.jit
-    def phase_drain(packed_e, cx, cz, sm, table):
-        return nb._drain_bits(p, packed_e, cx, cz, sm, table, jnp.int32(0))
-
-    out["drain_ms"] = t(phase_drain, packed_e, cx, cz, sm, table)
-    # Per-mode drain attribution: same inputs, each select strategy.
-    import dataclasses as _dc
-
-    for dm in DRAIN_SWEEP:
-        if dm == p.drain_mode:
-            out[f"drain_{dm}_ms"] = out["drain_ms"]
-            continue
-        pm = _dc.replace(p, drain_mode=dm)
-
-        def phase_drain_m(packed_e, cx, cz, sm, table, pm=pm):
-            return nb._drain_bits(pm, packed_e, cx, cz, sm, table,
-                                  jnp.int32(0))
-
-        out[f"drain_{dm}_ms"] = t(
-            jax.jit(phase_drain_m), packed_e, cx, cz, sm, table
-        )
-    step = nb._jitted_step_packed(p, "pallas")
-    cxp, czp, smp = nb._bins(p, ppos, spc)
-    bucp = (smp * p.grid_z + czp) * p.grid_x + cxp
-    table_p, slot_p, _, order_p, dst_p = jax.jit(
-        lambda b, a: nb._build_table(p, b, a, nb.LANES)
-    )(bucp, act)
-    # (The step no longer donates any arg — unusable-layout donation was
-    # removed in ISSUE 2 — so re-copying ppos is belt-and-braces only.)
-    out["full_step_ms"] = t(
-        lambda: step(
-            jnp.copy(ppos), act, spc, rad,
-            cxp, czp, smp, table_p, slot_p, order_p, dst_p,
-            pos, act, spc, rad,
-        )
-    )
-    # Steady state runs the single-launch fast path: one table+feats+kernel
-    # chain, one drain per mask, one slot gather.
-    out["est_tick_ms"] = round(
-        out["table_ms"] + out["feats_ms"] + out["kernel_ms"]
-        + 2 * out["drain_ms"] + out["gather_ms"], 2
-    )
-    return out
-
 
 # --- main --------------------------------------------------------------------
 
@@ -2279,14 +2156,7 @@ def _run_bench() -> int:
                     configs["boids_50k"] = {
                         "error": _exc_line()
                     }
-                # Per-phase attribution + cell-size sweep (same world span,
-                # 13200 units) — VERDICT r2 #8.
-                try:
-                    result["phases"] = bench_phase_profile()
-                except Exception:
-                    result["phases"] = {
-                        "error": _exc_line()
-                    }
+                # Cell-size sweep (same world span, 13200 units).
                 sweep = {}
                 saved_steps = os.environ.get("BENCH_STEPS")
                 os.environ["BENCH_STEPS"] = os.environ.get(
@@ -2429,12 +2299,6 @@ def _run_bench() -> int:
                                  "post_step_drain_meets_target",
                                  "inline_budget_clears_steady_state")
                             }
-                            # The phase profile was measured at the DEFAULT
-                            # config — keep it with those numbers rather
-                            # than attributing it to the tuned run.
-                            if "phases" in result:
-                                configs["default_config_headline"][
-                                    "phases"] = result.pop("phases")
                             for k, v in tuned.items():
                                 if k != "metric":
                                     result[k] = v

@@ -33,6 +33,7 @@ from goworld_tpu.entity.slabs import (
     SIF_SYNC_OWN_CLIENT,
 )
 from goworld_tpu.ops.neighbor import NeighborEngine, NeighborParams
+from goworld_tpu.telemetry.phases import engine_span
 
 # Fused-tick observability ([aoi] fuse_logic; module-scope families per
 # gwlint R5): how many classes/slots ran inside the device launch at the
@@ -63,11 +64,6 @@ _M_DELIVERY_FALLBACK_CLASSES = telemetry.gauge(
     "aoi_host_fallback_classes",
     "Entity classes kept on the host on_aoi_batch delivery path "
     "(custom AOI/interest hooks, or non-Entity harness doubles).")
-_M_HOST_PHASE = telemetry.counter(
-    "aoi_host_phase_seconds_total",
-    "Host wall seconds per tick phase (delivery: event decode + "
-    "interest-edge application; persist: entity snapshot packing).",
-    ("phase",))
 _M_FUSED_DELIVERY_EVENTS = telemetry.counter(
     "aoi_fused_delivery_events_total",
     "AOI events routed through the fused interest-edge decode "
@@ -1348,10 +1344,9 @@ class BatchAOIService:
             enters = _local(enters)
             leaves = _local(leaves)
         n_e, n_l = len(enters), len(leaves)
-        t_deliver = time.perf_counter()
-        enters, leaves = self._deliver_fused(pending, enters, leaves)
-        self._dispatch_events(enters, leaves)
-        _M_HOST_PHASE.labels("delivery").inc(time.perf_counter() - t_deliver)
+        with engine_span("delivery"):
+            enters, leaves = self._deliver_fused(pending, enters, leaves)
+            self._dispatch_events(enters, leaves)
         # Recycle quarantined slots whose leave events have now been emitted.
         self.slabs.recycle(recyclable)
         return n_e, n_l

@@ -31,6 +31,7 @@ from goworld_tpu.entity.game_client import GameClient
 from goworld_tpu.entity.slabs import EntitySlabs
 from goworld_tpu.entity.space import SPACE_KIND_NIL, Space
 from goworld_tpu.entity.vector import Vector3
+from goworld_tpu.telemetry.phases import AOI_HOST_PHASE
 from goworld_tpu.utils import gwlog, gwutils, post as post_mod
 from goworld_tpu.utils.timer import TimerService
 
@@ -47,14 +48,10 @@ _HOP = telemetry.counter(
 _HOP_COLLECT = _HOP.labels("game_collect")
 _HOP_PACK = _HOP.labels("game_pack")
 
-# Host-phase attribution, persist half (the delivery half lives in
-# aoi/batched.py — telemetry.counter get-or-creates, so both modules share
-# one family): wall seconds spent building freeze/migrate/save snapshots,
-# including the columnar batch gather that feeds them.
-_PHASE_PERSIST = telemetry.counter(
-    "aoi_host_phase_seconds_total",
-    "Busy wall seconds per host-side tick phase (delivery|persist).",
-    ("phase",)).labels("persist")
+# Host-phase attribution, persist half (telemetry.phases owns the family):
+# wall seconds spent building freeze/migrate/save snapshots, including
+# the columnar batch gather that feeds them.
+_PHASE_PERSIST = AOI_HOST_PHASE.labels("persist")
 
 
 class Runtime:

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from goworld_tpu.telemetry import sentinel
+from goworld_tpu.telemetry.phases import engine_span
 
 # Launch/trace accounting for every step jit built below; this module
 # already owns the process's first jax import, so the persistent
@@ -752,6 +753,7 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
                 pltpu.SemaphoreType.DMA((2,)),
             ],
             interpret=interpret,
+            name="aoi_event_kernel",
         )
     tiles = pair_tiles(drain_inline * (2 if dual else 1))
     return pl.pallas_call(
@@ -780,6 +782,7 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
             pltpu.SMEM((2, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="aoi_event_kernel",
     )
 
 
@@ -817,123 +820,125 @@ def _drain_bits(
     fewer rows than ``capacity`` there (own rows only); the pair's entity
     side is then a ROW index the caller maps to a slot.
     """
-    if max_events is None:
-        max_events = p.max_events
-    start_rank = start_flat
-    n = p.capacity
-    n_rows = packed_e.shape[0]
-    gxl = p.grid_x if gx_ext is None else gx_ext
-    pc = jax.lax.population_count(packed_e)  # [N, W]
-    row_counts = jnp.sum(pc, axis=1)  # [N]
-    row_cum = jnp.cumsum(row_counts)  # inclusive
-    row_starts = row_cum - row_counts  # exclusive
-    total = row_cum[-1]
+    with jax.named_scope("aoi.drain"):
+        if max_events is None:
+            max_events = p.max_events
+        start_rank = start_flat
+        n = p.capacity
+        n_rows = packed_e.shape[0]
+        gxl = p.grid_x if gx_ext is None else gx_ext
+        pc = jax.lax.population_count(packed_e)  # [N, W]
+        row_counts = jnp.sum(pc, axis=1)  # [N]
+        row_cum = jnp.cumsum(row_counts)  # inclusive
+        row_starts = row_cum - row_counts  # exclusive
+        total = row_cum[-1]
 
-    j = start_rank + jnp.arange(max_events, dtype=jnp.int32)
-    valid = j < total
-    if p.drain_mode == "scatter":
-        # Row-of-rank over the CONTIGUOUS range [start, start+E) is a
-        # monotonic step function: each row with events intersecting the
-        # range claims its first output position (one [N]→[E] scatter-max;
-        # at most one row straddles `start`, and distinct rows have
-        # distinct starts, so positions are unique), then cummax fills
-        # forward — replacing searchsorted's log2(N) gather passes. The
-        # scatter target is max_events-sized, nothing like the 118M-slot
-        # round-2 pathology.
-        first_pos = row_starts - start_rank
-        intersects = (row_counts > 0) & (row_cum > start_rank) & (
-            first_pos < max_events
-        )
-        target = jnp.where(
-            intersects, jnp.maximum(first_pos, 0), max_events
-        )
-        seed = jnp.full((max_events,), -1, jnp.int32)
-        seed = seed.at[target].max(
-            jnp.arange(n_rows, dtype=jnp.int32), mode="drop"
-        )
-        row = jnp.clip(jax.lax.cummax(seed), 0, n_rows - 1)
-    else:
-        row = (
-            jnp.searchsorted(row_starts, j, side="right").astype(jnp.int32)
-            - 1
-        )
-        row = jnp.clip(row, 0, n_rows - 1)
-    k = j - row_starts[row]  # event rank within its row
+        j = start_rank + jnp.arange(max_events, dtype=jnp.int32)
+        valid = j < total
+        if p.drain_mode == "scatter":
+            # Row-of-rank over the CONTIGUOUS range [start, start+E) is a
+            # monotonic step function: each row with events intersecting
+            # the range claims its first output position (one [N]→[E]
+            # scatter-max; at most one row straddles `start`, and distinct
+            # rows have distinct starts, so positions are unique), then
+            # cummax fills forward — replacing searchsorted's log2(N)
+            # gather passes. The scatter target is max_events-sized,
+            # nothing like the 118M-slot round-2 pathology.
+            first_pos = row_starts - start_rank
+            intersects = (row_counts > 0) & (row_cum > start_rank) & (
+                first_pos < max_events
+            )
+            target = jnp.where(
+                intersects, jnp.maximum(first_pos, 0), max_events
+            )
+            seed = jnp.full((max_events,), -1, jnp.int32)
+            seed = seed.at[target].max(
+                jnp.arange(n_rows, dtype=jnp.int32), mode="drop"
+            )
+            row = jnp.clip(jax.lax.cummax(seed), 0, n_rows - 1)
+        else:
+            row = (
+                jnp.searchsorted(row_starts, j, side="right").astype(jnp.int32)
+                - 1
+            )
+            row = jnp.clip(row, 0, n_rows - 1)
+        k = j - row_starts[row]  # event rank within its row
 
-    # Word selection by binary search over the row's inclusive word-count
-    # cumsum: computed ONCE as [N, W] and probed with ceil(log2(W+1)) flat
-    # [E] gathers. (The round-3 predecessor gathered each event's full
-    # 72-word row and re-cumsummed it — [E, W] traffic ~7x this, measured
-    # on-chip 2026-07-30.)
-    nw = pc.shape[1]
-    word_cum = jnp.cumsum(pc, axis=1)  # [N, W] inclusive
-    if p.drain_mode in ("grouped", "scatter"):
-        # Two-level select via CONTIGUOUS row gathers: the bsearch mode's
-        # ~log2(W) random scalar gathers per event are latency-bound on
-        # TPU; here each event pulls its row's [G] group cumsums and the
-        # [gsz] words of the chosen group in two row gathers, then finds
-        # group/word with wide prefix compares (VPU-friendly).
-        # Invariant: word w holds rank k iff word_cum[w] > k and
-        # word_cum[w-1] <= k, so index = count of inclusive cumsums <= k.
-        gsz = 8
-        ng = (nw + gsz - 1) // gsz
-        pad = ng * gsz - nw
-        # edge-pad: padded words repeat the last cumsum (popcount 0).
-        wc_pad = jnp.pad(word_cum, ((0, 0), (0, pad)), mode="edge")
-        group_cum = wc_pad[:, gsz - 1 :: gsz]  # [N, G] inclusive per group
-        g_rows = group_cum[row]  # [E, G]
-        g = jnp.sum((g_rows <= k[:, None]).astype(jnp.int32), axis=1)
-        g = jnp.minimum(g, ng - 1)
-        # The chosen group's word cumsums per event: [E, gsz].
-        idx = (row * (ng * gsz) + g * gsz)[:, None] + jnp.arange(
-            gsz, dtype=jnp.int32
-        )[None, :]
-        wg = wc_pad.reshape(-1)[idx]
-        wi = jnp.sum((wg <= k[:, None]).astype(jnp.int32), axis=1)
-        w = jnp.minimum(g * gsz + wi, nw - 1)
-        ev = jnp.arange(max_events)
-        # Exclusive cumsum at w: last word of the previous group when the
-        # event is the group's first word, else the group-local neighbor.
-        prev_in_group = wg[ev, jnp.maximum(wi - 1, 0)]
-        prev_group_end = jnp.where(g > 0, g_rows[ev, jnp.maximum(g - 1, 0)], 0)
-        word_start = jnp.where(wi > 0, prev_in_group, prev_group_end)
-        kk = k - word_start  # set-bit rank within the word
-    else:
-        wc_flat = word_cum.reshape(-1)
-        pc_flat = pc.reshape(-1)
-        base = row * nw
-        lo = jnp.zeros((max_events,), jnp.int32)
-        hi = jnp.full((max_events,), nw, jnp.int32)
-        for _ in range(max(1, nw.bit_length())):
-            mid = jnp.minimum((lo + hi) // 2, nw - 1)
-            gt = wc_flat[base + mid] > k
-            hi = jnp.where(gt, mid, hi)
-            lo = jnp.where(gt, lo, mid + 1)
-        w = jnp.minimum(lo, nw - 1)
-        word_start = wc_flat[base + w] - pc_flat[base + w]
-        kk = k - word_start  # set-bit rank within the word
+        # Word selection by binary search over the row's inclusive word-count
+        # cumsum: computed ONCE as [N, W] and probed with ceil(log2(W+1)) flat
+        # [E] gathers. (The round-3 predecessor gathered each event's full
+        # 72-word row and re-cumsummed it — [E, W] traffic ~7x this, measured
+        # on-chip 2026-07-30.)
+        nw = pc.shape[1]
+        word_cum = jnp.cumsum(pc, axis=1)  # [N, W] inclusive
+        if p.drain_mode in ("grouped", "scatter"):
+            # Two-level select via CONTIGUOUS row gathers: the bsearch mode's
+            # ~log2(W) random scalar gathers per event are latency-bound on
+            # TPU; here each event pulls its row's [G] group cumsums and the
+            # [gsz] words of the chosen group in two row gathers, then finds
+            # group/word with wide prefix compares (VPU-friendly).
+            # Invariant: word w holds rank k iff word_cum[w] > k and
+            # word_cum[w-1] <= k, so index = count of inclusive cumsums <= k.
+            gsz = 8
+            ng = (nw + gsz - 1) // gsz
+            pad = ng * gsz - nw
+            # edge-pad: padded words repeat the last cumsum (popcount 0).
+            wc_pad = jnp.pad(word_cum, ((0, 0), (0, pad)), mode="edge")
+            group_cum = wc_pad[:, gsz - 1 :: gsz]  # [N, G] inclusive per group
+            g_rows = group_cum[row]  # [E, G]
+            g = jnp.sum((g_rows <= k[:, None]).astype(jnp.int32), axis=1)
+            g = jnp.minimum(g, ng - 1)
+            # The chosen group's word cumsums per event: [E, gsz].
+            idx = (row * (ng * gsz) + g * gsz)[:, None] + jnp.arange(
+                gsz, dtype=jnp.int32
+            )[None, :]
+            wg = wc_pad.reshape(-1)[idx]
+            wi = jnp.sum((wg <= k[:, None]).astype(jnp.int32), axis=1)
+            w = jnp.minimum(g * gsz + wi, nw - 1)
+            ev = jnp.arange(max_events)
+            # Exclusive cumsum at w: last word of the previous group when the
+            # event is the group's first word, else the group-local neighbor.
+            prev_in_group = wg[ev, jnp.maximum(wi - 1, 0)]
+            prev_group_end = jnp.where(
+                g > 0, g_rows[ev, jnp.maximum(g - 1, 0)], 0)
+            word_start = jnp.where(wi > 0, prev_in_group, prev_group_end)
+            kk = k - word_start  # set-bit rank within the word
+        else:
+            wc_flat = word_cum.reshape(-1)
+            pc_flat = pc.reshape(-1)
+            base = row * nw
+            lo = jnp.zeros((max_events,), jnp.int32)
+            hi = jnp.full((max_events,), nw, jnp.int32)
+            for _ in range(max(1, nw.bit_length())):
+                mid = jnp.minimum((lo + hi) // 2, nw - 1)
+                gt = wc_flat[base + mid] > k
+                hi = jnp.where(gt, mid, hi)
+                lo = jnp.where(gt, lo, mid + 1)
+            w = jnp.minimum(lo, nw - 1)
+            word_start = wc_flat[base + w] - pc_flat[base + w]
+            kk = k - word_start  # set-bit rank within the word
 
-    word = packed_e[row, w]
-    bits = (word[:, None] >> jnp.arange(_PACK, dtype=jnp.int32)) & 1
-    bcum = jnp.cumsum(bits, axis=1)  # inclusive set-bit counts
-    b = jnp.sum((bcum <= kk[:, None]).astype(jnp.int32), axis=1)
-    b = jnp.minimum(b, _PACK - 1)
+        word = packed_e[row, w]
+        bits = (word[:, None] >> jnp.arange(_PACK, dtype=jnp.int32)) & 1
+        bcum = jnp.cumsum(bits, axis=1)  # inclusive set-bit counts
+        b = jnp.sum((bcum <= kk[:, None]).astype(jnp.int32), axis=1)
+        b = jnp.minimum(b, _PACK - 1)
 
-    c = w * _PACK + b  # candidate index within the row's 3x3 halo
-    hc = c // LANES
-    lane = c % LANES
-    dzo = hc // 3 - 1
-    dxo = hc % 3 - 1
-    czz = jnp.mod(cz[row] + dzo, p.grid_z)
-    if wrap_x:
-        cxx = jnp.mod(cx[row] + dxo, gxl)
-    else:
-        cxx = cx[row] + dxo  # strip slab: ghost columns are physical
-    bucket = (sm[row] * p.grid_z + czz) * gxl + cxx
-    other = table[bucket * LANES + lane]
-    ent = jnp.where(valid, row, n)
-    other = jnp.where(valid, other, n)
-    return jnp.stack([ent, other], axis=1), total
+        c = w * _PACK + b  # candidate index within the row's 3x3 halo
+        hc = c // LANES
+        lane = c % LANES
+        dzo = hc // 3 - 1
+        dxo = hc % 3 - 1
+        czz = jnp.mod(cz[row] + dzo, p.grid_z)
+        if wrap_x:
+            cxx = jnp.mod(cx[row] + dxo, gxl)
+        else:
+            cxx = cx[row] + dxo  # strip slab: ghost columns are physical
+        bucket = (sm[row] * p.grid_z + czz) * gxl + cxx
+        other = table[bucket * LANES + lane]
+        ent = jnp.where(valid, row, n)
+        other = jnp.where(valid, other, n)
+        return jnp.stack([ent, other], axis=1), total
 
 
 def _step_pallas(
@@ -960,38 +965,47 @@ def _step_pallas(
     kernel = _compiled_event_kernel(p, interpret)
     kernel_dual = _compiled_event_kernel(p, interpret, dual=True)
 
-    cxc, czc, smc = _bins(p, pos, spc)
-    cxp, czp, smp = pcx, pcz, psm
-    buc_c = (smc * p.grid_z + czc) * p.grid_x + cxc
-    table_c, slot_c, dropped_c, order_c, dst_c = _build_table(
-        p, buc_c, act, LANES
-    )
-    table_p, slot_p = ptable, pslot
+    # Stage names (jax.named_scope) ride each op's metadata into the
+    # compiled program and the profiler's trace: aoi.table, aoi.feats,
+    # aoi.guard, aoi.gather, aoi.drain (inside _drain_bits too), aoi.pack;
+    # the kernel is named aoi_event_kernel.
+    with jax.named_scope("aoi.table"):
+        cxc, czc, smc = _bins(p, pos, spc)
+        cxp, czp, smp = pcx, pcz, psm
+        buc_c = (smc * p.grid_z + czc) * p.grid_x + cxc
+        table_c, slot_c, dropped_c, order_c, dst_c = _build_table(
+            p, buc_c, act, LANES
+        )
+        table_p, slot_p = ptable, pslot
 
     # Each epoch's x row is poisoned by its OWN slot validity: an entity
     # outside epoch E's table (inactive or capacity-dropped that tick) must
     # be invalid under E even when its row is written through the OTHER
     # epoch's table — e.g. a fresh spawn's stale previous position must not
     # suppress its enter event.
-    xs_c = jnp.where(slot_c >= 0, pos[:, 0], jnp.nan)
-    xs_p = jnp.where(slot_p >= 0, ppos[:, 0], jnp.nan)
-    cur_feats = (xs_c, pos[:, 1], spc, rad)
-    prev_feats = (xs_p, ppos[:, 1], pspc, prad)
-    cells_c = _scatter_feats(p, dst_c, order_c, cur_feats, prev_feats)
+    with jax.named_scope("aoi.feats"):
+        xs_c = jnp.where(slot_c >= 0, pos[:, 0], jnp.nan)
+        xs_p = jnp.where(slot_p >= 0, ppos[:, 0], jnp.nan)
+        cur_feats = (xs_c, pos[:, 1], spc, rad)
+        prev_feats = (xs_p, ppos[:, 1], pspc, prad)
+        cells_c = _scatter_feats(p, dst_c, order_c, cur_feats, prev_feats)
 
     # dropped_c == 0 is required: a capacity-dropped entity is absent from
     # table_c entirely, so the single-launch path could never see its
     # epoch-B pairs — its neighbors' leave events must come from the
     # previous grid, where it is still tabled (code-review r3 finding).
-    fast = _fast_guard(p, ppos, pact, pspc, prad, pos, act, spc, dropped_c)
+    with jax.named_scope("aoi.guard"):
+        fast = _fast_guard(p, ppos, pact, pspc, prad, pos, act, spc,
+                           dropped_c)
 
     w_words = 9 * LANES // _PACK
 
     def per_entity(packed_cells, slot):
-        nw = packed_cells.shape[-1]
-        flat = packed_cells.reshape(-1, nw)
-        safe = jnp.maximum(slot, 0)
-        return jnp.where((slot >= 0)[:, None], flat[safe], 0)
+        with jax.named_scope("aoi.gather"):
+            nw = packed_cells.shape[-1]
+            flat = packed_cells.reshape(-1, nw)
+            safe = jnp.maximum(slot, 0)
+            return jnp.where((slot >= 0)[:, None], flat[safe], 0)
 
     # Each branch returns its PER-ENTITY masks with the grid artifacts the
     # leave mask was computed on (current grid in fast mode, previous
@@ -1005,7 +1019,8 @@ def _step_pallas(
                 cxc, czc, smc, table_c)
 
     def slow_fn():
-        cells_p = _scatter_feats(p, pdst, porder, prev_feats, cur_feats)
+        with jax.named_scope("aoi.feats"):
+            cells_p = _scatter_feats(p, pdst, porder, prev_feats, cur_feats)
         return (per_entity(kernel(cells_c), slot_c),
                 per_entity(kernel(cells_p), slot_p),
                 cxp, czp, smp, table_p)
@@ -1013,21 +1028,25 @@ def _step_pallas(
     packed_e, packed_l, lcx, lcz, lsm, ltable = (
         jax.lax.cond(fast, fast_fn, slow_fn)
     )
-    n_enters = jnp.sum(jax.lax.population_count(packed_e)).astype(jnp.int32)
-    n_leaves = jnp.sum(jax.lax.population_count(packed_l)).astype(jnp.int32)
+    with jax.named_scope("aoi.drain"):
+        n_enters = jnp.sum(
+            jax.lax.population_count(packed_e)).astype(jnp.int32)
+        n_leaves = jnp.sum(
+            jax.lax.population_count(packed_l)).astype(jnp.int32)
 
     ep, _ = _drain_bits(p, packed_e, cxc, czc, smc, table_c, jnp.int32(0))
     lp, _ = _drain_bits(p, packed_l, lcx, lcz, lsm, ltable, jnp.int32(0))
     # Rank-based paging resumes at max_events, so the cursor row is unused.
-    zero = jnp.int32(0)
-    header = jnp.stack(
-        [
-            jnp.stack([n_enters, n_leaves]),
-            jnp.stack([dropped_c, zero]),
-            jnp.stack([zero, zero]),
-        ]
-    ).astype(jnp.int32)
-    out = jnp.concatenate([header, ep, lp], axis=0)
+    with jax.named_scope("aoi.pack"):
+        zero = jnp.int32(0)
+        header = jnp.stack(
+            [
+                jnp.stack([n_enters, n_leaves]),
+                jnp.stack([dropped_c, zero]),
+                jnp.stack([zero, zero]),
+            ]
+        ).astype(jnp.int32)
+        out = jnp.concatenate([header, ep, lp], axis=0)
     # Paging context: everything _drain_bits needs for overflow chunks.
     enter_ctx = (packed_e, cxc, czc, smc, table_c)
     leave_ctx = (packed_l, lcx, lcz, lsm, ltable)
@@ -1063,25 +1082,26 @@ def _apply_fused_logic(programs, pos, y, yaw, sel, dt, cols):
     arrays. The Python loop over ``programs`` runs at TRACE time — the
     compiled launch contains only the unrolled elementwise ops. Returns
     (new_pos [N,2], new_y, new_yaw, new_cols tuple)."""
-    x = pos[:, 0]
-    z = pos[:, 1]
-    new = [x, y, z, yaw]
-    out_cols = list(cols)
-    off = 0
-    for k, prog in enumerate(programs):
-        nc = len(prog.columns)
-        pc = tuple(cols[off + i] for i in range(nc))
-        outs = _fused_program_apply(prog, x, y, z, yaw, dt, pc)
-        m = sel == jnp.int32(k + 1)
-        for i in range(4):
-            new[i] = jnp.where(m, outs[i].astype(new[i].dtype), new[i])
-        for i in range(nc):
-            base = out_cols[off + i]
-            out_cols[off + i] = jnp.where(
-                m, outs[4 + i].astype(base.dtype), base)
-        off += nc
-    new_pos = jnp.stack([new[0], new[2]], axis=1)
-    return new_pos, new[1], new[3], tuple(out_cols)
+    with jax.named_scope("aoi.logic"):
+        x = pos[:, 0]
+        z = pos[:, 1]
+        new = [x, y, z, yaw]
+        out_cols = list(cols)
+        off = 0
+        for k, prog in enumerate(programs):
+            nc = len(prog.columns)
+            pc = tuple(cols[off + i] for i in range(nc))
+            outs = _fused_program_apply(prog, x, y, z, yaw, dt, pc)
+            m = sel == jnp.int32(k + 1)
+            for i in range(4):
+                new[i] = jnp.where(m, outs[i].astype(new[i].dtype), new[i])
+            for i in range(nc):
+                base = out_cols[off + i]
+                out_cols[off + i] = jnp.where(
+                    m, outs[4 + i].astype(base.dtype), base)
+            off += nc
+        new_pos = jnp.stack([new[0], new[2]], axis=1)
+        return new_pos, new[1], new[3], tuple(out_cols)
 
 
 def _step_packed_fused_jnp(
@@ -1137,7 +1157,8 @@ def _jitted_step_packed_fused(params: NeighborParams, backend: str,
             _step_packed_fused_pallas, params,
             backend == "pallas_interpret", programs,
         )
-    return sentinel.SentinelJit(f"aoi_step_fused_{backend}", jax.jit(fn))
+    return sentinel.SentinelJit(f"aoi_step_fused_{backend}",
+                                jax.jit(_named("aoi_step_fused", fn)))
 
 
 # --- sync cadence tier pass ([sync]; rides the step launch) ------------------
@@ -1159,24 +1180,25 @@ def _tier_pass(pos, ppos, radius, subj, wat, n_tiers: int,
     conservative default). Distance uses the CURRENT epoch; a pair whose
     distance shrank since the PREVIOUS epoch is approaching and drops one
     tier toward full rate."""
-    n = pos.shape[0]
-    valid = (subj >= 0) & (subj < n) & (wat >= 0) & (wat < n)
-    s = jnp.clip(subj, 0, n - 1)
-    w = jnp.clip(wat, 0, n - 1)
-    d = pos[s] - pos[w]
-    d2 = d[:, 0] * d[:, 0] + d[:, 1] * d[:, 1]
-    pd = ppos[s] - ppos[w]
-    pd2 = pd[:, 0] * pd[:, 0] + pd[:, 1] * pd[:, 1]
-    r = radius[w]
-    r2 = jnp.maximum(r * r, jnp.float32(1e-12))
-    ratio = jnp.sqrt(d2 / r2)
-    span = max(far_ratio - near_ratio, 1e-9)
-    tier = 1 + jnp.floor(
-        (ratio - near_ratio) / span * (n_tiers - 1)).astype(jnp.int32)
-    tier = jnp.clip(tier, 0, n_tiers - 1)
-    tier = jnp.where(ratio <= near_ratio, 0, tier)
-    tier = jnp.where(d2 < pd2, jnp.maximum(tier - 1, 0), tier)
-    return jnp.where(valid, tier, 0).astype(jnp.uint8)
+    with jax.named_scope("aoi.tier"):
+        n = pos.shape[0]
+        valid = (subj >= 0) & (subj < n) & (wat >= 0) & (wat < n)
+        s = jnp.clip(subj, 0, n - 1)
+        w = jnp.clip(wat, 0, n - 1)
+        d = pos[s] - pos[w]
+        d2 = d[:, 0] * d[:, 0] + d[:, 1] * d[:, 1]
+        pd = ppos[s] - ppos[w]
+        pd2 = pd[:, 0] * pd[:, 0] + pd[:, 1] * pd[:, 1]
+        r = radius[w]
+        r2 = jnp.maximum(r * r, jnp.float32(1e-12))
+        ratio = jnp.sqrt(d2 / r2)
+        span = max(far_ratio - near_ratio, 1e-9)
+        tier = 1 + jnp.floor(
+            (ratio - near_ratio) / span * (n_tiers - 1)).astype(jnp.int32)
+        tier = jnp.clip(tier, 0, n_tiers - 1)
+        tier = jnp.where(ratio <= near_ratio, 0, tier)
+        tier = jnp.where(d2 < pd2, jnp.maximum(tier - 1, 0), tier)
+        return jnp.where(valid, tier, 0).astype(jnp.uint8)
 
 
 def _edge_verdicts(p: NeighborParams, out, subj, wat):
@@ -1240,7 +1262,7 @@ def _jitted_step_packed_tiered(params: NeighborParams, backend: str,
     # carries 7 carried-grid artifacts first.
     off = 0 if backend == "jnp" else 7
 
-    def fn(subj, wat, ppos, pact, pspc, prad, *rest):
+    def aoi_step_tiered(subj, wat, ppos, pact, pspc, prad, *rest):
         outs = base(ppos, pact, pspc, prad, *rest)
         if tier_cfg is not None:
             n_tiers, near_ratio, far_ratio = tier_cfg
@@ -1253,7 +1275,7 @@ def _jitted_step_packed_tiered(params: NeighborParams, backend: str,
 
     label = ("aoi_step_tiered_" if tier_cfg is not None
              else "aoi_step_verdict_") + backend
-    return sentinel.SentinelJit(label, jax.jit(fn))
+    return sentinel.SentinelJit(label, jax.jit(aoi_step_tiered))
 
 
 def tier_edge_capacity(n_edges: int) -> int:
@@ -1267,6 +1289,18 @@ def tier_edge_capacity(n_edges: int) -> int:
 
 
 # --- jit wrappers ------------------------------------------------------------
+
+
+def _named(name: str, fn):
+    """``fn`` (a ``functools.partial``) as a function called ``name``: the
+    jit's program is then ``jit_<name>`` in the profiler's trace and the
+    compile cache, not ``jit__unknown``."""
+
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 @functools.lru_cache(maxsize=None)
@@ -1286,22 +1320,24 @@ def _jitted_step_packed(params: NeighborParams, backend: str):
     # buffers; likewise the previous meta arrays (act/space/radius), which
     # with ``meta_dirty=False`` are the SAME device buffers as the current
     # epoch's meta.
-    return sentinel.SentinelJit(f"aoi_step_{backend}", jax.jit(fn))
+    return sentinel.SentinelJit(f"aoi_step_{backend}",
+                                jax.jit(_named("aoi_step", fn)))
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_drain_ids(params: NeighborParams):
-    return sentinel.SentinelJit("aoi_drain_ids", jax.jit(
-        functools.partial(
+    return sentinel.SentinelJit("aoi_drain_ids", jax.jit(_named(
+        "aoi_drain_ids", functools.partial(
             _drain_ids, n=params.capacity, max_events=params.max_events
         )
-    ))
+    )))
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_drain_bits(params: NeighborParams):
     return sentinel.SentinelJit(
-        "aoi_drain_bits", jax.jit(functools.partial(_drain_bits, params)))
+        "aoi_drain_bits", jax.jit(_named(
+            "aoi_drain_bits", functools.partial(_drain_bits, params))))
 
 
 # --- host-facing engine ------------------------------------------------------
@@ -1384,32 +1420,37 @@ class PendingStep:
         jax.block_until_ready(self._out)
 
     def collect(self) -> tuple[np.ndarray, np.ndarray, int]:
-        """Fetch (enter_pairs, leave_pairs, dropped); one blocking read."""
+        """Fetch (enter_pairs, leave_pairs, dropped); one blocking read.
+        Host phases: ``wait`` for the device, ``readback`` of the packed
+        result, ``page`` per pager call (telemetry.phases.engine_span)."""
         assert not self._collected, "PendingStep already collected"
         self._collected = True
         eng = self._engine
         p = eng.params
         e = p.max_events
-        out = np.asarray(self._out)  # THE round trip
-        n_e, n_l = int(out[0, 0]), int(out[0, 1])
-        dropped = int(out[1, 0])
-        enter_last, leave_last = int(out[2, 0]), int(out[2, 1])
-        enters = out[3:3 + min(n_e, e)]
-        leaves = out[3 + e:3 + e + min(n_l, e)]
+        with engine_span("wait"):
+            self._out.block_until_ready()
+        with engine_span("readback"):
+            out = np.asarray(self._out)  # THE round trip
+            n_e, n_l = int(out[0, 0]), int(out[0, 1])
+            dropped = int(out[1, 0])
+            enter_last, leave_last = int(out[2, 0]), int(out[2, 1])
+            enters = out[3:3 + min(n_e, e)]
+            leaves = out[3 + e:3 + e + min(n_l, e)]
         # Storm paging (rare): the pallas drain pages by event RANK (resume
         # at e), the jnp drain by flat matrix index (resume after the last
         # drained position).
         rank_paging = eng.backend != "jnp"
         if n_e > e:
-            enters = np.concatenate(
-                [enters,
-                 self._pager("enter", n_e - e, e if rank_paging else enter_last + 1)]
-            )
+            with engine_span("page"):
+                more = self._pager(
+                    "enter", n_e - e, e if rank_paging else enter_last + 1)
+            enters = np.concatenate([enters, more])
         if n_l > e:
-            leaves = np.concatenate(
-                [leaves,
-                 self._pager("leave", n_l - e, e if rank_paging else leave_last + 1)]
-            )
+            with engine_span("page"):
+                more = self._pager(
+                    "leave", n_l - e, e if rank_paging else leave_last + 1)
+            leaves = np.concatenate([leaves, more])
         eng.last_grid_dropped = dropped
         if dropped:
             from goworld_tpu.utils import gwlog
@@ -1550,75 +1591,78 @@ class NeighborEngine:
         ``cols`` the flat per-program column arrays.
         """
         assert self._state is not None, "call reset() first"
-        check_radius(self.params, radius, active)
-        if self.backend != "jnp":
-            check_space_ids(space, active)
-        # jnp.array (not asarray): the arrays become next tick's PREVIOUS
-        # state, so they must not alias the caller's numpy buffers — on the
-        # CPU backend a zero-copy view would silently mutate history when
-        # game code updates positions in place.
-        if meta_dirty:
-            meta = (
-                jnp.array(active, jnp.bool_),
-                jnp.array(space, jnp.int32),
-                jnp.array(radius, jnp.float32),
-            )
-        else:
-            meta = self._state[1:4]
-        cur = (jnp.array(pos, jnp.float32),) + meta
         fused_out = None
         tier_out = None
         tier_meta = None
+        verdict_out = None
         extra: tuple = ()
         programs: tuple | None = None
-        if logic is not None:
-            programs, sel, y, yaw, dt, cols = logic
-            programs = tuple(programs)
-            extra = (
-                jnp.array(y, jnp.float32),
-                jnp.array(yaw, jnp.float32),
-                jnp.array(sel, jnp.int32),
-                jnp.float32(dt),
-            ) + tuple(jnp.array(c) for c in cols)
-        verdict_out = None
-        if tiers is not None:
-            # ``tiers = (edge_version, n_edges, subj_pad, wat_pad,
-            # (n_tiers, near_ratio, far_ratio)[, want_verdicts])`` — the
-            # [sync] cadence tier pass and/or the fused-delivery edge
-            # verdict pass ride the SAME launch as the step (+ any fused
-            # logic); the outputs are the step outputs plus one uint8
-            # vector per requested pass. A 5-tuple is the legacy
-            # tiers-only payload; the 6-tuple may set the tier config to
-            # None for a verdicts-only launch.
-            if len(tiers) == 5:
-                t_ver, t_n, subj_pad, wat_pad, tcfg = tiers
-                want_verdicts = False
+        with engine_span("upload"):
+            check_radius(self.params, radius, active)
+            if self.backend != "jnp":
+                check_space_ids(space, active)
+            # jnp.array (not asarray): the arrays become next tick's
+            # PREVIOUS state, so they must not alias the caller's numpy
+            # buffers — on the CPU backend a zero-copy view would silently
+            # mutate history when game code updates positions in place.
+            if meta_dirty:
+                meta = (
+                    jnp.array(active, jnp.bool_),
+                    jnp.array(space, jnp.int32),
+                    jnp.array(radius, jnp.float32),
+                )
             else:
-                t_ver, t_n, subj_pad, wat_pad, tcfg, want_verdicts = tiers
-            tier_meta = (t_ver, t_n)
-            jit_tiered = _jitted_step_packed_tiered(
-                self.params, self.backend, programs,
-                tuple(tcfg) if tcfg is not None else None,
-                len(subj_pad), want_verdicts,
-            )
-            outs = jit_tiered(
-                jnp.array(subj_pad, jnp.int32),
-                jnp.array(wat_pad, jnp.int32),
-                *self._state, *cur, *extra,
-            )
-            if want_verdicts:
-                verdict_out = outs[-1]
-                outs = outs[:-1]
-            if tcfg is not None:
-                tier_out = outs[-1]
-                outs = outs[:-1]
-        elif logic is not None:
-            jit_fused = _jitted_step_packed_fused(
-                self.params, self.backend, programs
-            )
-            outs = jit_fused(*self._state, *cur, *extra)
-        else:
-            outs = self._jit_step(*self._state, *cur)
+                meta = self._state[1:4]
+            cur = (jnp.array(pos, jnp.float32),) + meta
+            if logic is not None:
+                programs, sel, y, yaw, dt, cols = logic
+                programs = tuple(programs)
+                extra = (
+                    jnp.array(y, jnp.float32),
+                    jnp.array(yaw, jnp.float32),
+                    jnp.array(sel, jnp.int32),
+                    jnp.float32(dt),
+                ) + tuple(jnp.array(c) for c in cols)
+        with engine_span("launch"):
+            if tiers is not None:
+                # ``tiers = (edge_version, n_edges, subj_pad, wat_pad,
+                # (n_tiers, near_ratio, far_ratio)[, want_verdicts])`` —
+                # the [sync] cadence tier pass and/or the fused-delivery
+                # edge verdict pass ride the SAME launch as the step (+ any
+                # fused logic); the outputs are the step outputs plus one
+                # uint8 vector per requested pass. A 5-tuple is the legacy
+                # tiers-only payload; the 6-tuple may set the tier config
+                # to None for a verdicts-only launch.
+                if len(tiers) == 5:
+                    t_ver, t_n, subj_pad, wat_pad, tcfg = tiers
+                    want_verdicts = False
+                else:
+                    (t_ver, t_n, subj_pad, wat_pad, tcfg,
+                     want_verdicts) = tiers
+                tier_meta = (t_ver, t_n)
+                jit_tiered = _jitted_step_packed_tiered(
+                    self.params, self.backend, programs,
+                    tuple(tcfg) if tcfg is not None else None,
+                    len(subj_pad), want_verdicts,
+                )
+                outs = jit_tiered(
+                    jnp.array(subj_pad, jnp.int32),
+                    jnp.array(wat_pad, jnp.int32),
+                    *self._state, *cur, *extra,
+                )
+                if want_verdicts:
+                    verdict_out = outs[-1]
+                    outs = outs[:-1]
+                if tcfg is not None:
+                    tier_out = outs[-1]
+                    outs = outs[:-1]
+            elif logic is not None:
+                jit_fused = _jitted_step_packed_fused(
+                    self.params, self.backend, programs
+                )
+                outs = jit_fused(*self._state, *cur, *extra)
+            else:
+                outs = self._jit_step(*self._state, *cur)
         if self.backend == "jnp":
             if logic is not None:
                 enter_ids, leave_ids, out, fused_out = outs

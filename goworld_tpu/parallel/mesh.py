@@ -36,6 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from goworld_tpu.telemetry import sentinel
+from goworld_tpu.telemetry.phases import engine_span
 from goworld_tpu.ops.neighbor import (
     LANES,
     _PACK,
@@ -499,7 +500,10 @@ class ShardedPendingStep:
         # Block layout: 3 header rows, nd replicated-counts rows
         # (multihost paging convergence), e enter pairs, e leave pairs.
         block = 3 + nd + 2 * e
-        out = np.asarray(self._out)  # THE round trip
+        with engine_span("wait"):
+            self._out.block_until_ready()
+        with engine_span("readback"):
+            out = np.asarray(self._out)  # THE round trip
         enters, leaves = [], []
         enter_deficit = np.zeros(nd, np.int64)
         leave_deficit = np.zeros(nd, np.int64)
@@ -527,9 +531,13 @@ class ShardedPendingStep:
                 leave_deficit[d] = max(0, n_l - e)
                 leave_starts[d] = e if rank_paging else int(o[2, 1]) + 1
         if enter_deficit.any():
-            enters += eng._page(self._enter_ctx, enter_deficit, enter_starts)
+            with engine_span("page"):
+                enters += eng._page(self._enter_ctx, enter_deficit,
+                                    enter_starts)
         if leave_deficit.any():
-            leaves += eng._page(self._leave_ctx, leave_deficit, leave_starts)
+            with engine_span("page"):
+                leaves += eng._page(self._leave_ctx, leave_deficit,
+                                    leave_starts)
         eng.last_grid_dropped = dropped
         # Header flags (out[1, 1], replicated): the spatial engines report
         # the seam-free fast-tick bit there; other programs write 0.
@@ -674,28 +682,30 @@ class ShardedNeighborEngine:
         """Dispatch one tick without blocking (parity with NeighborEngine,
         including the ``meta_dirty=False`` upload-elision contract)."""
         assert self._state is not None, "call reset() first"
-        check_radius(self.params, radius, active)
-        if self.backend != "jnp":
-            check_space_ids(space, active)
         put = lambda x: jax.device_put(x, self._sharding)  # noqa: E731
-        # np.array (copying, not asarray): state must not alias caller
-        # buffers — see NeighborEngine.step_async. Numpy (not jnp) inputs by
-        # design: see reset().
-        if meta_dirty:
-            meta = (
-                put(np.array(active, bool)),
-                put(np.array(space, np.int32)),
-                put(np.array(radius, np.float32)),
-            )
-        else:
-            meta = self._state[1:4]
-        cur = (put(np.array(pos, np.float32)),) + meta
+        with engine_span("upload"):
+            check_radius(self.params, radius, active)
+            if self.backend != "jnp":
+                check_space_ids(space, active)
+            # np.array (copying, not asarray): state must not alias caller
+            # buffers — see NeighborEngine.step_async. Numpy (not jnp)
+            # inputs by design: see reset().
+            if meta_dirty:
+                meta = (
+                    put(np.array(active, bool)),
+                    put(np.array(space, np.int32)),
+                    put(np.array(radius, np.float32)),
+                )
+            else:
+                meta = self._state[1:4]
+            cur = (put(np.array(pos, np.float32)),) + meta
+        with engine_span("launch"):
+            res = self._jit_step(*self._state, *cur)
         if self.backend == "jnp":
-            enter_ids, leave_ids, out = self._jit_step(*self._state, *cur)
+            enter_ids, leave_ids, out = res
             enter_ctx: tuple = (enter_ids,)
             leave_ctx: tuple = (leave_ids,)
         else:
-            res = self._jit_step(*self._state, *cur)
             enter_ctx, leave_ctx, out = res[0:5], res[5:10], res[10]
         self._state = cur
         _M_ALLGATHER_TOTAL.inc(self.allgather_bytes_per_tick)

@@ -105,6 +105,7 @@ from goworld_tpu.ops.neighbor import (
     untile_pairs,
 )
 from goworld_tpu.telemetry import sentinel
+from goworld_tpu.telemetry.phases import engine_span
 from goworld_tpu.parallel.mesh import (
     SHARD_AXIS,
     ShardedPendingStep,
@@ -180,63 +181,66 @@ def _exchange_halo(
     spatial step bodies — the exchanged bytes are identical on both tiers
     (radius does not travel; ghost queries are never extracted, so their
     radius rows may be zero)."""
-    n = p.capacity
-    chunk = pos_l.shape[0]
+    with jax.named_scope("aoi.halo"):
+        n = p.capacity
+        chunk = pos_l.shape[0]
 
-    def pack_band(idx):
-        safe = jnp.minimum(idx, chunk - 1)
-        pad = idx >= chunk
-        f32b = jnp.stack(
-            [ppos_l[safe, 0], ppos_l[safe, 1], pos_l[safe, 0], pos_l[safe, 1]],
-            axis=1,
+        def pack_band(idx):
+            safe = jnp.minimum(idx, chunk - 1)
+            pad = idx >= chunk
+            f32b = jnp.stack(
+                [ppos_l[safe, 0], ppos_l[safe, 1],
+                 pos_l[safe, 0], pos_l[safe, 1]],
+                axis=1,
+            )
+            i32b = jnp.stack(
+                [pspc_l[safe], spc_l[safe], jnp.where(pad, n, slot_l[safe])],
+                axis=1,
+            )
+            boolb = jnp.stack([pact_l[safe] & ~pad, act_l[safe] & ~pad],
+                              axis=1)
+            return f32b, i32b, boolb
+
+        fwd = [(i, (i + 1) % n_dev) for i in range(n_dev)]
+        bwd = [(i, (i - 1) % n_dev) for i in range(n_dev)]
+
+        def exchange(blocks, perm):
+            return tuple(
+                jax.lax.ppermute(b, SHARD_AXIS, perm=perm) for b in blocks
+            )
+
+        # from_left = my predecessor's high-seam band; from_right = my
+        # successor's low-seam band.
+        from_left = exchange(pack_band(send_hi_idx), fwd)
+        from_right = exchange(pack_band(send_lo_idx), bwd)
+
+        def unpack(blocks):
+            f32b, i32b, boolb = blocks
+            return (
+                f32b[:, 0:2], f32b[:, 2:4],  # ppos, pos
+                i32b[:, 0], i32b[:, 1], i32b[:, 2],  # pspc, spc, slot
+                boolb[:, 0], boolb[:, 1],  # pact, act
+            )
+
+        gl_ppos, gl_pos, gl_pspc, gl_spc, gl_slot, gl_pact, gl_act = unpack(
+            from_left
         )
-        i32b = jnp.stack(
-            [pspc_l[safe], spc_l[safe], jnp.where(pad, n, slot_l[safe])],
-            axis=1,
+        gr_ppos, gr_pos, gr_pspc, gr_spc, gr_slot, gr_pact, gr_act = unpack(
+            from_right
         )
-        boolb = jnp.stack([pact_l[safe] & ~pad, act_l[safe] & ~pad], axis=1)
-        return f32b, i32b, boolb
-
-    fwd = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-    bwd = [(i, (i - 1) % n_dev) for i in range(n_dev)]
-
-    def exchange(blocks, perm):
-        return tuple(
-            jax.lax.ppermute(b, SHARD_AXIS, perm=perm) for b in blocks
-        )
-
-    # from_left = my predecessor's high-seam band; from_right = my
-    # successor's low-seam band.
-    from_left = exchange(pack_band(send_hi_idx), fwd)
-    from_right = exchange(pack_band(send_lo_idx), bwd)
-
-    def unpack(blocks):
-        f32b, i32b, boolb = blocks
+        h = gl_pos.shape[0]
+        zeros_h = jnp.zeros((h,), jnp.float32)
         return (
-            f32b[:, 0:2], f32b[:, 2:4],  # ppos, pos
-            i32b[:, 0], i32b[:, 1], i32b[:, 2],  # pspc, spc, slot
-            boolb[:, 0], boolb[:, 1],  # pact, act
+            jnp.concatenate([pos_l, gl_pos, gr_pos], axis=0),
+            jnp.concatenate([ppos_l, gl_ppos, gr_ppos], axis=0),
+            jnp.concatenate([act_l, gl_act, gr_act]),
+            jnp.concatenate([pact_l, gl_pact, gr_pact]),
+            jnp.concatenate([spc_l, gl_spc, gr_spc]),
+            jnp.concatenate([pspc_l, gl_pspc, gr_pspc]),
+            jnp.concatenate([slot_l, gl_slot, gr_slot]),
+            jnp.concatenate([rad_l, zeros_h, zeros_h]),
+            jnp.concatenate([prad_l, zeros_h, zeros_h]),
         )
-
-    gl_ppos, gl_pos, gl_pspc, gl_spc, gl_slot, gl_pact, gl_act = unpack(
-        from_left
-    )
-    gr_ppos, gr_pos, gr_pspc, gr_spc, gr_slot, gr_pact, gr_act = unpack(
-        from_right
-    )
-    h = gl_pos.shape[0]
-    zeros_h = jnp.zeros((h,), jnp.float32)
-    return (
-        jnp.concatenate([pos_l, gl_pos, gr_pos], axis=0),
-        jnp.concatenate([ppos_l, gl_ppos, gr_ppos], axis=0),
-        jnp.concatenate([act_l, gl_act, gr_act]),
-        jnp.concatenate([pact_l, gl_pact, gr_pact]),
-        jnp.concatenate([spc_l, gl_spc, gr_spc]),
-        jnp.concatenate([pspc_l, gl_pspc, gr_pspc]),
-        jnp.concatenate([slot_l, gl_slot, gr_slot]),
-        jnp.concatenate([rad_l, zeros_h, zeros_h]),
-        jnp.concatenate([prad_l, zeros_h, zeros_h]),
-    )
 
 
 def _fast_guard_strip(p: NeighborParams, ppos_l, pact_l, pspc_l, prad_l,
@@ -1373,240 +1377,256 @@ class SpatialShardedNeighborEngine:
         logic: tuple | None = None,
     ):
         assert self._state is not None, "call reset() first"
-        check_radius(self.params, radius, active)
-        if self.backend != "jnp":
-            check_space_ids(space, active)
-        p = self.params
-        gx = p.grid_x
-        # Copies, not views: these become the host prev mirror and must
-        # not alias caller buffers (same contract as the other engines).
-        cur = (
-            np.array(pos, np.float32),
-            np.array(active, bool),
-            np.array(space, np.int32),
-            np.array(radius, np.float32),
-        )
-        cur_pos, cur_act, cur_spc, _ = cur
-        cx = bins_reference(p, cur_pos, cur_spc)[0]
-        self._dispatches += 1
-
-        from goworld_tpu.telemetry import tracing
-
-        halo_span = tracing.child_scope("tick.halo")
-        t0 = time.monotonic()
-
-        perm_rebuilt = False
-        migrations = 0
-        prev_act = self._host_prev[1]
-        # Slow-cadence density re-plan.
-        if (
-            self.replan_interval
-            and self._dispatches % self.replan_interval == 0
-            and self._replan(cx, cur_act)
-        ):
-            self._perm_dirty = True
-        # Hysteresis migration: move a row only when its cell is a full
-        # column past the seam.
-        act_idx = np.flatnonzero(cur_act)
-        keep = self._in_strip_or_band(cx[act_idx], self.assign[act_idx])
-        movers = act_idx[~keep]
-        if len(movers):
-            self.assign[movers] = self._col_owner[cx[movers]]
-            migrations += len(movers)
-            self._perm_dirty = True
-        # Prev-epoch-only rows (freshly despawned) re-home by their
-        # PREVIOUS column: their only remaining job is hosting their
-        # prev-epoch pairs, so an adopted re-plan that moved boundaries
-        # several columns must carry them to the new owner of that cell —
-        # otherwise the stranded prev cell trips the teleport guard and
-        # the tick pays the exact all-gather fallback for no reason.
-        migrations += self._rehome_prev_only(prev_act, cur_act)
-
-        fallback_reason = None
-        # Row placement covers slots live in EITHER epoch: a slot that
-        # just despawned still owns a row on its strip this tick so its
-        # neighbors' leave events resolve there.
-        placed_idx = np.flatnonzero(cur_act | prev_act)
-        counts = np.bincount(
-            self.assign[placed_idx], minlength=self.n_devices
-        ).astype(np.int64)
-        if counts.max(initial=0) > self.chunk:
-            # A strip outgrew its row budget: re-plan NOW; if one column
-            # is hotter than a whole shard's budget even alone, spatial
-            # sharding cannot represent it — exact fallback.
-            if self._replan(cx, cur_act):
-                # Boundary move: reassign by owner column (hysteresis slack
-                # resets), counting only rows that actually changed shard.
-                new_assign = self._col_owner[cx[act_idx]]
-                migrations += int((new_assign != self.assign[act_idx]).sum())
-                self.assign[act_idx] = new_assign
-                self._perm_dirty = True
-                migrations += self._rehome_prev_only(prev_act, cur_act)
-                counts = np.bincount(
-                    self.assign[placed_idx], minlength=self.n_devices
-                ).astype(np.int64)
-            if counts.max(initial=0) > self.chunk:
-                fallback_reason = "strip_overflow"
-        self.shard_population = counts
-
-        if fallback_reason is None:
-            # Teleport guard: every row active in the PREVIOUS epoch must
-            # have its previous cell inside its (current) shard's slack
-            # band, or its leave pass would reach past the halo.
-            pa_idx = np.flatnonzero(prev_act)
-            ok = self._in_strip_or_band(
-                self._prev_cx[pa_idx], self.assign[pa_idx]
-            )
-            if not ok.all():
-                fallback_reason = "teleport"
-
-        if self._perm_dirty and fallback_reason != "strip_overflow":
-            # Bands are expressed as LOCAL row indices, so the layout must
-            # be rebuilt before they are selected. (The dirty flag is
-            # persistent state: a strip-overflow fallback tick defers the
-            # rebuild — chunk cannot hold the strip — without losing it.)
-            self._rebuild_perm(cur_act | prev_act)
-            self._perm_dirty = False
-            perm_rebuilt = True
-        send_lo = send_hi = None
-        if fallback_reason is None:
-            send_lo, send_hi, overflow = self._build_bands(
-                cx, cur_act, prev_act
-            )
-            if overflow:
-                fallback_reason = "halo_overflow"
-        if migrations:
-            self.total_migrations += migrations
-            self._m_migrations.inc(migrations)
-        for d in range(self.n_devices):
-            self._m_shard_entities.labels(str(d)).set(int(counts[d]))
-        if halo_span is not None:
-            halo_span.args["migrations"] = migrations
-            halo_span.args["mode"] = fallback_reason or "spatial"
-            tracing.record_span(
-                halo_span.name, t0, time.monotonic() - t0,
-                halo_span.ctx.trace_id, halo_span.ctx.span_id,
-                halo_span.parent_id, halo_span.args,
-            )
-
-        put = lambda x: jax.device_put(x, self._sharding)  # noqa: E731
-        perm = self.perm
-        if perm_rebuilt:
-            # The previous epoch must live in the NEW layout or the device
-            # diff would read a migration as despawn+spawn. Cheap at the
-            # host tier: four slot-space gathers + uploads.
-            hp = self._host_prev
-            self._state = (
-                put(hp[0][perm]), put(hp[1][perm]),
-                put(hp[2][perm]), put(hp[3][perm]),
-            )
-            self._perm_dev = put(perm)
-        if meta_dirty or perm_rebuilt:
-            meta = (
-                put(cur[1][perm]), put(cur[2][perm]), put(cur[3][perm])
-            )
-        else:
-            meta = self._state[1:4]
-        cur_dev = (put(cur[0][perm]),) + meta
-
-        fused_out = None
-        logic_dev: tuple = ()
-        if logic is not None:
-            # Row-permuted upload of the fused-logic inputs: the programs
-            # run per LOCAL row, so sel/y/yaw/columns travel through the
-            # same perm as the positions; dt rides as a [D] sharded array
-            # (one scalar per shard body).
-            programs, sel, y, yaw, dt, cols = logic
-            logic_dev = (
-                put(np.asarray(y, np.float32)[perm]),
-                put(np.asarray(yaw, np.float32)[perm]),
-                put(np.asarray(sel, np.int32)[perm]),
-                put(np.full(self.n_devices, dt, np.float32)),
-            ) + tuple(put(np.asarray(c)[perm]) for c in cols)
-
-        if fallback_reason is None:
+        with engine_span("upload"):
+            check_radius(self.params, radius, active)
             if self.backend != "jnp":
-                band_args = (
-                    self._perm_dev, put(send_lo), put(send_hi),
-                    self._strip_lo_dev,
+                check_space_ids(space, active)
+            p = self.params
+            # Copies, not views: these become the host prev mirror and
+            # must not alias caller buffers (same contract as the other
+            # engines).
+            cur = (
+                np.array(pos, np.float32),
+                np.array(active, bool),
+                np.array(space, np.int32),
+                np.array(radius, np.float32),
+            )
+        with engine_span("plan"):
+            cur_pos, cur_act, cur_spc, _ = cur
+            cx = bins_reference(p, cur_pos, cur_spc)[0]
+            self._dispatches += 1
+
+            from goworld_tpu.telemetry import tracing
+
+            halo_span = tracing.child_scope("tick.halo")
+            t0 = time.monotonic()
+
+            perm_rebuilt = False
+            migrations = 0
+            prev_act = self._host_prev[1]
+            # Slow-cadence density re-plan.
+            if (
+                self.replan_interval
+                and self._dispatches % self.replan_interval == 0
+                and self._replan(cx, cur_act)
+            ):
+                self._perm_dirty = True
+            # Hysteresis migration: move a row only when its cell is a
+            # full column past the seam.
+            act_idx = np.flatnonzero(cur_act)
+            keep = self._in_strip_or_band(cx[act_idx], self.assign[act_idx])
+            movers = act_idx[~keep]
+            if len(movers):
+                self.assign[movers] = self._col_owner[cx[movers]]
+                migrations += len(movers)
+                self._perm_dirty = True
+            # Prev-epoch-only rows (freshly despawned) re-home by their
+            # PREVIOUS column: their only remaining job is hosting their
+            # prev-epoch pairs, so an adopted re-plan that moved
+            # boundaries several columns must carry them to the new owner
+            # of that cell — otherwise the stranded prev cell trips the
+            # teleport guard and the tick pays the exact all-gather
+            # fallback for no reason.
+            migrations += self._rehome_prev_only(prev_act, cur_act)
+
+            fallback_reason = None
+            # Row placement covers slots live in EITHER epoch: a slot that
+            # just despawned still owns a row on its strip this tick so its
+            # neighbors' leave events resolve there.
+            placed_idx = np.flatnonzero(cur_act | prev_act)
+            counts = np.bincount(
+                self.assign[placed_idx], minlength=self.n_devices
+            ).astype(np.int64)
+            if counts.max(initial=0) > self.chunk:
+                # A strip outgrew its row budget: re-plan NOW; if one
+                # column is hotter than a whole shard's budget even alone,
+                # spatial sharding cannot represent it — exact fallback.
+                if self._replan(cx, cur_act):
+                    # Boundary move: reassign by owner column (hysteresis
+                    # slack resets), counting only rows that actually
+                    # changed shard.
+                    new_assign = self._col_owner[cx[act_idx]]
+                    migrations += int(
+                        (new_assign != self.assign[act_idx]).sum())
+                    self.assign[act_idx] = new_assign
+                    self._perm_dirty = True
+                    migrations += self._rehome_prev_only(prev_act, cur_act)
+                    counts = np.bincount(
+                        self.assign[placed_idx], minlength=self.n_devices
+                    ).astype(np.int64)
+                if counts.max(initial=0) > self.chunk:
+                    fallback_reason = "strip_overflow"
+            self.shard_population = counts
+
+            if fallback_reason is None:
+                # Teleport guard: every row active in the PREVIOUS epoch
+                # must have its previous cell inside its (current) shard's
+                # slack band, or its leave pass would reach past the halo.
+                pa_idx = np.flatnonzero(prev_act)
+                ok = self._in_strip_or_band(
+                    self._prev_cx[pa_idx], self.assign[pa_idx]
                 )
-                if logic is not None:
-                    jit_fused = _jitted_spatial_step_pallas_fused(
-                        self.params, self.mesh, self.events_inline,
-                        self.halo_cap, self.backend == "pallas_interpret",
-                        self.strip_cols, tuple(logic[0]), len(logic[5]),
-                        self.drain_inline,
+                if not ok.all():
+                    fallback_reason = "teleport"
+
+            if self._perm_dirty and fallback_reason != "strip_overflow":
+                # Bands are expressed as LOCAL row indices, so the layout
+                # must be rebuilt before they are selected. (The dirty flag
+                # is persistent state: a strip-overflow fallback tick
+                # defers the rebuild — chunk cannot hold the strip —
+                # without losing it.)
+                self._rebuild_perm(cur_act | prev_act)
+                self._perm_dirty = False
+                perm_rebuilt = True
+            send_lo = send_hi = None
+            if fallback_reason is None:
+                send_lo, send_hi, overflow = self._build_bands(
+                    cx, cur_act, prev_act
+                )
+                if overflow:
+                    fallback_reason = "halo_overflow"
+            if migrations:
+                self.total_migrations += migrations
+                self._m_migrations.inc(migrations)
+            for d in range(self.n_devices):
+                self._m_shard_entities.labels(str(d)).set(int(counts[d]))
+            if halo_span is not None:
+                halo_span.args["migrations"] = migrations
+                halo_span.args["mode"] = fallback_reason or "spatial"
+                tracing.record_span(
+                    halo_span.name, t0, time.monotonic() - t0,
+                    halo_span.ctx.trace_id, halo_span.ctx.span_id,
+                    halo_span.parent_id, halo_span.args,
+                )
+
+        with engine_span("upload"):
+            put = lambda x: jax.device_put(x, self._sharding)  # noqa: E731
+            perm = self.perm
+            if perm_rebuilt:
+                # The previous epoch must live in the NEW layout or the
+                # device diff would read a migration as despawn+spawn.
+                # Cheap at the host tier: four slot-space gathers +
+                # uploads.
+                hp = self._host_prev
+                self._state = (
+                    put(hp[0][perm]), put(hp[1][perm]),
+                    put(hp[2][perm]), put(hp[3][perm]),
+                )
+                self._perm_dev = put(perm)
+            if meta_dirty or perm_rebuilt:
+                meta = (
+                    put(cur[1][perm]), put(cur[2][perm]), put(cur[3][perm])
+                )
+            else:
+                meta = self._state[1:4]
+            cur_dev = (put(cur[0][perm]),) + meta
+
+            fused_out = None
+            logic_dev: tuple = ()
+            if logic is not None:
+                # Row-permuted upload of the fused-logic inputs: the
+                # programs run per LOCAL row, so sel/y/yaw/columns travel
+                # through the same perm as the positions; dt rides as a
+                # [D] sharded array (one scalar per shard body).
+                programs, sel, y, yaw, dt, cols = logic
+                logic_dev = (
+                    put(np.asarray(y, np.float32)[perm]),
+                    put(np.asarray(yaw, np.float32)[perm]),
+                    put(np.asarray(sel, np.int32)[perm]),
+                    put(np.full(self.n_devices, dt, np.float32)),
+                ) + tuple(put(np.asarray(c)[perm]) for c in cols)
+
+        with engine_span("launch"):
+            if fallback_reason is None:
+                if self.backend != "jnp":
+                    band_args = (
+                        self._perm_dev, put(send_lo), put(send_hi),
+                        self._strip_lo_dev,
                     )
-                    res = jit_fused(
-                        *self._state, *cur_dev, *band_args, *logic_dev,
-                    )
-                    fused_out = res[11]
+                    if logic is not None:
+                        jit_fused = _jitted_spatial_step_pallas_fused(
+                            self.params, self.mesh, self.events_inline,
+                            self.halo_cap,
+                            self.backend == "pallas_interpret",
+                            self.strip_cols, tuple(logic[0]),
+                            len(logic[5]), self.drain_inline,
+                        )
+                        res = jit_fused(
+                            *self._state, *cur_dev, *band_args, *logic_dev,
+                        )
+                        fused_out = res[11]
+                    else:
+                        res = self._jit_step(*self._state, *cur_dev,
+                                             *band_args)
+                    enter_ctx = (("pallas",) + tuple(res[0:5])
+                                 + (self._perm_dev,))
+                    leave_ctx = (("pallas",) + tuple(res[5:10])
+                                 + (self._perm_dev,))
+                    out = res[10]
                 else:
-                    res = self._jit_step(*self._state, *cur_dev, *band_args)
-                enter_ctx = ("pallas",) + tuple(res[0:5]) + (self._perm_dev,)
-                leave_ctx = ("pallas",) + tuple(res[5:10]) + (self._perm_dev,)
-                out = res[10]
+                    if logic is not None:
+                        jit_fused = _jitted_spatial_step_fused(
+                            self.params, self.mesh, self.events_inline,
+                            self.halo_cap, tuple(logic[0]), len(logic[5]),
+                        )
+                        enter_ids, leave_ids, out, fused_out = jit_fused(
+                            *self._state, *cur_dev, self._perm_dev,
+                            put(send_lo), put(send_hi), *logic_dev,
+                        )
+                    else:
+                        enter_ids, leave_ids, out = self._jit_step(
+                            *self._state, *cur_dev, self._perm_dev,
+                            put(send_lo), put(send_hi),
+                        )
+                    enter_ctx = ("spatial", enter_ids, self._perm_dev)
+                    leave_ctx = ("spatial", leave_ids, self._perm_dev)
+                self.last_mode = "spatial"
+                self._m_halo_bytes.inc(self.halo_bytes_per_tick)
+                if self._last_band_counts is not None:
+                    for s in range(self.n_devices):
+                        lo_n, hi_n = self._last_band_counts[s]
+                        if lo_n:
+                            self._halo_link_children[s][0].inc(
+                                int(lo_n) * HALO_ROW_BYTES)
+                        if hi_n:
+                            self._halo_link_children[s][1].inc(
+                                int(hi_n) * HALO_ROW_BYTES)
+                pending = ShardedPendingStep(self, enter_ctx, leave_ctx,
+                                             out)
+                # The strip-local bit drain pages by event RANK; everything
+                # else (jnp ids, the jnp all-gather fallback) by flat
+                # index.
+                pending.rank_paging = self.backend != "jnp"
+                # In-kernel drain pairs are cell-major: an overflowing
+                # shard's inline window is order-incompatible with rank
+                # resume, so collect() discards it and repages that shard
+                # from rank 0.
+                pending.full_repage = self.drain_inline > 0
             else:
                 if logic is not None:
-                    jit_fused = _jitted_spatial_step_fused(
+                    jit_fused = _jitted_sharded_step_fused(
                         self.params, self.mesh, self.events_inline,
-                        self.halo_cap, tuple(logic[0]), len(logic[5]),
+                        tuple(logic[0]), len(logic[5]),
                     )
                     enter_ids, leave_ids, out, fused_out = jit_fused(
-                        *self._state, *cur_dev, self._perm_dev,
-                        put(send_lo), put(send_hi), *logic_dev,
+                        *self._state, *cur_dev, *logic_dev,
                     )
                 else:
-                    enter_ids, leave_ids, out = self._jit_step(
-                        *self._state, *cur_dev, self._perm_dev,
-                        put(send_lo), put(send_hi),
+                    enter_ids, leave_ids, out = self._jit_fallback(
+                        *self._state, *cur_dev
                     )
-                enter_ctx = ("spatial", enter_ids, self._perm_dev)
-                leave_ctx = ("spatial", leave_ids, self._perm_dev)
-            self.last_mode = "spatial"
-            self._m_halo_bytes.inc(self.halo_bytes_per_tick)
-            if self._last_band_counts is not None:
-                for s in range(self.n_devices):
-                    lo_n, hi_n = self._last_band_counts[s]
-                    if lo_n:
-                        self._halo_link_children[s][0].inc(
-                            int(lo_n) * HALO_ROW_BYTES)
-                    if hi_n:
-                        self._halo_link_children[s][1].inc(
-                            int(hi_n) * HALO_ROW_BYTES)
-            pending = ShardedPendingStep(self, enter_ctx, leave_ctx, out)
-            # The strip-local bit drain pages by event RANK; everything
-            # else (jnp ids, the jnp all-gather fallback) by flat index.
-            pending.rank_paging = self.backend != "jnp"
-            # In-kernel drain pairs are cell-major: an overflowing shard's
-            # inline window is order-incompatible with rank resume, so
-            # collect() discards it and repages that shard from rank 0.
-            pending.full_repage = self.drain_inline > 0
-        else:
-            if logic is not None:
-                jit_fused = _jitted_sharded_step_fused(
-                    self.params, self.mesh, self.events_inline,
-                    tuple(logic[0]), len(logic[5]),
+                enter_ctx = ("fallback", enter_ids)
+                leave_ctx = ("fallback", leave_ids)
+                self.last_mode = f"fallback:{fallback_reason}"
+                self.total_fallbacks += 1
+                self._m_fallback.labels(fallback_reason).inc()
+                self._m_allgather_bytes.inc(self.allgather_bytes_per_tick)
+                pending = _FallbackPendingStep(
+                    self, enter_ctx, leave_ctx, out, perm.copy()
                 )
-                enter_ids, leave_ids, out, fused_out = jit_fused(
-                    *self._state, *cur_dev, *logic_dev,
-                )
-            else:
-                enter_ids, leave_ids, out = self._jit_fallback(
-                    *self._state, *cur_dev
-                )
-            enter_ctx = ("fallback", enter_ids)
-            leave_ctx = ("fallback", leave_ids)
-            self.last_mode = f"fallback:{fallback_reason}"
-            self.total_fallbacks += 1
-            self._m_fallback.labels(fallback_reason).inc()
-            self._m_allgather_bytes.inc(self.allgather_bytes_per_tick)
-            pending = _FallbackPendingStep(
-                self, enter_ctx, leave_ctx, out, perm.copy()
-            )
-            # The fallback is the jnp all-gather program on EITHER backend:
-            # its cursors are flat matrix indices.
-            pending.rank_paging = False
+                # The fallback is the jnp all-gather program on EITHER
+                # backend: its cursors are flat matrix indices.
+                pending.rank_paging = False
 
         if fused_out is not None:
             from goworld_tpu.ops.neighbor import start_host_copy

@@ -34,17 +34,61 @@ residual host work (timers, crontab, post queue, non-fusable hooks) while
 the logic cost moves inside the ``aoi`` phase's device step — the collapse
 is the observable signature that fusion is live (``bench.py --fused``
 reports it; aoi_fused_classes/aoi_fused_slots on /metrics name the cause).
+
+The AOI engine's host path is timed from inside by :func:`engine_span`,
+one primitive for two readers: the span's seconds accumulate on
+``aoi_host_phase_seconds_total{phase}``, and a profiler session, when one
+is active, sees the same span as ``aoi.<phase>`` on the host timeline
+beside the device planes.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import time
-from typing import Optional, Sequence
+from typing import Any, Iterator, Optional, Sequence
 
 from goworld_tpu.telemetry.metrics import REGISTRY, Registry
 
 #: Label value reserved for the whole begin()→commit() span.
 TOTAL_PHASE = "total"
+
+#: Host wall seconds per AOI engine phase. The engine's own spans
+#: (engine_span): upload, launch, wait, readback, page, plan; the
+#: service's: delivery (event decode + interest-edge application) and
+#: persist (entity snapshot packing).
+AOI_HOST_PHASE = REGISTRY.counter(
+    "aoi_host_phase_seconds_total",
+    "Host wall seconds per AOI phase (upload|launch|wait|readback|page|"
+    "plan: engine dispatch and collect; delivery: event decode + "
+    "interest-edge application; persist: entity snapshot packing).",
+    ("phase",))
+
+
+@functools.lru_cache(maxsize=None)
+def _resolved(phase: str) -> tuple[Any, str, Any]:
+    """(TraceAnnotation, span name, counter child) of one phase, resolved
+    once; JAX is imported on the first span."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation, f"aoi.{phase}", AOI_HOST_PHASE.labels(phase)
+
+
+@contextlib.contextmanager
+def engine_span(phase: str) -> Iterator[None]:
+    """``with engine_span("wait"): ...`` — time one phase of the AOI
+    engine's host path: ``aoi.<phase>`` on the profiler's host timeline
+    (a TraceMe, nearly free with no session) and its perf_counter seconds
+    on ``aoi_host_phase_seconds_total{phase}``. Processes that never open
+    a span never import JAX."""
+    annotation, name, child = _resolved(phase)
+    with annotation(name):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            child.inc(time.perf_counter() - t0)
 
 
 class PhaseTracer:

@@ -6,10 +6,13 @@ refuse — interpret-mode tests cannot see that. Nothing runs: these say
 nothing about results or times. The topology is described in a module
 fixture, never at import, so only the worker given this file loads the
 TPU library. Each test compiles one kernel (about a second), asserts the
-kernel is in the executable and prints its ``memory_analysis()``.
+kernel is in the executable and prints its ``memory_analysis()``. One
+compiles the whole single-chip step at a tiny size and reads the stage
+names the profiler's trace will carry.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +29,8 @@ HEADLINE = NeighborParams(capacity=102400, cell_size=300.0, grid_x=44,
 # grid 44 over 4 devices, and its per-shard inline event budget.
 STRIP_COLS = 22
 EVENTS_INLINE = HEADLINE.max_events // 4
+TINY = NeighborParams(capacity=1024, cell_size=100.0, grid_x=8, grid_z=8,
+                      space_slots=1, cell_capacity=64, max_events=1024)
 
 
 @pytest.fixture(scope="module")
@@ -85,3 +90,25 @@ def test_boids_kernel(one_chip):
     compile_kernel(kernel, jax.ShapeDtypeStruct(
         (p.grid_z + 2, p.grid_x + 2, boids._F, boids.LANES), jnp.float32,
         sharding=one_chip))
+
+
+def test_step_stage_names(one_chip):
+    """The step compiles as ``jit_aoi_step``; its ops carry the stage
+    scopes in their metadata and the kernel is ``aoi_event_kernel``."""
+    n, buckets = TINY.capacity, TINY.num_buckets * LANES
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    epoch = (arg((n, 2), jnp.float32), arg((n,), jnp.bool_),
+             arg((n,), jnp.int32), arg((n,), jnp.float32))
+    grid = ((arg((n,), jnp.int32),) * 3 + (arg((buckets,), jnp.int32),)
+            + (arg((n,), jnp.int32),) * 3)
+    step = neighbor._jitted_step_packed(TINY, "pallas")._jitted
+    text = step.lower(*epoch, *grid, *epoch).compile().as_text()
+    assert text.startswith("HloModule jit_aoi_step,")
+    scopes = set(re.findall(r'op_name="[^"]*?/(aoi\.\w+)', text))
+    assert {"aoi.table", "aoi.feats", "aoi.guard", "aoi.gather",
+            "aoi.drain", "aoi.pack"} <= scopes
+    assert re.search(r"%aoi_event_kernel[.\d]* = .*"
+                     r'custom_call_target="tpu_custom_call"', text)
