@@ -49,7 +49,7 @@ CELL_SWEEP = ((100.0, 132), (150.0, 88), (300.0, 44), (440.0, 30), (600.0, 22))
 # The sweep still spans 64k..192k: smaller budgets shrink drain+readback if
 # occasional paging is cheaper, larger ones buy storm headroom.
 EVENTS_SWEEP = (65536, 98304, 131072, 163840, 196608)
-DRAIN_SWEEP = ("bsearch", "grouped", "scatter")  # select strategies (neighbor.py)
+DRAIN_SWEEP = ("bsearch", "grouped")  # word-find strategies (neighbor.py)
 
 
 # --- backend resolution ------------------------------------------------------

@@ -100,22 +100,17 @@ class NeighborParams:
     space_slots: int = 8  # space-id folding slots for the shared grid
     cell_capacity: int = 64  # M: max entities visible per grid cell
     max_events: int = 65536  # enter/leave pairs fetched per host round trip
-    # Pallas-drain select strategy (identical results, different gather/
-    # scatter shapes — the on-chip bench sweep promotes the winner):
-    #   bsearch: searchsorted row-find (log2(N) gathers/event) + binary-
-    #            search word-find (log2(W) random scalar gathers/event)
-    #   grouped: searchsorted row-find + two contiguous-row gathers
-    #            ([E, G] group cumsums, then [E, W/G] words) per event
-    #   scatter: one [N]→[E] scatter + cummax fill for the row-find
-    #            (row-of-rank is a monotonic step function over the
-    #            contiguous requested range) + the grouped word-find
+    # Pallas-drain word-find (identical results, different gather shapes;
+    # the row-find before it is chosen by shape, _row_find_steps):
+    #   bsearch: binary-search word-find (log2(W) random scalar gathers/event)
+    #   grouped: two contiguous-row gathers ([E, G] group cumsums, then
+    #            [E, W/G] words) per event
     drain_mode: str = "bsearch"
 
     def __post_init__(self) -> None:
-        if self.drain_mode not in ("bsearch", "grouped", "scatter"):
+        if self.drain_mode not in ("bsearch", "grouped"):
             raise ValueError(
-                f"drain_mode must be bsearch|grouped|scatter, "
-                f"got {self.drain_mode!r}"
+                f"drain_mode must be bsearch|grouped, got {self.drain_mode!r}"
             )
         if self.grid_x < 4 or self.grid_z < 4:
             # 3x3 neighborhoods must touch 9 distinct buckets after wrap.
@@ -786,6 +781,59 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
     )
 
 
+def _row_find_steps(n_rows: int, max_events: int) -> bool:
+    """Whether the drain's row-find takes the step formulation at this
+    static shape: when one scatter of ``n_rows`` updates is no more work
+    than the binary search's ``max_events * ceil(log2(n_rows + 1))``
+    gathers.
+
+    On a v5e a scatter of N updates costs about one gather pass over N
+    elements: an [E]-wide random gather runs ~7.1 ns an element whatever
+    it reads (0.4675 ms at E = 65,536), a 128,000-update scatter ~0.6 ms.
+    So the two counts compare directly. The single-chip step and its
+    pager (128,000 rows against 65,536 * 17) and the strip engine (own
+    rows only) take step; the entity-sharded engine at 409,600 entities
+    on 4 chips (512,000 rows against 16,384 * 19) keeps search.
+    """
+    return n_rows <= max_events * n_rows.bit_length()
+
+
+def _row_of_rank(row_counts, row_cum, row_starts, start_rank, max_events):
+    """i32[max_events]: the row that holds each event rank of
+    [start_rank, start_rank + max_events), clipped to [0, n_rows). Ranks
+    at or past the total get an arbitrary row; the caller masks them.
+
+    ``row_cum`` / ``row_starts`` are the inclusive / exclusive cumsums of
+    ``row_counts``. Two formulations, chosen by ``_row_find_steps``:
+    - step: row-of-rank over the contiguous range is a monotone step
+      function. Each row whose events intersect the range writes its id
+      at its first position in it, and a running max fills forward. At
+      most one row straddles ``start_rank`` and the others start at
+      distinct ranks, so the positions are distinct; every other row
+      aims past the end at ``max_events + row`` and is dropped. The
+      targets are then unique, which the scatter is told (0.60 ms a side
+      at the single-chip shape on a v5e, against 0.88 ms unhinted).
+    - search: ``searchsorted(row_starts, rank, "right") - 1``, a binary
+      search of ceil(log2(n_rows + 1)) serial [E] gather passes.
+    """
+    n_rows = row_counts.shape[0]
+    with jax.named_scope("row_find"):
+        if _row_find_steps(n_rows, max_events):
+            rows = jnp.arange(n_rows, dtype=jnp.int32)
+            first_pos = row_starts - start_rank
+            intersects = ((row_counts > 0) & (row_cum > start_rank)
+                          & (first_pos < max_events))
+            target = jnp.where(intersects, jnp.maximum(first_pos, 0),
+                               max_events + rows)
+            seed = jnp.full((max_events,), -1, jnp.int32).at[target].set(
+                rows, mode="drop", unique_indices=True)
+            row = jax.lax.cummax(seed)
+        else:
+            j = start_rank + jnp.arange(max_events, dtype=jnp.int32)
+            row = jnp.searchsorted(row_starts, j, side="right") - 1
+        return jnp.clip(row.astype(jnp.int32), 0, n_rows - 1)
+
+
 def _drain_bits(
     p: NeighborParams,
     packed_e: jax.Array,  # i32[N, W] per-entity packed event mask
@@ -803,9 +851,9 @@ def _drain_bits(
     ``bincount(cumsum(mask))`` lowering scatter-adds over the full
     N * 9 * LANES flat space (118M elements at the headline config — a
     multi-second TPU scatter). Here the only full-size ops are popcounts
-    and per-axis cumsums; each requested event then finds its row by binary
-    search, its word by a 72-wide prefix compare, and its bit by a 16-wide
-    prefix compare — ~max_events * 90 lanes of work, no scatter.
+    and per-axis cumsums; each requested event then finds its row
+    (``_row_of_rank``, under the nested scope ``row_find``), its word
+    (``drain_mode``) and its bit by a 16-wide prefix compare.
 
     Candidate c of entity i maps to halo cell c // LANES (row-major 3x3) and
     lane c % LANES. Returns (pairs i32[max_events, 2], row_counts' total) —
@@ -825,7 +873,6 @@ def _drain_bits(
             max_events = p.max_events
         start_rank = start_flat
         n = p.capacity
-        n_rows = packed_e.shape[0]
         gxl = p.grid_x if gx_ext is None else gx_ext
         pc = jax.lax.population_count(packed_e)  # [N, W]
         row_counts = jnp.sum(pc, axis=1)  # [N]
@@ -835,33 +882,8 @@ def _drain_bits(
 
         j = start_rank + jnp.arange(max_events, dtype=jnp.int32)
         valid = j < total
-        if p.drain_mode == "scatter":
-            # Row-of-rank over the CONTIGUOUS range [start, start+E) is a
-            # monotonic step function: each row with events intersecting
-            # the range claims its first output position (one [N]→[E]
-            # scatter-max; at most one row straddles `start`, and distinct
-            # rows have distinct starts, so positions are unique), then
-            # cummax fills forward — replacing searchsorted's log2(N)
-            # gather passes. The scatter target is max_events-sized,
-            # nothing like the 118M-slot round-2 pathology.
-            first_pos = row_starts - start_rank
-            intersects = (row_counts > 0) & (row_cum > start_rank) & (
-                first_pos < max_events
-            )
-            target = jnp.where(
-                intersects, jnp.maximum(first_pos, 0), max_events
-            )
-            seed = jnp.full((max_events,), -1, jnp.int32)
-            seed = seed.at[target].max(
-                jnp.arange(n_rows, dtype=jnp.int32), mode="drop"
-            )
-            row = jnp.clip(jax.lax.cummax(seed), 0, n_rows - 1)
-        else:
-            row = (
-                jnp.searchsorted(row_starts, j, side="right").astype(jnp.int32)
-                - 1
-            )
-            row = jnp.clip(row, 0, n_rows - 1)
+        row = _row_of_rank(row_counts, row_cum, row_starts, start_rank,
+                           max_events)
         k = j - row_starts[row]  # event rank within its row
 
         # Word selection by binary search over the row's inclusive word-count
@@ -871,7 +893,7 @@ def _drain_bits(
         # on-chip 2026-07-30.)
         nw = pc.shape[1]
         word_cum = jnp.cumsum(pc, axis=1)  # [N, W] inclusive
-        if p.drain_mode in ("grouped", "scatter"):
+        if p.drain_mode == "grouped":
             # Two-level select via CONTIGUOUS row gathers: the bsearch mode's
             # ~log2(W) random scalar gathers per event are latency-bound on
             # TPU; here each event pulls its row's [G] group cumsums and the

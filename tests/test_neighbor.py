@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from goworld_tpu.ops import NeighborEngine, NeighborParams
-from goworld_tpu.ops.neighbor import LANES
+from goworld_tpu.ops.neighbor import LANES, _row_find_steps
 
 
 def brute_force_sets(pos, active, space, radius):
@@ -259,25 +259,31 @@ def test_pallas_single_space_slot():
 
 
 def test_drain_modes_match_bsearch():
-    """drain_mode=grouped and drain_mode=scatter must produce the identical
-    event stream as the default bsearch select, including under storm
-    paging (tiny max_events forces many chunks through each mode's
-    row-find and group/word compares)."""
+    """drain_mode=grouped must produce the identical event stream as the
+    default bsearch select, including under storm paging (tiny max_events
+    forces many chunks through each mode's row-find and group/word
+    compares), and both row-finds must give the same stream: at this
+    256-row world max_events 8 takes the search row-find, 64 and 8192 the
+    step row-find."""
     base = dict(
         capacity=256, cell_size=100.0, grid_x=16, grid_z=16,
         space_slots=4, cell_capacity=64,
     )
-    rng = np.random.default_rng(11)
-    # 64 forces storm paging; 8192 covers the non-paging shape (> any
+    # 8 and 64 force storm paging; 8192 covers the non-paging shape (> any
     # event count this world produces) without the compile cost of a
     # production-sized budget.
-    for max_events in (64, 8192):
+    budgets = (8, 64, 8192)
+    assert [_row_find_steps(256, e) for e in budgets] == [False, True, True]
+    streams = {}
+    for max_events in budgets:
+        rng = np.random.default_rng(11)
         engines = {}
-        for mode in ("bsearch", "grouped", "scatter"):
+        for mode in ("bsearch", "grouped"):
             p = NeighborParams(max_events=max_events, drain_mode=mode, **base)
             engines[mode] = NeighborEngine(p, backend="pallas_interpret")
             engines[mode].reset()
         pos, active, space, radius = make_world(256, 200, seed=7)
+        stream = streams[max_events] = []
         for tick in range(4):
             results = {
                 m: e.step(pos, active, space, radius)
@@ -285,12 +291,77 @@ def test_drain_modes_match_bsearch():
             }
             for which in (0, 1):
                 a = np.asarray(results["bsearch"][which])
-                for mode in ("grouped", "scatter"):
-                    b = np.asarray(results[mode][which])
-                    assert np.array_equal(a, b), (
-                        tick, which, max_events, mode
-                    )
+                b = np.asarray(results["grouped"][which])
+                assert np.array_equal(a, b), (tick, which, max_events)
+                stream.append(a)
             pos = pos + rng.uniform(-30, 30, pos.shape).astype(np.float32)
+    for max_events in budgets[1:]:
+        for i, (a, b) in enumerate(zip(streams[8], streams[max_events])):
+            assert np.array_equal(a, b), (i, max_events)
+
+
+def _row_of_rank_ref(counts, start, max_events):
+    """numpy row-of-rank and the mask of ranks below the total."""
+    starts = np.cumsum(counts) - counts
+    j = start + np.arange(max_events)
+    return np.searchsorted(starts, j, "right") - 1, j < counts.sum()
+
+
+def _sparse_counts(n, seed):
+    rng = np.random.default_rng(seed)
+    return np.where(rng.random(n) < 0.7, 0, rng.integers(1, 5, n))
+
+
+ROW_OF_RANK_CASES = {
+    # name: (row counts, start rank, max_events)
+    "zero_rows_start_middle_end": ([0, 0, 3, 0, 2, 0, 0, 1, 0, 0], 0, 8),
+    "start_inside_a_row": ([2, 5, 0, 3, 1], 3, 4),
+    "start_at_total": ([1, 2, 3], 6, 4),
+    "start_past_total": ([1, 2, 3], 9, 4),
+    "events_beyond_total": ([0, 4, 0, 1, 0], 2, 32),
+    "events_far_below_rows": (_sparse_counts(1000, 3), 611, 5),
+    "sparse_page": (_sparse_counts(500, 4), 17, 64),
+}
+
+
+@pytest.mark.parametrize("steps", [True, False], ids=["step", "search"])
+@pytest.mark.parametrize("case", list(ROW_OF_RANK_CASES))
+def test_row_of_rank_matches_searchsorted(case, steps, monkeypatch):
+    """Both row-find formulations give numpy's row of every rank below the
+    total, and a row in range for the ranks past it."""
+    import jax.numpy as jnp
+
+    from goworld_tpu.ops import neighbor
+
+    counts, start, max_events = ROW_OF_RANK_CASES[case]
+    counts = np.asarray(counts, np.int32)
+    monkeypatch.setattr(neighbor, "_row_find_steps", lambda n, e: steps)
+    cum = jnp.cumsum(jnp.asarray(counts))
+    row = np.asarray(neighbor._row_of_rank(
+        jnp.asarray(counts), cum, cum - counts, jnp.int32(start), max_events))
+    want, valid = _row_of_rank_ref(counts, start, max_events)
+    assert row.shape == (max_events,) and row.dtype == np.int32
+    assert ((row >= 0) & (row < len(counts))).all()
+    np.testing.assert_array_equal(row[valid], want[valid])
+
+
+@pytest.mark.parametrize("n_rows, max_events, steps", [
+    (128_000, 65_536, True),  # one chip at 102,400 entities, and its pager
+    (512_000, 16_384, False),  # entity-sharded, 409,600 entities on 4 chips
+    (256, 8, False),
+    (256, 64, True),
+])
+def test_row_find_shape_rule(n_rows, max_events, steps):
+    """Step when its n_rows scatter updates are no more than search's
+    max_events * ceil(log2(n_rows + 1)) gathers."""
+    assert _row_find_steps(n_rows, max_events) is steps
+
+
+@pytest.mark.parametrize("mode", ["scatter", "nonzero"])
+def test_drain_mode_rejects_unknown(mode):
+    """drain_mode chooses the word-find only; the row-find has no mode."""
+    with pytest.raises(ValueError, match="bsearch|grouped"):
+        NeighborParams(drain_mode=mode)
 
 
 @pytest.mark.slow

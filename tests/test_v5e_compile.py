@@ -110,5 +110,7 @@ def test_step_stage_names(one_chip):
     scopes = set(re.findall(r'op_name="[^"]*?/(aoi\.\w+)', text))
     assert {"aoi.table", "aoi.feats", "aoi.guard", "aoi.gather",
             "aoi.drain", "aoi.pack"} <= scopes
+    # 1,024 rows against 1,024 events: the step row-find, in its own scope.
+    assert re.search(r'op_name="[^"]*/aoi\.drain/row_find/scatter', text)
     assert re.search(r"%aoi_event_kernel[.\d]* = .*"
                      r'custom_call_target="tpu_custom_call"', text)
