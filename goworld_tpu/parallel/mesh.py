@@ -20,6 +20,10 @@ Host interface parity with the single-device engine (round-2 upgrade):
 exactly ONE blocking device→host read — every shard packs its header +
 inline event pairs into one stacked ``[D * (3 + 2E), 2]`` buffer. Event
 storms beyond the inline budget page through per-shard chunked drains.
+The inline budget is divided: each shard keeps ``E = max_events / D``,
+so the tier's total stays ``max_events`` (the world does not grow with
+the chips here; the spatial tier, parallel/spatial.py, keeps
+``max_events`` on each chip instead).
 
 Collectives are XLA's (all_gather inside shard_map); there is no NCCL/MPI
 analog to port — the reference's TCP star stays the control plane

@@ -31,7 +31,8 @@ params. Tested by spawning real OS processes over the Gloo CPU backend
 mirroring how the reference CI tests its multi-process cluster.
 
 The jitted step/drain builders (``jax.shard_map``) are shared with
-parallel/mesh.py. The spatially sharded
+parallel/mesh.py, and so is its inline-budget rule: each shard keeps
+``max_events / D`` events a side inline. The spatially sharded
 engine (parallel/spatial.py) is single-controller only for now: its
 host-side strip planner assumes one process owns the whole slot space.
 """

@@ -45,8 +45,15 @@ Exactness contract (same event sets as the single-device engine):
 
 Same host interface as the other engines: ``step_async`` returns a
 pending with ONE blocking packed readback in ``collect()``, storm paging
-beyond the per-shard inline budget, and the ``meta_dirty=False`` upload
-elision (which additionally requires an unchanged row permutation here).
+beyond the inline budget, and the ``meta_dirty=False`` upload elision
+(which additionally requires an unchanged row permutation here).
+
+Inline budget: EACH chip keeps ``params.max_events`` events a side
+inline (its step, in-kernel drain, storm pager and the exact fallback
+alike), not ``max_events / D`` as the entity-sharded tiers do
+(parallel/mesh.py, parallel/multihost.py). A seamless world grows with
+its chips, so a chip's share of the events does not shrink as chips are
+added, and a window that did would overflow and repage every tick.
 
 Two device backends share the halo layout (ISSUE 15):
 
@@ -958,15 +965,45 @@ def plan_placement(devices: list) -> np.ndarray:
     return ident
 
 
+def strip_cols_for(grid_x: int, n_dev: int,
+                   strip_cols: int | None = None) -> int:
+    """The Pallas tier's static kernel-slab width cap, checked: by default
+    2x the uniform strip, clamped to planner feasibility on both sides."""
+    ceil_w = -(-grid_x // n_dev)
+    if strip_cols is None:
+        strip_cols = min(grid_x - (n_dev - 1) * MIN_STRIP_COLS, 2 * ceil_w)
+    strip_cols = int(strip_cols)
+    if strip_cols < ceil_w:
+        raise ValueError(
+            f"strip_cols {strip_cols} < ceil(grid_x/{n_dev}) = {ceil_w}: "
+            f"{n_dev} capped strips cannot cover {grid_x} columns"
+        )
+    if strip_cols + 4 > grid_x:
+        raise ValueError(
+            f"strip_cols {strip_cols} + 4 ghost columns exceeds grid_x "
+            f"{grid_x}; lower strip_cols (the strip slab must not wrap "
+            f"onto itself)"
+        )
+    return strip_cols
+
+
+def default_halo_cap(params: NeighborParams, n_dev: int) -> int:
+    """Rows each seam band may ship: ~6 band columns of the uniform-density
+    column population, doubled for clustering, clamped to the chunk (an
+    overflow past this budget falls back for the tick, it never breaks)."""
+    est = 12 * params.capacity // params.grid_x
+    return max(64, min(params.capacity // n_dev, ((est + 7) // 8) * 8))
+
+
 class SpatialShardedNeighborEngine:
     """Grid-strip sharded AOI engine (see module docstring).
 
     Interface parity with ShardedNeighborEngine: ``reset`` /
     ``step_async`` / ``step``, one packed readback per tick, paging past
-    the per-shard inline budget. Extra observability attributes:
-    ``last_mode`` ("spatial" | "fallback:<reason>"), ``last_fast_tick``
-    (the seam-free single-pass guard held on the last collected tick),
-    ``shard_population`` (np int64[D] active rows per shard at the last
+    each chip's inline budget of ``params.max_events``. Extra
+    observability attributes: ``last_mode`` ("spatial" |
+    "fallback:<reason>"), ``last_fast_tick`` (the seam-free single-pass
+    guard held on the last collected tick), ``shard_population`` (np int64[D] active rows per shard at the last
     dispatch), ``halo_bytes_per_tick`` (structural ppermute payload), and
     the telemetry counters wired in ``__init__``.
 
@@ -1008,10 +1045,6 @@ class SpatialShardedNeighborEngine:
             raise ValueError(
                 f"capacity {params.capacity} must be a multiple of 8*{n_dev}"
             )
-        if params.max_events % n_dev != 0:
-            raise ValueError(
-                f"max_events {params.max_events} must be divisible by {n_dev}"
-            )
         if params.grid_x < MIN_STRIP_COLS * n_dev:
             raise ValueError(
                 f"grid_x {params.grid_x} < {MIN_STRIP_COLS}*{n_dev} "
@@ -1051,39 +1084,16 @@ class SpatialShardedNeighborEngine:
         self.backend = backend
         self.n_devices = n_dev
         self.chunk = params.capacity // n_dev
-        self.events_inline = params.max_events // n_dev
-        gx = params.grid_x
+        # Per chip, not divided by D (module docstring).
+        self.events_inline = params.max_events
         if backend != "jnp":
-            # Static kernel-slab width cap. Default: 2x the uniform strip,
-            # clamped to planner feasibility on both sides.
-            ceil_w = -(-gx // n_dev)
-            if strip_cols is None:
-                strip_cols = min(
-                    gx - (n_dev - 1) * MIN_STRIP_COLS, 2 * ceil_w
-                )
-            strip_cols = int(strip_cols)
-            if strip_cols < ceil_w:
-                raise ValueError(
-                    f"strip_cols {strip_cols} < ceil(grid_x/{n_dev}) = "
-                    f"{ceil_w}: {n_dev} capped strips cannot cover "
-                    f"{gx} columns"
-                )
-            if strip_cols + 4 > gx:
-                raise ValueError(
-                    f"strip_cols {strip_cols} + 4 ghost columns exceeds "
-                    f"grid_x {gx}; lower strip_cols (the strip slab must "
-                    f"not wrap onto itself)"
-                )
-            self._max_cols: int | None = strip_cols
+            self._max_cols: int | None = strip_cols_for(
+                params.grid_x, n_dev, strip_cols)
         else:
             self._max_cols = None
         self.strip_cols = self._max_cols
         if halo_cap is None:
-            # ~6 band columns of the uniform-density column population,
-            # doubled for clustering, clamped to the chunk (an overflow
-            # past this budget falls back for the tick, it never breaks).
-            est = 12 * params.capacity // params.grid_x
-            halo_cap = max(64, min(self.chunk, ((est + 7) // 8) * 8))
+            halo_cap = default_halo_cap(params, n_dev)
         self.halo_cap = int(halo_cap)
         self.replan_interval = int(replan_interval)
         self.halo_bytes_per_tick = (

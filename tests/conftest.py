@@ -19,3 +19,17 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def drain_launches():
+    """Launches so far of the spatial engine's paging programs (strip
+    drains and the all-gather fallback's drain), as a callable."""
+    from goworld_tpu.telemetry import sentinel
+
+    return lambda: sum(
+        sentinel.launches_total(name) for name in
+        ("spatial_drain", "spatial_drain_bits", "sharded_drain"))

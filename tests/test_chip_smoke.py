@@ -9,7 +9,7 @@ import jax
 import chip_smoke
 from goworld_tpu.ops import NeighborParams
 
-# 64 inline events per side, so the enter storm pages.
+# 64 inline events a side (a chip's window), so the enter storm pages.
 PARAMS = NeighborParams(capacity=256, cell_size=100.0, grid_x=16, grid_z=4,
                         space_slots=2, cell_capacity=64, max_events=64)
 
@@ -30,7 +30,7 @@ def test_chips4_phase_rehearsal():
         PARAMS, jax.devices("cpu")[:4], backend="pallas_interpret",
         n_steady=2, prewarm_fallback=False)
     assert rec["ok"] and rec["phase"] == "chips4"
-    assert rec["drain_inline"] == PARAMS.max_events // 4
+    assert rec["drain_inline"] == PARAMS.max_events  # a chip's window
     assert rec["state_shards_on_distinct_devices"] == 4
     assert rec["aoi_link_bytes_total_halo"] > 0
     assert [t["kind"] for t in rec["ticks"]][-1] == "despawn_teleport"

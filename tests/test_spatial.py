@@ -1,7 +1,7 @@
 """Spatially sharded AOI (grid-strip halo exchange) must agree EXACTLY
 with the single-device engine — including entities straddling and crossing
 strip seams, migrations with hysteresis, density re-plans mid-run, event
-storms past the per-shard inline budget, cell-capacity drops at seam
+storms past a chip's inline budget, cell-capacity drops at seam
 cells, and the exact all-gather fallback ticks (teleports, halo overflow,
 strip overflow)."""
 
@@ -122,18 +122,21 @@ def test_seam_straddle_and_cross_exact():
     assert spatial.total_migrations > 0
 
 
-def test_event_storm_pages_chunked_drain():
-    """First-tick enter storm past the per-shard inline budget (16/shard
-    here) must page through the chunked drain with exactly-once pairs."""
+def test_event_storm_pages_chunked_drain(drain_launches):
+    """First-tick enter storm past a chip's inline budget (max_events on
+    each chip, 64 here) must page through the chunked drain with
+    exactly-once pairs."""
     p = NeighborParams(
         capacity=512, cell_size=100.0, grid_x=32, grid_z=16,
-        space_slots=4, cell_capacity=64, max_events=128,
+        space_slots=4, cell_capacity=64, max_events=64,
     )
     single, spatial = make_engines(p)
+    assert spatial.events_inline == p.max_events
     rng, pos, active, space, radius = make_world(400, seed=11, world=1200.0)
     e1, l1, _ = single.step(pos, active, space, radius)
+    pages0 = drain_launches()
     e2, l2, _ = spatial.step(pos, active, space, radius)
-    assert len(e1) > p.max_events  # the storm really overflows
+    assert drain_launches() > pages0  # a chip's window really overflows
     assert to_sets(e1) == to_sets(e2)
     assert len(e1) == len(e2)  # exactly-once across chunks
 

@@ -26,9 +26,17 @@ HEADLINE = NeighborParams(capacity=102400, cell_size=300.0, grid_x=44,
                           grid_z=44, space_slots=4, cell_capacity=128,
                           max_events=262144)
 # The 4-chip strip: SpatialShardedNeighborEngine's default strip_cols at
-# grid 44 over 4 devices, and its per-shard inline event budget.
+# grid 44 over 4 devices, and its inline event budget (max_events on
+# each chip).
 STRIP_COLS = 22
-EVENTS_INLINE = HEADLINE.max_events // 4
+EVENTS_INLINE = HEADLINE.max_events
+# The benchmark's seamless_250k cell: 312,512 slots over 4 strips of a
+# 134-column grid, strip_cols derived (68), the game's 65,536 events a
+# side inline on each chip.
+SEAMLESS = NeighborParams(capacity=312512, cell_size=300.0, grid_x=134,
+                          grid_z=134, space_slots=1, cell_capacity=64,
+                          max_events=65536)
+SEAMLESS_STRIP_COLS = 68
 TINY = NeighborParams(capacity=1024, cell_size=100.0, grid_x=8, grid_z=8,
                       space_slots=1, cell_capacity=64, max_events=1024)
 
@@ -59,9 +67,9 @@ def compile_kernel(fn, *args):
     return compiled
 
 
-def cells(sharding, rows, cols, planes, dtype):
+def cells(sharding, rows, cols, planes, dtype, p=HEADLINE):
     return jax.ShapeDtypeStruct(
-        (HEADLINE.space_slots, rows + 2, cols, planes, LANES), dtype,
+        (p.space_slots, rows + 2, cols, planes, LANES), dtype,
         sharding=sharding)
 
 
@@ -73,14 +81,18 @@ def test_event_kernel_headline(one_chip, dual):
 
 
 @pytest.mark.parametrize("dual", [False, True], ids=["plain", "dual"])
-def test_strip_kernel_inkernel_drain(one_chip, dual):
-    qcols = STRIP_COLS + 2
+@pytest.mark.parametrize("shape", [
+    (HEADLINE, STRIP_COLS, EVENTS_INLINE),
+    (SEAMLESS, SEAMLESS_STRIP_COLS, SEAMLESS.max_events),
+], ids=["headline", "seamless_250k"])
+def test_strip_kernel_inkernel_drain(one_chip, dual, shape):
+    p, strip_cols, drain_inline = shape
     kernel = neighbor._compiled_event_kernel(
-        HEADLINE, False, rows=HEADLINE.grid_z, cols=qcols, dual=dual,
-        drain_inline=EVENTS_INLINE)
-    gz, gxe = HEADLINE.grid_z, STRIP_COLS + 4
-    compile_kernel(kernel, cells(one_chip, gz, gxe, _F, jnp.float32),
-                   cells(one_chip, gz, gxe, 2, jnp.int32))
+        p, False, rows=p.grid_z, cols=strip_cols + 2, dual=dual,
+        drain_inline=drain_inline)
+    gz, gxe = p.grid_z, strip_cols + 4
+    compile_kernel(kernel, cells(one_chip, gz, gxe, _F, jnp.float32, p),
+                   cells(one_chip, gz, gxe, 2, jnp.int32, p))
 
 
 def test_boids_kernel(one_chip):
