@@ -23,10 +23,14 @@ Host-side layout (the part jax never sees):
   when a strip overflows its row budget) — the AoiZora-style
   density-aware placement seed (PAPERS.md).
 - Row permutation: device rows ``[d*chunk, (d+1)*chunk)`` hold the slots
-  assigned to shard d (active slots sorted by slot id, then inactive
-  fill). When any slot migrates, the PREVIOUS epoch is re-uploaded in the
-  new layout from the host mirror, so the device diff never sees a
+  assigned to shard d: those placed in either epoch, then inactive fill,
+  in no fixed order. A migrating slot swaps rows with a free row of its
+  new strip, and only the swapped rows of the PREVIOUS epoch are written
+  on the device, from the host mirror, so the device diff never sees a
   migration as a despawn+spawn — event streams are migration-transparent.
+  The layout is rebuilt whole (both epochs re-uploaded) only on the first
+  dispatch after ``reset``, after an adopted re-plan, and when a strip
+  has too few free rows for the slots moving in.
 
 Exactness contract (same event sets as the single-device engine):
 
@@ -46,7 +50,7 @@ Exactness contract (same event sets as the single-device engine):
 Same host interface as the other engines: ``step_async`` returns a
 pending with ONE blocking packed readback in ``collect()``, storm paging
 beyond the inline budget, and the ``meta_dirty=False`` upload elision
-(which additionally requires an unchanged row permutation here).
+(void here on a dispatch that rebuilds the row layout).
 
 Inline budget: EACH chip keeps ``params.max_events`` events a side
 inline (its step, in-kernel drain, storm pager and the exact fallback
@@ -497,6 +501,37 @@ def _jitted_spatial_drain(
         out_specs=(spec, spec),
     )
     return sentinel.SentinelJit("spatial_drain", jax.jit(mapped))
+
+
+# Rows one launch of the incremental relayout writes; a tick that moves
+# more loops over launches of this one shape, so no move count compiles.
+ROW_MOVE_BATCH = 256
+
+
+def _row_moves(chunk, ppos, pact, pspc, prad, perm, ipay, fpay):
+    """Overwrite rows of the sharded previous epoch and row→slot map.
+    ``ipay`` int32 [K, 4] = (global row, slot, active, space), ``fpay``
+    f32 [K, 3] = (x, z, radius); each shard keeps the rows of its block
+    and drops the rest (padding rows are ``capacity``, in no block)."""
+    local = ipay[:, 0] - jax.lax.axis_index(SHARD_AXIS) * chunk
+    local = jnp.where((local >= 0) & (local < chunk), local, chunk)
+
+    def put(a, v):
+        return a.at[local].set(v.astype(a.dtype), mode="drop")
+
+    return (put(ppos, fpay[:, :2]), put(pact, ipay[:, 2] != 0),
+            put(pspc, ipay[:, 3]), put(prad, fpay[:, 2]),
+            put(perm, ipay[:, 1]))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_row_moves(mesh: Mesh, chunk: int):
+    spec = P(SHARD_AXIS)
+    mapped = jax.shard_map(
+        functools.partial(_row_moves, chunk), mesh=mesh,
+        in_specs=(spec,) * 5 + (P(), P()), out_specs=(spec,) * 5,
+    )
+    return sentinel.SentinelJit("spatial_row_moves", jax.jit(mapped))
 
 
 # --- Pallas strip tier (ISSUE 15) --------------------------------------------
@@ -1145,8 +1180,10 @@ class SpatialShardedNeighborEngine:
         self._jit_fallback_drain = _jitted_sharded_drain(
             params, mesh, self.events_inline, self.chunk
         )
+        self._jit_row_moves = _jitted_row_moves(mesh, self.chunk)
         self._flat_end = self.chunk * 9 * params.cell_capacity
         self._sharding = NamedSharding(mesh, P(SHARD_AXIS))
+        self._replicated = NamedSharding(mesh, P())
         self._state: tuple | None = None
         self.last_grid_dropped = 0
         self.last_mode = "spatial"
@@ -1156,6 +1193,12 @@ class SpatialShardedNeighborEngine:
         self.total_migrations = 0
         self.total_fallbacks = 0
         self.total_replans = 0
+        # Dispatches by how they brought the row layout up to date
+        # ("incremental": swaps of the moved slots only; "rebuild": the
+        # whole layout and both epochs' upload), and the slots the
+        # incremental swaps moved to another strip's rows.
+        self.total_relayouts = {"incremental": 0, "rebuild": 0}
+        self.total_row_moves = 0
         from goworld_tpu import telemetry
 
         telemetry.gauge(
@@ -1199,6 +1242,15 @@ class SpatialShardedNeighborEngine:
             "Entities reassigned to a different AOI grid-strip shard "
             "(hysteresis: one full cell past the seam).",
         )
+        relayouts = telemetry.counter(
+            "aoi_shard_relayouts_total",
+            "Dispatches that changed the AOI strip engine's row layout: "
+            "kind=incremental swapped only the moved slots' rows, "
+            "kind=rebuild rebuilt the layout and re-uploaded both epochs.",
+            ("kind",),
+        )
+        self._m_relayouts = {k: relayouts.labels(k)
+                             for k in self.total_relayouts}
         self._m_fallback = telemetry.counter(
             "aoi_shard_fallback_total",
             "Ticks the spatial engine ran the exact all-gather program "
@@ -1268,10 +1320,17 @@ class SpatialShardedNeighborEngine:
         self._host_prev = zeros
         self._prev_cx = bins_reference(self.params, zeros[0], zeros[2])[0]
         self._dispatches = 0
-        self._perm_dirty = False
+        # Layout debt: the first dispatch builds the layout whole; after
+        # that, ``_moved`` collects the slots whose ``assign`` changed
+        # since the rows last matched it.
+        self._relayout_full = True
+        self._moved: list[np.ndarray] = []
         put = lambda x: jax.device_put(x, self._sharding)  # noqa: E731
         self._state = tuple(put(a) for a in zeros)
         self._perm_dev = put(self.perm)
+        # Compile the row-move program now (a no-op write: every row is
+        # padding), not on the first migration inside the game loop.
+        self._move_rows(np.empty(0, np.int32), np.empty(0, np.int32))
 
     def _rebuild_col_owner(self) -> None:
         gx = self.params.grid_x
@@ -1317,7 +1376,7 @@ class SpatialShardedNeighborEngine:
         movers = prev_only[~keep]
         if len(movers):
             self.assign[movers] = self._col_owner[self._prev_cx[movers]]
-            self._perm_dirty = True
+            self._moved.append(movers)
         return int(len(movers))
 
     def _replan(self, cx: np.ndarray, active: np.ndarray) -> bool:
@@ -1337,6 +1396,9 @@ class SpatialShardedNeighborEngine:
             return False
         self.boundaries = new
         self._rebuild_col_owner()
+        # Boundaries moved: rows may change strip by the thousand, so the
+        # next relayout rebuilds the layout whole.
+        self._relayout_full = True
         self.total_replans += 1
         self._m_replans.inc()
         return True
@@ -1346,7 +1408,12 @@ class SpatialShardedNeighborEngine:
         PLACED slots (active in either epoch — a freshly-despawned slot
         must stay on the strip its previous-epoch pairs live on, or its
         neighbors' leave events would never find it) in slot order, then
-        free fill (deterministic)."""
+        free fill (deterministic).
+
+        Runs only where many rows move at once: the first dispatch after
+        ``reset``, the dispatch after an adopted re-plan, and a dispatch
+        whose moved slots find too few free rows in their new strip
+        (``_swap_rows``). Every other migration swaps rows in place."""
         n = self.params.capacity
         d = self.n_devices
         chunk = self.chunk
@@ -1370,6 +1437,113 @@ class SpatialShardedNeighborEngine:
         self.perm = perm
         self.row_of = np.empty(n, np.int32)
         self.row_of[perm] = np.arange(n, dtype=np.int32)
+
+    def _free_rows(self, s: int, need: int, placed: np.ndarray):
+        """``need`` rows of shard s's block whose slot is placed in
+        neither epoch, searched from the block's end (where a rebuild
+        parks the free fill) over a window that grows until it holds
+        enough; None when the whole block has fewer."""
+        lo, hi = s * self.chunk, (s + 1) * self.chunk
+        width = max(64, 4 * need)
+        while True:
+            start = max(lo, hi - width)
+            free = start + np.flatnonzero(~placed[self.perm[start:hi]])
+            if len(free) >= need:
+                return free[len(free) - need:].astype(np.int32)
+            if start == lo:
+                return None
+            width *= 4
+
+    def _swap_rows(self, placed: np.ndarray):
+        """Incremental relayout: each slot in ``_moved`` that is placed
+        and now assigned to another strip swaps rows with a free row of
+        that strip; the free row's slot, placed in neither epoch, parks
+        in the vacated row. Updates ``perm``, ``row_of`` and ``assign``
+        for those rows only and returns (rows, slots) to write on the
+        device, or None (layout untouched) when a strip lacks the free
+        rows, and the caller rebuilds."""
+        cand = np.unique(np.concatenate(self._moved))
+        block = self.row_of[cand] // self.chunk
+        off = self.assign[cand] != block
+        cand, block = cand[off], block[off]
+        idle = ~placed[cand]
+        movers = cand[~idle]
+        dest = self.assign[movers]
+        picked = []
+        for s in np.unique(dest):
+            mine = movers[dest == s]
+            rows = self._free_rows(int(s), len(mine), placed)
+            if rows is None:
+                return None
+            picked.append((mine, rows))
+        # An unplaced slot holds no epoch's values: it stays parked in
+        # its row and takes that row's strip.
+        self.assign[cand[idle]] = block[idle]
+        if not picked:
+            return np.empty(0, np.int32), np.empty(0, np.int32)
+        movers = np.concatenate([m for m, _ in picked])
+        dst = np.concatenate([r for _, r in picked])
+        src = self.row_of[movers]
+        parked = self.perm[dst]
+        self.perm[dst] = movers
+        self.perm[src] = parked
+        self.row_of[movers] = dst
+        self.row_of[parked] = src
+        self.assign[parked] = src // self.chunk
+        return (np.concatenate([dst, src]),
+                np.concatenate([movers, parked]))
+
+    def _move_rows(self, rows: np.ndarray, slots: np.ndarray) -> None:
+        """Write ``rows`` of the device's previous epoch with those
+        slots' values from the host mirror, and of the row→slot map with
+        the slots: fixed-shape launches of ``ROW_MOVE_BATCH`` rows."""
+        hp = self._host_prev
+        cap = self.params.capacity
+        k = ROW_MOVE_BATCH
+        for b in range(0, max(len(rows), 1), k):
+            r, sl = rows[b:b + k], slots[b:b + k]
+            ipay = np.zeros((k, 4), np.int32)
+            ipay[:, 0] = cap
+            ipay[:len(r), 0] = r
+            ipay[:len(r), 1] = sl
+            ipay[:len(r), 2] = hp[1][sl]
+            ipay[:len(r), 3] = hp[2][sl]
+            fpay = np.zeros((k, 3), np.float32)
+            fpay[:len(r), :2] = hp[0][sl]
+            fpay[:len(r), 2] = hp[3][sl]
+            out = self._jit_row_moves(
+                *self._state, self._perm_dev,
+                jax.device_put(ipay, self._replicated),
+                jax.device_put(fpay, self._replicated),
+            )
+            self._state = tuple(out[:4])
+            self._perm_dev = out[4]
+
+    def _relayout(self, placed: np.ndarray):
+        """Bring the host's row layout up to the current ``assign``.
+        Returns (kind, swapped): kind "incremental" with the (rows,
+        slots) ``_move_rows`` writes on the device, "rebuild" when the
+        whole layout was rebuilt (both epochs go up again), or None
+        when no placed slot changed strip."""
+        if not self._relayout_full and self._moved:
+            swapped = self._swap_rows(placed)
+            if swapped is not None:
+                self._moved = []
+                if not len(swapped[0]):
+                    return None, None
+                self.total_row_moves += len(swapped[0]) // 2
+                return self._count_relayout("incremental"), swapped
+        if not (self._relayout_full or self._moved):
+            return None, None
+        self._rebuild_perm(placed)
+        self._relayout_full = False
+        self._moved = []
+        return self._count_relayout("rebuild"), None
+
+    def _count_relayout(self, kind: str) -> str:
+        self.total_relayouts[kind] += 1
+        self._m_relayouts[kind].inc()
+        return kind
 
     # --- dispatch -----------------------------------------------------------
 
@@ -1411,16 +1585,13 @@ class SpatialShardedNeighborEngine:
             halo_span = tracing.child_scope("tick.halo")
             t0 = time.monotonic()
 
-            perm_rebuilt = False
             migrations = 0
             prev_act = self._host_prev[1]
             # Slow-cadence density re-plan.
-            if (
-                self.replan_interval
-                and self._dispatches % self.replan_interval == 0
-                and self._replan(cx, cur_act)
+            if self.replan_interval and (
+                self._dispatches % self.replan_interval == 0
             ):
-                self._perm_dirty = True
+                self._replan(cx, cur_act)
             # Hysteresis migration: move a row only when its cell is a
             # full column past the seam.
             act_idx = np.flatnonzero(cur_act)
@@ -1429,7 +1600,7 @@ class SpatialShardedNeighborEngine:
             if len(movers):
                 self.assign[movers] = self._col_owner[cx[movers]]
                 migrations += len(movers)
-                self._perm_dirty = True
+                self._moved.append(movers)
             # Prev-epoch-only rows (freshly despawned) re-home by their
             # PREVIOUS column: their only remaining job is hosting their
             # prev-epoch pairs, so an adopted re-plan that moved
@@ -1459,7 +1630,6 @@ class SpatialShardedNeighborEngine:
                     migrations += int(
                         (new_assign != self.assign[act_idx]).sum())
                     self.assign[act_idx] = new_assign
-                    self._perm_dirty = True
                     migrations += self._rehome_prev_only(prev_act, cur_act)
                     counts = np.bincount(
                         self.assign[placed_idx], minlength=self.n_devices
@@ -1479,15 +1649,14 @@ class SpatialShardedNeighborEngine:
                 if not ok.all():
                     fallback_reason = "teleport"
 
-            if self._perm_dirty and fallback_reason != "strip_overflow":
+            relayout = swapped = None
+            if fallback_reason != "strip_overflow":
                 # Bands are expressed as LOCAL row indices, so the layout
-                # must be rebuilt before they are selected. (The dirty flag
-                # is persistent state: a strip-overflow fallback tick
-                # defers the rebuild — chunk cannot hold the strip —
+                # must follow ``assign`` before they are selected. (The
+                # layout debt is persistent state: a strip-overflow
+                # fallback tick defers it — chunk cannot hold the strip —
                 # without losing it.)
-                self._rebuild_perm(cur_act | prev_act)
-                self._perm_dirty = False
-                perm_rebuilt = True
+                relayout, swapped = self._relayout(cur_act | prev_act)
             send_lo = send_hi = None
             if fallback_reason is None:
                 send_lo, send_hi, overflow = self._build_bands(
@@ -1512,18 +1681,19 @@ class SpatialShardedNeighborEngine:
         with engine_span("upload"):
             put = lambda x: jax.device_put(x, self._sharding)  # noqa: E731
             perm = self.perm
-            if perm_rebuilt:
-                # The previous epoch must live in the NEW layout or the
-                # device diff would read a migration as despawn+spawn.
-                # Cheap at the host tier: four slot-space gathers +
-                # uploads.
-                hp = self._host_prev
-                self._state = (
-                    put(hp[0][perm]), put(hp[1][perm]),
-                    put(hp[2][perm]), put(hp[3][perm]),
-                )
-                self._perm_dev = put(perm)
-            if meta_dirty or perm_rebuilt:
+            # The previous epoch must live in the new layout, or the
+            # device diff would read a migration as despawn+spawn. A
+            # swap writes only the moved rows (and, with them, the reused
+            # meta below); a rebuild gathers and uploads the whole epoch.
+            if relayout == "incremental":
+                self._move_rows(*swapped)
+            elif relayout == "rebuild":
+                self._state = tuple(put(a[perm]) for a in self._host_prev)
+                # A copy: ``_swap_rows`` edits ``perm`` in place later, and
+                # a put may read (on the CPU, alias) the host buffer after
+                # it returns, while this tick is still in flight.
+                self._perm_dev = put(perm.copy())
+            if meta_dirty or relayout == "rebuild":
                 meta = (
                     put(cur[1][perm]), put(cur[2][perm]), put(cur[3][perm])
                 )

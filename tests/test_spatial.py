@@ -350,6 +350,172 @@ def test_pipelined_matches_sync():
     assert sync_stream == pipe_stream
 
 
+def brute_keys(pos, active, space, radius):
+    """Every valid (watcher, other) pair of one epoch as ``i * N + j``,
+    by brute force over all pairs."""
+    d2 = ((pos[:, None, :] - pos[None, :, :]) ** 2).sum(-1)
+    ok = (active[:, None] & active[None, :]
+          & (space[:, None] == space[None, :])
+          & (d2 <= radius[:, None] ** 2))
+    np.fill_diagonal(ok, False)
+    i, j = np.nonzero(ok)
+    return set((i * len(pos) + j).tolist())
+
+
+def pair_set(pairs):
+    p = np.asarray(pairs, np.int64).reshape(-1, 2)
+    return set((p[:, 0] * N + p[:, 1]).tolist())
+
+
+def assert_events_exact(got, prev_keys, cur_keys, tag):
+    enters, leaves, dropped = got
+    assert dropped == 0, tag
+    assert pair_set(enters) == cur_keys - prev_keys, f"enters differ {tag}"
+    assert pair_set(leaves) == prev_keys - cur_keys, f"leaves differ {tag}"
+    assert len(enters) == len(cur_keys - prev_keys), f"duplicates {tag}"
+
+
+def walk_step(rng, pos, sigma, world=WORLD_X):
+    pos = pos + rng.normal(0, sigma, pos.shape).astype(np.float32)
+    np.clip(pos[:, 0], 0, world, out=pos[:, 0])
+    np.clip(pos[:, 1], 1.0, 1599.0, out=pos[:, 1])
+    return pos.astype(np.float32)
+
+
+@pytest.mark.parametrize("case", ["walk", "replan", "pipelined"])
+def test_incremental_relayout_exact(case):
+    """Seam crossings move only the migrated rows: every tick's events
+    equal the brute-force reference while the row layout is swapped in
+    place. ``walk`` runs 24 ticks with the meta upload elided except on
+    churn ticks; ``replan`` adopts density re-plans mid-walk, so whole
+    rebuilds and swaps alternate; ``pipelined`` dispatches tick t+1 (its
+    rows swapped) before tick t is collected, so tick t's pairs must map
+    through the layout it was dispatched with."""
+    single, spatial = make_engines(replan_interval=4 if case == "replan"
+                                   else 0)
+    del single
+    rng, pos, active, space, radius = make_world(400, seed=41)
+    if case == "replan":
+        # 60% of the crowd on the left half: every strip still fits its
+        # rows, but the uniform split is lopsided, so a later re-plan is
+        # adopted.
+        pos[:240, 0] *= 0.5
+        pos[240:400, 0] = 0.5 * (WORLD_X + pos[240:400, 0])
+    # The trajectory and its reference first, so the dispatches follow
+    # each other as closely as a game loop's.
+    ticks = []
+    for tick in range(24):
+        churn = tick % 6 == 5
+        if churn:
+            active = active.copy()
+            active[rng.integers(0, N, 8)] ^= True
+        ticks.append((pos, active, tick == 0 or churn,
+                      brute_keys(pos, active, space, radius)))
+        pos = walk_step(rng, pos, 25.0)
+    prev_keys: set = set()
+    pending = None
+    rebuilds = 0
+    for tick, (pos, active, dirty, keys) in enumerate(ticks):
+        replans = spatial.total_replans
+        pend = spatial.step_async(pos, active, space, radius,
+                                  meta_dirty=dirty)
+        replanned = spatial.total_replans > replans
+        # A row the hysteresis keeps past a moved boundary can trip the
+        # teleport guard on the dispatch that adopts the re-plan.
+        assert spatial.last_mode == "spatial" or replanned, tick
+        # The whole layout is rebuilt at set-up and on a dispatch that
+        # adopts a re-plan, never for an ordinary seam crossing.
+        rebuilds += tick == 0 or replanned
+        assert spatial.total_relayouts["rebuild"] == rebuilds, tick
+        if case == "pipelined":
+            if pending is not None:
+                # This dispatch swapped rows in place: the tick in flight
+                # still holds the row->slot map it was dispatched with.
+                held = np.asarray(pending[0]._enter_ctx[-1])
+                assert np.array_equal(held, pending[-1]), tick
+                assert_events_exact(pending[0].collect(), *pending[1:3],
+                                    f"@ tick {tick - 1}")
+            pending = (pend, prev_keys, keys, spatial.perm.copy())
+        else:
+            assert_events_exact(pend.collect(), prev_keys, keys,
+                                f"@ tick {tick}")
+        prev_keys = keys
+    if pending is not None:
+        assert_events_exact(pending[0].collect(), *pending[1:3], "@ last")
+    assert spatial.total_row_moves > 0
+    assert spatial.total_relayouts["incremental"] > 0
+    if case == "replan":
+        assert rebuilds >= 2
+    assert spatial._jit_step._cache_size() == 1
+    assert spatial.total_fallbacks <= spatial.total_replans
+
+
+def test_full_strip_relayout_falls_back_exactly():
+    """A strip whose rows are all placed has no free row for a slot
+    moving in: the tick rebuilds the layout whole instead of swapping
+    (a slot leaves the same tick, so the strip still fits), and a net
+    inflow past its rows takes the strip-overflow path, deferring the
+    moves; every tick stays exact, and a later crossing swaps again."""
+    spatial = make_engines(replan_interval=0)[1]
+    chunk = spatial.chunk  # 64 rows; strip s owns x in [800s, 800s + 800)
+    pos = np.zeros((N, 2), np.float32)
+    active = np.zeros(N, bool)
+    space = np.zeros(N, np.int32)
+    radius = np.full(N, 100.0, np.float32)
+    rng = np.random.default_rng(43)
+    # Strip 1 holds ``chunk`` entities, all in column 12, so no re-plan
+    # can split them; strips 0 and 2 hold 40 each.
+    active[:chunk + 80] = True
+    pos[:chunk, 0] = rng.uniform(1205, 1295, chunk)
+    pos[chunk:chunk + 40, 0] = rng.uniform(100, 600, 40)
+    pos[chunk + 40:chunk + 80, 0] = rng.uniform(1700, 2300, 40)
+    pos[:chunk + 80, 1] = rng.uniform(100, 1500, chunk + 80)
+    prev_keys: set = set()
+
+    def tick(tag, mode="spatial"):
+        nonlocal prev_keys
+        keys = brute_keys(pos, active, space, radius)
+        before = dict(spatial.total_relayouts)
+        assert_events_exact(spatial.step(pos, active, space, radius),
+                            prev_keys, keys, tag)
+        assert spatial.last_mode == mode, (tag, spatial.last_mode)
+        prev_keys = keys
+        return {k: spatial.total_relayouts[k] - before[k] for k in before}
+
+    none = {"incremental": 0, "rebuild": 0}
+    assert tick("setup") == {"incremental": 0, "rebuild": 1}
+    assert spatial.shard_population[1] == chunk
+    # Exchange: one of strip 1 walks into strip 2 while one of strip 0
+    # walks into strip 1, which fits (64) but has no free row.
+    pos[0] = (1590.0, 800.0)
+    pos[chunk] = (790.0, 800.0)
+    assert tick("staging") == none
+    pos[0] = (1705.0, 800.0)
+    pos[chunk] = (905.0, 800.0)
+    assert tick("exchange") == {"incremental": 0, "rebuild": 1}
+    assert spatial.shard_population[1] == chunk
+    # Net inflow: two more walk in from strip 0 and none leave.
+    pos[chunk + 1] = (790.0, 300.0)
+    pos[chunk + 2] = (790.0, 1300.0)
+    tick("staging 2")
+    pos[chunk + 1] = (905.0, 300.0)
+    pos[chunk + 2] = (905.0, 1300.0)
+    assert tick("inflow", "fallback:strip_overflow") == none
+    pos[chunk + 1] = (790.0, 300.0)  # still in strip 1's slack band
+    pos[chunk + 2] = (790.0, 1300.0)
+    assert tick("back", "fallback:strip_overflow") == none
+    # Back past the band: the deferred moves cancel, nothing to relayout.
+    pos[chunk + 1] = (690.0, 300.0)
+    pos[chunk + 2] = (690.0, 1300.0)
+    assert tick("back 2") == none
+    # A crossing into strip 3, which has free rows, swaps.
+    pos[chunk + 40] = (2390.0, 800.0)
+    tick("staging 3")
+    pos[chunk + 40] = (2505.0, 800.0)
+    assert tick("cross") == {"incremental": 1, "rebuild": 0}
+    assert spatial.total_fallbacks == 2
+
+
 def test_plan_strips_properties():
     """Planner unit: boundaries cover [0, gx], honor the minimum width,
     and an 8x density skew pulls more columns into the sparse strips."""
@@ -392,15 +558,26 @@ def test_telemetry_counters_move():
 
     single, spatial = make_engines()
     halo0 = telemetry.counter("aoi_halo_bytes_total").value
+    relayouts = telemetry.counter("aoi_shard_relayouts_total",
+                                  labelnames=("kind",))
+    kinds = ("incremental", "rebuild")
+    relayouts0 = {k: relayouts.labels(k).value for k in kinds}
     rng, pos, active, space, radius = make_world(400, seed=17)
-    for _ in range(3):
+    for _ in range(6):
         spatial.step(pos, active, space, radius)
         pos = np.clip(
-            pos + rng.normal(0, 20, pos.shape), 0, WORLD_X
+            pos + rng.normal(0, 40, pos.shape), 0, WORLD_X
         ).astype(np.float32)
     assert telemetry.counter("aoi_halo_bytes_total").value >= (
-        halo0 + 3 * spatial.halo_bytes_per_tick
+        halo0 + 6 * spatial.halo_bytes_per_tick
     )
+    # The relayout counter moves with the engine's own counts: the set-up
+    # rebuild, then swaps for the seam crossings.
+    assert spatial.total_relayouts["rebuild"] == 1
+    assert spatial.total_relayouts["incremental"] >= 1
+    for k in kinds:
+        assert relayouts.labels(k).value - relayouts0[k] == (
+            spatial.total_relayouts[k])
     assert telemetry.gauge("aoi_shard_count").value == 8
     got = sum(
         int(telemetry.gauge("aoi_shard_entities", labelnames=("shard",))

@@ -113,6 +113,76 @@ def test_pallas_strip_parity_with_migrations_replans_and_drops():
     assert spatial.total_fallbacks == 0
 
 
+def brute_keys(pos, active, space, radius):
+    """Every valid (watcher, other) pair of one epoch as ``i * N + j``,
+    by brute force over all pairs."""
+    d2 = ((pos[:, None, :] - pos[None, :, :]) ** 2).sum(-1)
+    ok = (active[:, None] & active[None, :]
+          & (space[:, None] == space[None, :])
+          & (d2 <= radius[:, None] ** 2))
+    np.fill_diagonal(ok, False)
+    i, j = np.nonzero(ok)
+    return set((i * N + j).tolist())
+
+
+def assert_events_exact(got, prev_keys, cur_keys, tag):
+    enters, leaves, dropped = got
+    assert dropped == 0, tag
+    for pairs, want, side in ((enters, cur_keys - prev_keys, "enters"),
+                              (leaves, prev_keys - cur_keys, "leaves")):
+        p = np.asarray(pairs, np.int64).reshape(-1, 2)
+        assert set((p[:, 0] * N + p[:, 1]).tolist()) == want, (
+            f"{side} differ {tag}")
+        assert len(p) == len(want), f"duplicate {side} {tag}"
+
+
+@pytest.mark.parametrize("case,ticks", [("walk", 20), ("pipelined", 6)])
+def test_pallas_strip_incremental_relayout_exact(case, ticks):
+    """Seam crossings on the strip kernel move only the migrated rows:
+    every tick's events equal the brute-force reference, with the meta
+    upload elided except on churn ticks, while rows swap in place. The
+    pipelined case dispatches tick t+1 (its rows swapped) before tick t
+    is collected: tick t's pairs map through its own layout."""
+    spatial = make_engines(replan_interval=0)[1]
+    rng, pos, active, space, radius = make_world(400, seed=41)
+    prev_keys: set = set()
+    pending = None
+    swaps = 0
+    for tick in range(ticks):
+        churn = tick % 6 == 5
+        if churn:
+            active = active.copy()
+            active[rng.integers(0, N, 8)] ^= True
+        keys = brute_keys(pos, active, space, radius)
+        before = spatial.total_relayouts["incremental"]
+        pend = spatial.step_async(pos, active, space, radius,
+                                  meta_dirty=tick == 0 or churn)
+        assert spatial.last_mode == "spatial", spatial.last_mode
+        if spatial.total_relayouts["incremental"] > before and tick:
+            swaps += 1
+        if case == "pipelined":
+            if pending is not None:
+                assert_events_exact(pending[0].collect(), *pending[1:],
+                                    f"@ tick {tick - 1}")
+            pending = (pend, prev_keys, keys)
+        else:
+            assert_events_exact(pend.collect(), prev_keys, keys,
+                                f"@ tick {tick}")
+        prev_keys = keys
+        pos = pos + rng.normal(0, 25, pos.shape).astype(np.float32)
+        np.clip(pos[:, 0], 0, WORLD_X, out=pos[:, 0])
+        np.clip(pos[:, 1], 1.0, WORLD_Z - 1.0, out=pos[:, 1])
+        pos = pos.astype(np.float32)
+    if pending is not None:
+        assert_events_exact(pending[0].collect(), *pending[1:], "@ last")
+    assert swaps >= 2, "too few ticks swapped rows"
+    assert spatial.total_row_moves > 0
+    # Only the set-up dispatch rebuilds the layout whole.
+    assert spatial.total_relayouts["rebuild"] == 1
+    assert spatial._jit_step._cache_size() == 1
+    assert spatial.total_fallbacks == 0
+
+
 def test_pallas_strip_fast_path_one_launch_trace_pin():
     """Seam-free steady-state ticks (radius 40, ~4-unit drift keeps the
     replicated guard TRUE) must (a) match the single-device stream, (b)
