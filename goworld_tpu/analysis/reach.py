@@ -6,7 +6,7 @@ conservative in one direction only (a reported symbol really has no
 textual reference anywhere), so findings are reviewed and deleted by a
 human, not failed by CI.  References are gathered from the package plus
 every caller surface that legitimately reaches into it: tests/, tools/,
-bench.py, examples/, and the graft entry point.
+examples/, and the graft entry point.
 
 A module-level def counts as referenced if its bare name appears
 anywhere outside its own definition as a Name load, an attribute access,
@@ -29,7 +29,7 @@ from goworld_tpu.analysis.core import ParsedModule, iter_py_files
 
 #: caller surfaces outside the package whose references keep symbols alive
 EXTRA_ROOTS = ("tests", "tools", "examples")
-EXTRA_FILES = ("bench.py", "__graft_entry__.py")
+EXTRA_FILES = ("__graft_entry__.py",)
 
 
 @dataclasses.dataclass

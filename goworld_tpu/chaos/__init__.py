@@ -11,11 +11,12 @@ assert zero bot errors, zero entity loss, and recovery within a deadline.
 The seventh scenario — migrate-during-dispatcher-restart — needs two real
 game processes (the entity manager is per-process state) and lives in the
 subprocess-backed multigame harness (``chaos/multigame.py``), which also
-carries the ``bench.py --multigame`` rebalance floor.
+measures live rebalancing. ``chaos/massive.py`` serves 1000+ client
+sockets from bot-fleet subprocesses (``chaos/botfleet.py``).
 
 Entry points: the scenario coroutines here (used by tests/test_chaos.py)
-and ``bench.py --chaos`` (one compact JSON headline like the other bench
-modes).
+and ``python -m goworld_tpu.chaos``, which runs the catalog on both
+transports and prints one JSON summary.
 """
 
 from goworld_tpu.chaos.harness import (  # noqa: F401

@@ -1,7 +1,7 @@
 """Subprocess bot fleets: thousands of REAL client sockets for the
-massive fan-out floor (``bench.py --fanout-massive``).
+massive fan-out run (``chaos/massive.py``).
 
-The ``--multigame`` move applied to the CLIENT side: one parent process
+The multigame harness's move applied to the CLIENT side: one parent process
 cannot pump 1000+ asyncio sockets beside an in-process cluster on a small
 host, so the bots live in K fleet subprocesses of N bots each, each a full
 :class:`goworld_tpu.client.ClientBot` (entity mirrors, keyframe/delta

@@ -1,8 +1,8 @@
 """In-process chaos cluster: real sockets, real services, injected faults.
 
-The cluster plumbing mirrors ``bench.py --fanout`` (dispatcher + game +
-gate over localhost TCP, protocol bots on the gate) extended to N
-dispatchers and fault injectors. Everything runs in ONE asyncio loop and
+The cluster is N dispatchers + one game + one or more gates over
+localhost TCP (or unix sockets), with strict protocol bots on the gates,
+plus fault injectors. Everything runs in ONE asyncio loop and
 ONE process — "killing a dispatcher" stops its service after aborting its
 sockets (RST, not FIN: peers see a crash, not a shutdown), "pausing" one
 stalls its logic/tick loops with sockets open (the half-open-link case the
@@ -52,10 +52,10 @@ from goworld_tpu.utils import gwlog
 
 AOI_DISTANCE = 100.0
 
-# Per-scenario recovery time, scraped from /metrics and summed into the
-# bench --chaos headline (satellite of ISSUE 10: today's harness only
-# surfaced the worst recovery). Gauge, not histogram: each scenario runs
-# once per suite and the CURRENT value is the interesting one.
+# Per-scenario recovery time, scraped from /metrics (the summary of
+# ``python -m goworld_tpu.chaos`` carries the same numbers per scenario).
+# Gauge, not histogram: each scenario runs once per suite and the
+# CURRENT value is the interesting one.
 _RECOVERY = telemetry.gauge(
     "chaos_recovery_seconds",
     "Recovery (or detection) seconds of the last run of each chaos "
@@ -65,6 +65,7 @@ _RECOVERY = telemetry.gauge(
 class _Holder:
     arena = None
     joined = 0
+    gap = 3.0
 
 
 class ChaosSpace(Space):
@@ -91,9 +92,8 @@ class ChaosAvatar(Entity):
         arena = _Holder.arena
         if arena is not None:
             # Clustered inside one AOI radius: full mutual interest, so
-            # position syncs and creates fan out bot-to-bot (real traffic
-            # shapes, like the fanout bench).
-            x = 3.0 * _Holder.joined
+            # position syncs and creates fan out bot-to-bot.
+            x = _Holder.gap * _Holder.joined
             _Holder.joined += 1
             self.enter_space(arena.id, Vector3(x, 0.0, 10.0))
         self.set_client_syncing(True)
@@ -148,7 +148,9 @@ def dropped_packet_count() -> float:
 
 
 class ChaosCluster:
-    """N dispatchers + 1 game + 1 gate + strict bots, with fault hooks."""
+    """N dispatchers + 1 game + ``n_gates`` gates + strict bots, with fault
+    hooks. Bots connect to the gates in turn; the gate fault injectors
+    act on gate 1."""
 
     def __init__(
         self,
@@ -163,15 +165,17 @@ class ChaosCluster:
         storage_knobs: Optional[dict] = None,
         sync_knobs: Optional[dict] = None,
         transport: str = "tcp",
+        n_gates: int = 1,
     ) -> None:
         self.run_dir = run_dir
         self.n_dispatchers = n_dispatchers
         self.n_bots = n_bots
+        self.n_gates = n_gates
         self.peer_heartbeat_timeout = peer_heartbeat_timeout
         # "uds": the game/gate↔dispatcher links ride Unix-domain sockets
         # (socket files under run_dir) — crash/replay/liveness semantics
         # must be transport-identical, and every scenario asserts exactly
-        # that when run on both transports (bench.py --chaos).
+        # that when run on both transports (run_chaos).
         self.transport = transport
         self.uds_dir = run_dir if transport == "uds" else None
         self.cluster_cfg = ClusterConfig(
@@ -190,7 +194,7 @@ class ChaosCluster:
         self.dispatchers: list[Optional[DispatcherService]] = []
         self.ports: list[int] = []
         self.game: Optional[GameService] = None
-        self.gate: Optional[GateService] = None
+        self.gates: list[Optional[GateService]] = []
         self.bots: list[ClientBot] = []
         self._game_task: Optional[asyncio.Task] = None
         self._ping_seq = 0
@@ -205,11 +209,14 @@ class ChaosCluster:
         em.cleanup_for_tests()
         _Holder.arena = None
         _Holder.joined = 0
+        # Past 30 bots the spacing shrinks so the whole line stays inside
+        # one AOI radius.
+        _Holder.gap = min(3.0, 90.0 / max(1, self.n_bots))
         em.register_space(ChaosSpace)
         em.register_entity(ChaosAvatar)
         for i in range(self.n_dispatchers):
             d = DispatcherService(
-                i + 1, desired_games=1, desired_gates=1,
+                i + 1, desired_games=1, desired_gates=self.n_gates,
                 peer_heartbeat_timeout=self.peer_heartbeat_timeout)
             await d.start(uds_dir=self.uds_dir)
             self.dispatchers.append(d)
@@ -217,7 +224,7 @@ class ChaosCluster:
 
         cfg = GoWorldConfig()
         cfg.deployment = DeploymentConfig(
-            desired_games=1, desired_gates=1,
+            desired_games=1, desired_gates=self.n_gates,
             desired_dispatchers=self.n_dispatchers)
         cfg.dispatchers = {
             i + 1: DispatcherConfig(port=p) for i, p in enumerate(self.ports)
@@ -225,9 +232,12 @@ class ChaosCluster:
         cfg.games = {1: GameConfig(
             boot_entity="ChaosAvatar", save_interval=0.0,
             position_sync_interval=self.sync_interval)}
-        cfg.gates = {1: GateConfig(
-            port=0, position_sync_interval=self.sync_interval,
-            heartbeat_timeout=30.0)}
+        cfg.gates = {
+            g: GateConfig(
+                port=0, position_sync_interval=self.sync_interval,
+                heartbeat_timeout=30.0)
+            for g in range(1, self.n_gates + 1)
+        }
         cfg.aoi = AOIConfig(backend="xzlist")  # host pipeline only, no jax
         cfg.storage = StorageConfig(
             type="filesystem", directory=self.run_dir + "/es",
@@ -249,8 +259,10 @@ class ChaosCluster:
         self.game = GameService(1, cfg, restore=False)
         self._game_task = asyncio.get_running_loop().create_task(
             self.game.run_async())
-        self.gate = GateService(1, cfg)
-        await self.gate.start()
+        for g in range(1, self.n_gates + 1):
+            gate = GateService(g, cfg)
+            await gate.start()
+            self.gates.append(gate)
         await self._wait(lambda: self.game.deployment_ready, 15.0,
                          "cluster never became deployment-ready")
         em.create_space_locally(1)
@@ -258,7 +270,8 @@ class ChaosCluster:
         # The gate's bound port survives restarts: a recreated GateService
         # must come back on the SAME address or clients could never
         # reconnect to a crashed gate in production either.
-        self.cfg.gates[1].port = self.gate.port
+        for g, gate in enumerate(self.gates, 1):
+            self.cfg.gates[g].port = gate.port
         await self._spawn_bots()
 
     async def _spawn_bots(self) -> None:
@@ -274,7 +287,8 @@ class ChaosCluster:
             self._pongs[bot.name] = []
             bot.rpc_handlers[(None, "Pong")] = (
                 lambda entity, n, name=bot.name: self._pongs[name].append(n))
-            await bot.connect("127.0.0.1", self.gate.port)
+            gate = self.gates[i % len(self.gates)]
+            await bot.connect("127.0.0.1", gate.port)
             await bot.wait_player(timeout=10)
             self.bots.append(bot)
         await self._wait(
@@ -295,8 +309,9 @@ class ChaosCluster:
 
         for b in self.bots:
             await b.close()
-        if self.gate is not None:
-            await self.gate.stop()
+        for gate in self.gates:
+            if gate is not None:
+                await gate.stop()
         if self.game is not None:
             self.game.terminate()
             try:
@@ -333,7 +348,7 @@ class ChaosCluster:
     def links_up(self) -> bool:
         return all(
             m.proxy is not None
-            for svc in (self.game, self.gate)
+            for svc in (self.game, *self.gates)
             for m in svc.cluster._mgrs
         )
 
@@ -359,15 +374,20 @@ class ChaosCluster:
                 raise RuntimeError("game down")
             return {"health": self.game._health(), "metrics": {}}
 
-        async def gate_fetch() -> dict:
-            if self.gate is None:
-                raise RuntimeError("gate down")
-            return {"health": self.gate._health(), "metrics": {}}
+        def gate_fetch(i: int):
+            async def fetch() -> dict:
+                gate = self.gates[i]
+                if gate is None:
+                    raise RuntimeError("gate down")
+                return {"health": gate._health(), "metrics": {}}
+
+            return fetch
 
         targets = [(f"dispatcher{i + 1}", disp_fetch(i))
                    for i in range(self.n_dispatchers)]
         targets.append(("game1", game_fetch))
-        targets.append(("gate1", gate_fetch))
+        targets.extend((f"gate{i + 1}", gate_fetch(i))
+                       for i in range(self.n_gates))
         return targets
 
     async def assert_cluster_view_converged(
@@ -461,7 +481,7 @@ class ChaosCluster:
 
     async def restart_dispatcher(self, i: int) -> None:
         d = DispatcherService(
-            i + 1, desired_games=1, desired_gates=1,
+            i + 1, desired_games=1, desired_gates=self.n_gates,
             peer_heartbeat_timeout=self.peer_heartbeat_timeout)
         for _ in range(100):  # the old socket may linger briefly
             try:
@@ -528,14 +548,15 @@ class ChaosCluster:
         """Crash the gate: RST every client socket and dispatcher link,
         then drop the listeners. Clients see a dead server; the
         dispatcher sees a vanished gate (reconnect-grace window starts)."""
-        assert self.gate is not None
-        for cp in list(self.gate.clients.values()):
+        gate = self.gates[0]
+        assert gate is not None
+        for cp in list(gate.clients.values()):
             cp.conn.conn.abort()
-        for m in self.gate.cluster._mgrs:
+        for m in gate.cluster._mgrs:
             if m.proxy is not None:
                 m.proxy.conn.abort()
-        await self.gate.stop()
-        self.gate = None
+        await gate.stop()
+        self.gates[0] = None
         gwlog.infof("chaos: gate killed")
 
     async def restart_gate(self) -> None:
@@ -553,7 +574,7 @@ class ChaosCluster:
             raise AssertionError(
                 f"chaos: could not rebind gate port "
                 f"{self.cfg.gates[1].port}")
-        self.gate = gate
+        self.gates[0] = gate
         gwlog.infof("chaos: gate restarted on port %d", gate.port)
 
 
@@ -1192,15 +1213,16 @@ async def scenario_battle_royale_keyframe_storm(
 
 def run_chaos(run_dir: str, n_dispatchers: int = 2, n_bots: int = 12,
               transport: str = "tcp", slo=None) -> dict:
-    """Run the single-cluster scenario suite (``bench.py --chaos``;
-    ``transport`` = "tcp" or "uds" — the fault semantics must be
-    transport-identical and every scenario asserts its own invariants
-    either way). Returns a JSON-able summary with per-scenario recovery
-    times and bot-error counts; a scenario failure is CAPTURED (named in
-    ``failures``) and aborts the remaining scenarios on this cluster —
-    the caller decides the exit code, so one red scenario can never hide
-    the others' numbers. A failed scenario also leaves a post-mortem
-    bundle (named in its failure entry) holding every history ring.
+    """Run the single-cluster scenario suite (``python -m
+    goworld_tpu.chaos``; ``transport`` = "tcp" or "uds" — the fault
+    semantics must be transport-identical and every scenario asserts its
+    own invariants either way). Returns a JSON-able summary with
+    per-scenario recovery times and bot-error counts; a scenario failure
+    is CAPTURED (named in ``failures``) and aborts the remaining
+    scenarios on this cluster — the caller decides the exit code, so one
+    red scenario can never hide the others' numbers. A failed scenario
+    also leaves a post-mortem bundle (named in its failure entry) holding
+    every history ring.
 
     ``slo`` is an optional :class:`SLOConfig`: with a
     ``bot_error_rate`` budget set, the suite's aggregate bot-error rate

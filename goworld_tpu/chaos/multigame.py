@@ -8,9 +8,9 @@ measurable: the parent holds the dispatcher objects, so the rebalancer's
 report table, the migration counters, the space-handoff park table, and
 the kvreg store are directly observable with no scraping.
 
-Entry points, all used by bench.py:
+Entry points, driven by tier-1 tests and ``python -m goworld_tpu.chaos``:
 
-- ``run_multigame`` (the ``--multigame`` floor): the pinned 2-game shape —
+- ``run_multigame``: the pinned 2-game shape —
   boot with a deliberately fully skewed placement (boot is game1-only,
   every avatar lands in game1's arena), resume the planner at t0, and
   measure rebalance convergence — then run the
@@ -564,29 +564,42 @@ class MultigameCluster:
 
     # --- phases --------------------------------------------------------------
 
-    async def wait_balanced(self, deadline: float = 30.0,
-                            what: str = "never balanced") -> float:
-        """Wait until per-game populations are balanced AND stable.
-        Stability must SPAN report cycles (the census is read from the
-        cached reports): balanced and unchanged for 3 report intervals,
-        with the sum conserved (an in-flight migration makes it dip).
-        Does NOT touch planner pause state. Returns the wait's length."""
+    def _balanced_probe(self, games: list[int]):
+        """A poll predicate: ``games`` balanced AND stable, by the
+        planner's own rule and input. The planner balances the per-game
+        entity counts of the load reports (spaces and service entities
+        included) and acts on a gap of at least ``min_entity_delta``, so
+        balanced is a spread below that gap. The arena census is not the
+        planner's quantity: empty arenas left behind by whole-space moves
+        and the planner-service entity make the two differ by a few
+        entities per game. Stability must SPAN report cycles (both are
+        read from the cached reports): unchanged for 3 report intervals,
+        with the census sum conserved (an in-flight migration makes it
+        dip)."""
         tol = self.rebalance_cfg.min_entity_delta
         span = 3.0 * self.rebalance_cfg.report_interval
-        t0 = time.monotonic()
-        state = {"census": None, "since": 0.0}
+        state = {"seen": None, "since": 0.0}
 
         def balanced() -> bool:
-            c = self.census()
+            census = tuple(self._game_pop(g) for g in games)
+            load = tuple(self._planner().reports.entities(g) for g in games)
             now = time.monotonic()
-            if c != state["census"]:
-                state["census"], state["since"] = c, now
-            return (sum(c) == self.n_bots
-                    and max(c) - min(c) <= tol
+            if (census, load) != state["seen"]:
+                state["seen"], state["since"] = (census, load), now
+            return (sum(census) == self.n_bots
+                    and max(load) - min(load) < tol
                     and now - state["since"] >= span)
 
+        return balanced
+
+    async def wait_balanced(self, deadline: float = 30.0,
+                            what: str = "never balanced") -> float:
+        """Wait until the fleet is balanced and stable
+        (``_balanced_probe``). Does NOT touch planner pause state.
+        Returns the wait's length."""
+        t0 = time.monotonic()
         await self._wait(
-            balanced, deadline, what,
+            self._balanced_probe(self.game_ids()), deadline, what,
             on_fail=lambda: (
                 f"census {self.census()}, reports "
                 f"{ {g: self._report(g) for g in self.game_ids()} }\n"
@@ -838,21 +851,8 @@ class MultigameCluster:
         # evacuated arenas sit wherever we pushed them, so the failed-over
         # planner has real work to do.
         self._resume_planners()
-        tol = self.rebalance_cfg.min_entity_delta
-        span = 3.0 * self.rebalance_cfg.report_interval
-        state = {"census": None, "since": 0.0}
-
-        def balanced() -> bool:
-            c = tuple(self._game_pop(g) for g in survivors)
-            now = time.monotonic()
-            if c != state["census"]:
-                state["census"], state["since"] = c, now
-            return (sum(c) == self.n_bots
-                    and max(c) - min(c) <= tol
-                    and now - state["since"] >= span)
-
         await self._wait(
-            balanced, deadline,
+            self._balanced_probe(survivors), deadline,
             "failed-over planner never rebalanced the survivors",
             on_fail=lambda: (
                 f"census {self.census()}, planner host "
@@ -1023,8 +1023,8 @@ async def _run_multigame(run_dir: str, n_bots: int, transport: str,
 
 def run_multigame(run_dir: str, n_bots: int = 12, transport: str = "tcp",
                   with_restart_phase: bool = True) -> dict:
-    """Blocking driver (bench.py --multigame / the dispatcher-restart
-    chaos scenario): the pinned 2-game floor shape."""
+    """Blocking driver of the pinned 2-game shape: live rebalancing, then
+    the migrate-during-dispatcher-restart chaos phase."""
     return asyncio.run(
         _run_multigame(run_dir, n_bots, transport, with_restart_phase))
 

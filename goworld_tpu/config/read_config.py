@@ -411,9 +411,10 @@ class SLOConfig:
 @dataclasses.dataclass
 class ScenarioConfig:
     """Scenario-matrix runner knobs (``[scenario]``; goworld_tpu/
-    scenarios/).  These parameterize DEVELOPMENT runs only — bench.py's
-    gate mode always passes the registry's fixed config + seed so
-    committed floors never drift with an operator's ini."""
+    scenarios/).  These parameterize DEVELOPMENT runs only: they are read
+    when ``run_scenario`` is passed None for engine, seed or ticks_scale,
+    and tests always pass them, so no test drifts with an operator's
+    ini."""
 
     # Seed for ad-hoc scenario runs (the registry's per-scenario fixed
     # seed is used when < 0).

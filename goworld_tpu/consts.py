@@ -89,9 +89,9 @@ TRACE_RING_SIZE = 4096
 # Slow-tick flight recorder: a game tick busier than this many seconds
 # dumps the last FLIGHT_RING_SIZE tick records + the tick's sampled spans
 # as ONE structured WARN (kept on GET /flight). Default 0.1 s ≈ 2x the
-# ~48 ms busy tick of the committed pinned-floor config (BENCH_FLOOR.json:
-# 2048 entities / 42k upd/s) — production 5 ms ticks only ever get near it
-# when something is genuinely wrong (jit recompile, storage stall, GC).
+# ~48 ms busy CPU tick of a 2048-entity jnp AOI step — production 5 ms
+# ticks only ever get near it when something is genuinely wrong (jit
+# recompile, storage stall, GC).
 SLOW_TICK_BUDGET = 0.1
 FLIGHT_RING_SIZE = 240
 

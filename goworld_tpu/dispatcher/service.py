@@ -57,8 +57,8 @@ _SYNC_RECORDS = telemetry.counter(
     "Position-sync records routed through the dispatcher, by direction.",
     ("dispid", "dir"))
 # Wall seconds spent in each hop of the sync fan-out pipeline (game pack →
-# dispatcher route → gate demux → client write); bench.py --fanout turns
-# deltas of these into the per-hop shares in its headline JSON.
+# dispatcher route → gate demux → client write); deltas of these over a
+# window give each hop's share of the pipeline's busy time.
 _HOP_SECONDS = telemetry.counter(
     "fanout_hop_seconds_total",
     "Busy wall seconds per sync fan-out hop (game_collect|game_pack|"
@@ -222,6 +222,12 @@ class DispatcherService:
         # on SPACE_MIGRATE_ACK (receiver restored), SPACE_MIGRATE_ABORT
         # (donor unfroze in place), or the deadline sweep.
         self._space_handoffs: dict[str, tuple[float, list]] = {}
+        # SPACE_MIGRATE_DATA this dispatcher wrote straight to its target's
+        # socket while the handoff is open: spaceid → (target game, packet).
+        # A target that dies before reading it takes the write with it, so
+        # the payload is kept until the handoff closes (ACK, ABORT or the
+        # deadline) and bounced home if the target is declared dead first.
+        self._space_in_flight: dict[str, tuple[int, Packet]] = {}
         self.deployment_ready = False
         self._boot_rr = 0
         self._lbc = LBCHeap()
@@ -716,15 +722,21 @@ class DispatcherService:
         for gameid, gi in list(self.games.items()):
             if gi.proxy is None and gi.block_until and not gi.blocked(now):
                 gi.block_until = 0.0
-                # Buffered REAL_MIGRATE / SPACE_MIGRATE_DATA payloads are
+                # Buffered REAL_MIGRATE / SPACE_MIGRATE_DATA payloads, and
+                # space payloads written to the link before it died, are
                 # entities' (or a whole space's) LAST copies: bounce each
                 # home before the buffer drops (the trailing source-gameid
                 # makes this possible without the long-gone forwarding
                 # proxy).
-                for msgtype, packet in gi.pending:
-                    if msgtype not in (MsgType.REAL_MIGRATE,
-                                       MsgType.SPACE_MIGRATE_DATA):
-                        continue
+                lost = [(msgtype, packet) for msgtype, packet in gi.pending
+                        if msgtype in (MsgType.REAL_MIGRATE,
+                                       MsgType.SPACE_MIGRATE_DATA)]
+                for spaceid, (target, packet) in list(
+                        self._space_in_flight.items()):
+                    if target == gameid:
+                        del self._space_in_flight[spaceid]
+                        lost.append((MsgType.SPACE_MIGRATE_DATA, packet))
+                for msgtype, packet in lost:
                     eid = packet.read_entity_id()
                     packet.set_read_pos(0)
                     if not self._bounce_migrate_home(
@@ -1391,6 +1403,9 @@ class DispatcherService:
         info.gameid = target_game
         self._mig_routed.inc()
         self.migrates_routed += 1
+        if (spaceid in self._space_handoffs and gi.connected
+                and not gi.blocked(now)):
+            self._space_in_flight[spaceid] = (target_game, packet)
         gi.dispatch(MsgType.SPACE_MIGRATE_DATA, packet, now)
         self._flush_entity_pending(info)
 
@@ -1416,6 +1431,7 @@ class DispatcherService:
         self._release_space_handoff(spaceid)
 
     def _release_space_handoff(self, spaceid: str) -> bool:
+        self._space_in_flight.pop(spaceid, None)
         entry = self._space_handoffs.pop(spaceid, None)
         if entry is None:
             return False

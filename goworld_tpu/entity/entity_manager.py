@@ -39,7 +39,7 @@ from goworld_tpu.utils.timer import TimerService
 # game/service.py and the dispatcher/gate hops): the game-side half is
 # split into collect (flag scan + interest-edge gather over the slabs)
 # and pack (per-gate structured-array build + wire bytes) so a fan-out
-# regression names the sub-stage (bench.py --fanout hop_shares).
+# regression names the sub-stage.
 _HOP = telemetry.counter(
     "fanout_hop_seconds_total",
     "Busy wall seconds per sync fan-out hop (game_collect|game_pack|"
@@ -488,7 +488,7 @@ def collect_entity_sync_infos() -> dict[int, tuple[bytes, bytes]]:
     are dropped STRUCTURALLY: slot release / client unbind clear the flag
     and cid columns the masks read. Wall time lands on
     fanout_hop_seconds_total{hop=game_collect|game_pack} (the two
-    game-side sub-hops of bench.py --fanout's breakdown)."""
+    game-side sub-hops of the fan-out's per-hop breakdown)."""
     slabs = runtime.slabs
     t0 = time.perf_counter()
     if not slabs.sync.enabled:

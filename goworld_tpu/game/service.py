@@ -28,8 +28,8 @@ from goworld_tpu.telemetry import sentinel, tracing
 from goworld_tpu.utils import async_jobs, crontab, gwlog, gwutils, post
 
 # Sync fan-out per-hop attribution (shared family with the dispatcher's
-# dispatcher_route and the gate's gate_demux/client_write hops; bench.py
-# --fanout reads the deltas into per-hop shares). The game side is split
+# dispatcher_route and the gate's gate_demux/client_write hops; the deltas
+# over a window split its busy time by hop). The game side is split
 # into game_collect + game_pack (entity_manager.collect_entity_sync_infos
 # owns both compute sub-hops) and game_send — the per-gate dispatcher-link
 # writes below, kept separate so pack COMPUTE is attributable apart from
@@ -540,8 +540,8 @@ class GameService:
                 # inside the tick window: a fixed 5 ms wait ADDS to the
                 # iteration's work time, so the configured sync rate ran
                 # ~25% slow on a quiet queue (6.3 ms achieved periods at a
-                # 5 ms interval — bench.py --fanout is cadence-bound on
-                # exactly this).
+                # 5 ms interval — a fan-out whose delivered records are
+                # bound by the cadence loses exactly that).
                 timeout = tick
                 if self.position_sync_interval > 0:
                     due = (self._last_sync_collect
@@ -648,9 +648,9 @@ class GameService:
                 # doesn't stretch the average sync period — the configured
                 # position_sync_interval is a rate, and under load the old
                 # fixed-delay reset ran it ~25% slow (5 ms config, ~6.2 ms
-                # achieved — measured by bench.py --fanout, where delivered
-                # records are cadence-bound). Clamped to one interval of
-                # backlog: a long stall must not trigger a catch-up burst.
+                # achieved on a cadence-bound fan-out). Clamped to one
+                # interval of backlog: a long stall must not trigger a
+                # catch-up burst.
                 self._last_sync_collect = max(
                     self._last_sync_collect + self.position_sync_interval,
                     now - self.position_sync_interval,
@@ -729,8 +729,7 @@ class GameService:
         entity slabs — entity_manager.collect_entity_sync_infos). Wall
         time lands on fanout_hop_seconds_total{hop="game_collect"|
         "game_pack"}; the dispatcher-link writes below land on game_send —
-        the game-side hops of the per-hop breakdown bench.py --fanout
-        reports."""
+        the game-side hops of the fan-out's per-hop breakdown."""
         per_gate = entity_manager.collect_entity_sync_infos()
         if not per_gate:
             return

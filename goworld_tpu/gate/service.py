@@ -74,7 +74,8 @@ _KILLS_HEARTBEAT = _CLIENT_KILLS.labels("heartbeat")
 _KILLS_ERROR = _CLIENT_KILLS.labels("error")
 
 # Sync fan-out per-hop attribution (shared family with game_pack and
-# dispatcher_route; bench.py --fanout reads the deltas into shares):
+# dispatcher_route; the deltas over a window split its busy time by hop,
+# tests/test_telemetry.py::test_fanout_pipeline_delivers):
 # gate_demux = the argsort demux of one sync packet, client_write = the
 # end-of-batch uncork sweep that actually writes the corked client conns.
 _HOP_SECONDS = _telemetry.counter(

@@ -1437,8 +1437,9 @@ class PendingStep:
     def wait_device(self) -> None:
         """Block until the device step has finished computing the packed
         result (collect() after this times only the host copy + unpack).
-        Latency instrumentation seam: the BASELINE p99 diff-latency budget
-        is measured from step completion to events-on-host (bench.py)."""
+        Latency instrumentation seam: the time from step completion to
+        events on the host is the ``readback`` share of a tick's
+        latency."""
         jax.block_until_ready(self._out)
 
     def collect(self) -> tuple[np.ndarray, np.ndarray, int]:

@@ -4,8 +4,8 @@ Every committed floor before this package measured ONE workload shape —
 mutually-interested bots on a uniform grid.  A scenario is a first-class
 workload object instead: a movement model + entity lifecycle + interest
 profile + per-tick assertions, built from a fixed config and a seed, so
-bench.py (``--scenario <name>``), the chaos harness, and tests all drive
-the SAME definition through one interface.
+the chaos harness and tests drive the SAME definition through one
+interface.
 
 Contract:
 

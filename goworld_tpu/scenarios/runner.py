@@ -18,9 +18,8 @@ Engines: ``batched`` is the single-device ``NeighborEngine`` on the jnp
 backend; ``sharded`` is the grid-strip ``SpatialShardedNeighborEngine``
 on a forced multi-device CPU mesh (the caller must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=<shards>`` before the
-first jax import — bench.py and the tests run this in a subprocess for
-exactly that reason).  The scenario definition is identical either way;
-only ``make_engine`` differs.
+first jax import, as tests/conftest.py does).  The scenario definition
+is identical either way; only ``make_engine`` differs.
 """
 
 from __future__ import annotations
@@ -88,7 +87,7 @@ class InterestOracle:
 def make_engine(config: Dict[str, Any], engine: str) -> Any:
     """Build the AOI engine a scenario runs on. ``batched`` | ``sharded``.
     Runs on JAX's default platform: a caller that wants the CPU selects it
-    (tests/conftest.py, ``bench.py --scenario``)."""
+    (tests/conftest.py)."""
     import jax
 
     from goworld_tpu.ops import NeighborEngine, NeighborParams
@@ -180,13 +179,11 @@ def run_scenario(name: str, engine: Optional[str] = "batched",
                  seed: Optional[int] = -1,
                  ticks_scale: Optional[float] = 1.0,
                  slo: Any = None) -> Dict[str, Any]:
-    """Run a registered scenario end-to-end; returns the headline dict
-    (bench.py prints it as the one JSON line).
+    """Run a registered scenario end-to-end; returns the headline dict.
 
     Passing ``None`` for engine/seed/ticks_scale consults the
-    ``[scenario]`` ini section (ad-hoc/dev runs); the defaults (and
-    bench.py's gate mode, which relies on them) never touch the ini, so
-    committed floors cannot drift with an operator's config.  A negative
+    ``[scenario]`` ini section (ad-hoc/dev runs); the defaults never
+    touch the ini, so tests cannot drift with an operator's config.  A negative
     seed — the default — means the registry's fixed per-scenario seed.
 
     ``slo`` is an optional :class:`SLOConfig`: when it has budgets set,

@@ -32,8 +32,8 @@ per-class columnar tick programs compile INTO the AOI device launch, so
 ``run_tick_batches`` skips them and ``entity_logic`` collapses to the
 residual host work (timers, crontab, post queue, non-fusable hooks) while
 the logic cost moves inside the ``aoi`` phase's device step — the collapse
-is the observable signature that fusion is live (``bench.py --fused``
-reports it; aoi_fused_classes/aoi_fused_slots on /metrics name the cause).
+is the observable signature that fusion is live (aoi_fused_classes/
+aoi_fused_slots on /metrics name the cause).
 
 The AOI engine's host path is timed from inside by :func:`engine_span`,
 one primitive for two readers: the span's seconds accumulate on

@@ -242,10 +242,57 @@ def test_multigame_spaces_kill_crosses(tmp_path):
     assert sum(r["census_final"]) == 12
 
 
+@pytest.mark.parametrize("fail", [None, "uds"], ids=["clean", "uds_red"])
+def test_chaos_main_summarizes_and_gates(monkeypatch, capsys, fail):
+    """``python -m goworld_tpu.chaos``: one suite run and one multigame
+    restart phase per transport, the phase appended to that transport's
+    scenarios, the loaded config's ``[slo]`` passed through, one JSON
+    summary printed, and exit code 1 exactly when some scenario failed
+    (a multigame exception counts as a failed scenario)."""
+    import json
+
+    from goworld_tpu.chaos import __main__ as chaos_main
+
+    calls = []
+
+    def fake_run_chaos(run_dir, transport="tcp", slo=None):
+        calls.append(("suite", transport, slo))
+        return {"scenarios": [{"scenario": "dispatcher_restart"}],
+                "failures": [], "passed": 1, "transport": transport}
+
+    def fake_run_multigame(run_dir, n_bots, transport, with_restart_phase):
+        calls.append(("multigame", transport, with_restart_phase))
+        if transport == fail:
+            raise AssertionError("zero-loss clause broken")
+        return {"dispatcher_restart_phase": {
+            "scenario": "migrate_during_dispatcher_restart"}}
+
+    monkeypatch.setattr(chaos_main, "run_chaos", fake_run_chaos)
+    monkeypatch.setattr(chaos_main, "run_multigame", fake_run_multigame)
+    rc = chaos_main.main()
+    slo = chaos_main.read_config.get().slo
+    assert calls == [("suite", "tcp", slo), ("multigame", "tcp", True),
+                     ("suite", "uds", slo), ("multigame", "uds", True)]
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert set(out) == {"tcp", "uds"}
+    assert [s["scenario"] for s in out["tcp"]["scenarios"]] == [
+        "dispatcher_restart", "migrate_during_dispatcher_restart"]
+    assert out["tcp"]["passed"] == 2
+    if fail is None:
+        assert rc == 0
+        assert not out["uds"]["failures"]
+    else:
+        assert rc == 1
+        [failure] = out["uds"]["failures"]
+        assert failure["scenario"] == "migrate_during_dispatcher_restart"
+        assert "zero-loss" in failure["error"]
+
+
 @pytest.mark.slow
 def test_full_chaos_soak(tmp_path):
     """All scenarios back to back over ONE cluster — state carried across
-    faults (the bench --chaos shape, with more dispatchers)."""
+    faults (the ``python -m goworld_tpu.chaos`` shape, with more
+    dispatchers)."""
 
     async def run():
         cluster = ChaosCluster(str(tmp_path), n_dispatchers=3, n_bots=16,

