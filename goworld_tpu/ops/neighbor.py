@@ -76,6 +76,7 @@ sentinel.install_compile_cache_listener()
 LANES = 128  # Pallas cell capacity = one TPU lane dimension
 _PACK = 16  # event-mask bits packed per i32 word
 _F = 8  # feature count (sublane multiple of 8)
+_GROUP = LANES // _PACK  # words per halo cell: the drain's word groups
 
 # Feature rows in the dense cell layout. Epoch A = the epoch whose positions
 # the grid is binned by; epoch B = the other epoch. The kernel computes
@@ -100,18 +101,8 @@ class NeighborParams:
     space_slots: int = 8  # space-id folding slots for the shared grid
     cell_capacity: int = 64  # M: max entities visible per grid cell
     max_events: int = 65536  # enter/leave pairs fetched per host round trip
-    # Pallas-drain word-find (identical results, different gather shapes;
-    # the row-find before it is chosen by shape, _row_find_steps):
-    #   bsearch: binary-search word-find (log2(W) random scalar gathers/event)
-    #   grouped: two contiguous-row gathers ([E, G] group cumsums, then
-    #            [E, W/G] words) per event
-    drain_mode: str = "bsearch"
 
     def __post_init__(self) -> None:
-        if self.drain_mode not in ("bsearch", "grouped"):
-            raise ValueError(
-                f"drain_mode must be bsearch|grouped, got {self.drain_mode!r}"
-            )
         if self.grid_x < 4 or self.grid_z < 4:
             # 3x3 neighborhoods must touch 9 distinct buckets after wrap.
             raise ValueError("grid_x and grid_z must be >= 4")
@@ -788,14 +779,24 @@ def _row_find_steps(n_rows: int, max_events: int) -> bool:
     gathers.
 
     On a v5e a scatter of N updates costs about one gather pass over N
-    elements: an [E]-wide random gather runs ~7.1 ns an element whatever
-    it reads (0.4675 ms at E = 65,536), a 128,000-update scatter ~0.6 ms.
-    So the two counts compare directly. The single-chip step and its
-    pager (128,000 rows against 65,536 * 17) and the strip engine (own
-    rows only) take step; the entity-sharded engine at 409,600 entities
-    on 4 chips (512,000 rows against 16,384 * 19) keeps search.
+    elements: an [E]-wide scalar gather runs ~7.1 ns an index (0.47 ms at
+    E = 65,536), a 128,000-update scatter ~0.6 ms. So the two counts
+    compare directly. The single-chip step and its pager (128,000 rows
+    against 65,536 * 17) and the strip engine (own rows only) take step;
+    the entity-sharded engine at 409,600 entities on 4 chips (512,000
+    rows against 16,384 * 19) keeps search.
     """
     return n_rows <= max_events * n_rows.bit_length()
+
+
+# The drain's gather rule, by the same per-index cost: on a v5e, at
+# E = 65,536 indices into 128,000 rows, a scalar i32 gather costs
+# 0.47 ms, and a row gather of a contiguous i32 row 0.16 ms whether the
+# row is 13, 16 or 72 words wide. So each level after the row-find reads
+# its whole row in one gather and selects in registers. Narrower views
+# cost more: the words as [N * 9, 8] rows 0.23 ms plus a ~590 MB
+# lane-padded relayout, and a (1, 8) slice gather at (row, 8g) of the
+# [N, 72] words 86 ms.
 
 
 def _row_of_rank(row_counts, row_cum, row_starts, start_rank, max_events):
@@ -851,9 +852,12 @@ def _drain_bits(
     ``bincount(cumsum(mask))`` lowering scatter-adds over the full
     N * 9 * LANES flat space (118M elements at the headline config — a
     multi-second TPU scatter). Here the only full-size ops are popcounts
-    and per-axis cumsums; each requested event then finds its row
-    (``_row_of_rank``, under the nested scope ``row_find``), its word
-    (``drain_mode``) and its bit by a 16-wide prefix compare.
+    and per-axis sums; each requested event then finds its row
+    (``_row_of_rank``, nested scope ``row_find``), its halo cell from the
+    row's record of per-cell cumsums (scope ``record``), its word among
+    the cell's ``_GROUP`` and its bit by prefix compares over the row's
+    words (scope ``word``). Each level is one row gather (the gather rule
+    beside ``_row_find_steps``); the compares run in registers.
 
     Candidate c of entity i maps to halo cell c // LANES (row-major 3x3) and
     lane c % LANES. Returns (pairs i32[max_events, 2], row_counts' total) —
@@ -874,8 +878,12 @@ def _drain_bits(
         start_rank = start_flat
         n = p.capacity
         gxl = p.grid_x if gx_ext is None else gx_ext
+        n_rows, nw = packed_e.shape
+        ng = nw // _GROUP  # the 9 halo cells
         pc = jax.lax.population_count(packed_e)  # [N, W]
-        row_counts = jnp.sum(pc, axis=1)  # [N]
+        cell_cum = jnp.cumsum(  # [N, 9] inclusive set bits per halo cell
+            jnp.sum(pc.reshape(n_rows, ng, _GROUP), axis=2), axis=1)
+        row_counts = cell_cum[:, -1]  # [N]
         row_cum = jnp.cumsum(row_counts)  # inclusive
         row_starts = row_cum - row_counts  # exclusive
         total = row_cum[-1]
@@ -884,79 +892,47 @@ def _drain_bits(
         valid = j < total
         row = _row_of_rank(row_counts, row_cum, row_starts, start_rank,
                            max_events)
-        k = j - row_starts[row]  # event rank within its row
 
-        # Word selection by binary search over the row's inclusive word-count
-        # cumsum: computed ONCE as [N, W] and probed with ceil(log2(W+1)) flat
-        # [E] gathers. (The round-3 predecessor gathered each event's full
-        # 72-word row and re-cumsummed it — [E, W] traffic ~7x this, measured
-        # on-chip 2026-07-30.)
-        nw = pc.shape[1]
-        word_cum = jnp.cumsum(pc, axis=1)  # [N, W] inclusive
-        if p.drain_mode == "grouped":
-            # Two-level select via CONTIGUOUS row gathers: the bsearch mode's
-            # ~log2(W) random scalar gathers per event are latency-bound on
-            # TPU; here each event pulls its row's [G] group cumsums and the
-            # [gsz] words of the chosen group in two row gathers, then finds
-            # group/word with wide prefix compares (VPU-friendly).
-            # Invariant: word w holds rank k iff word_cum[w] > k and
-            # word_cum[w-1] <= k, so index = count of inclusive cumsums <= k.
-            gsz = 8
-            ng = (nw + gsz - 1) // gsz
-            pad = ng * gsz - nw
-            # edge-pad: padded words repeat the last cumsum (popcount 0).
-            wc_pad = jnp.pad(word_cum, ((0, 0), (0, pad)), mode="edge")
-            group_cum = wc_pad[:, gsz - 1 :: gsz]  # [N, G] inclusive per group
-            g_rows = group_cum[row]  # [E, G]
-            g = jnp.sum((g_rows <= k[:, None]).astype(jnp.int32), axis=1)
-            g = jnp.minimum(g, ng - 1)
-            # The chosen group's word cumsums per event: [E, gsz].
-            idx = (row * (ng * gsz) + g * gsz)[:, None] + jnp.arange(
-                gsz, dtype=jnp.int32
-            )[None, :]
-            wg = wc_pad.reshape(-1)[idx]
-            wi = jnp.sum((wg <= k[:, None]).astype(jnp.int32), axis=1)
-            w = jnp.minimum(g * gsz + wi, nw - 1)
-            ev = jnp.arange(max_events)
-            # Exclusive cumsum at w: last word of the previous group when the
-            # event is the group's first word, else the group-local neighbor.
-            prev_in_group = wg[ev, jnp.maximum(wi - 1, 0)]
-            prev_group_end = jnp.where(
-                g > 0, g_rows[ev, jnp.maximum(g - 1, 0)], 0)
-            word_start = jnp.where(wi > 0, prev_in_group, prev_group_end)
-            kk = k - word_start  # set-bit rank within the word
-        else:
-            wc_flat = word_cum.reshape(-1)
-            pc_flat = pc.reshape(-1)
-            base = row * nw
-            lo = jnp.zeros((max_events,), jnp.int32)
-            hi = jnp.full((max_events,), nw, jnp.int32)
-            for _ in range(max(1, nw.bit_length())):
-                mid = jnp.minimum((lo + hi) // 2, nw - 1)
-                gt = wc_flat[base + mid] > k
-                hi = jnp.where(gt, mid, hi)
-                lo = jnp.where(gt, lo, mid + 1)
-            w = jnp.minimum(lo, nw - 1)
-            word_start = wc_flat[base + w] - pc_flat[base + w]
-            kk = k - word_start  # set-bit rank within the word
+        # A rank's halo cell is the count of the row's inclusive per-cell
+        # cumsums at or below it, and the largest of those is the cell's
+        # start; the same holds for its word among the cell's. Ranks past
+        # the total clip to the last cell, word and bit (masked).
+        with jax.named_scope("record"):
+            record = jnp.concatenate(  # i32[N, 4 + 9]
+                [jnp.stack([row_starts, cz, cx, sm], axis=1), cell_cum],
+                axis=1)[row]
+            k = j - record[:, 0]  # event rank within its row
+            ccum = record[:, 4:]
+            below = ccum <= k[:, None]
+            g = jnp.minimum(jnp.sum(below.astype(jnp.int32), axis=1), ng - 1)
+            kg = k - jnp.max(jnp.where(below, ccum, 0), axis=1)
 
-        word = packed_e[row, w]
+        with jax.named_scope("word"):
+            cell_words = jnp.sum(jnp.where(
+                (jnp.arange(ng) == g[:, None])[:, :, None],
+                packed_e[row].reshape(max_events, ng, _GROUP), 0), axis=1)
+            wcum = jnp.cumsum(jax.lax.population_count(cell_words), axis=1)
+            below = wcum <= kg[:, None]
+            wi = jnp.minimum(jnp.sum(below.astype(jnp.int32), axis=1),
+                             _GROUP - 1)
+            kk = kg - jnp.max(jnp.where(below, wcum, 0), axis=1)
+            word = jnp.sum(jnp.where(
+                jnp.arange(_GROUP) == wi[:, None], cell_words, 0), axis=1)
+
         bits = (word[:, None] >> jnp.arange(_PACK, dtype=jnp.int32)) & 1
         bcum = jnp.cumsum(bits, axis=1)  # inclusive set-bit counts
         b = jnp.sum((bcum <= kk[:, None]).astype(jnp.int32), axis=1)
         b = jnp.minimum(b, _PACK - 1)
 
-        c = w * _PACK + b  # candidate index within the row's 3x3 halo
-        hc = c // LANES
-        lane = c % LANES
-        dzo = hc // 3 - 1
-        dxo = hc % 3 - 1
-        czz = jnp.mod(cz[row] + dzo, p.grid_z)
+        lane = wi * _PACK + b  # a group's words are its halo cell's lanes
+        dzo = g // 3 - 1
+        dxo = g % 3 - 1
+        czz = jnp.mod(record[:, 1] + dzo, p.grid_z)
         if wrap_x:
-            cxx = jnp.mod(cx[row] + dxo, gxl)
+            cxx = jnp.mod(record[:, 2] + dxo, gxl)
         else:
-            cxx = cx[row] + dxo  # strip slab: ghost columns are physical
-        bucket = (sm[row] * p.grid_z + czz) * gxl + cxx
+            cxx = record[:, 2] + dxo  # strip slab: ghost columns are physical
+        bucket = (record[:, 3] * p.grid_z + czz) * gxl + cxx
         other = table[bucket * LANES + lane]
         ent = jnp.where(valid, row, n)
         other = jnp.where(valid, other, n)

@@ -258,46 +258,140 @@ def test_pallas_single_space_slot():
         ).astype(np.float32)
 
 
-def test_drain_modes_match_bsearch():
-    """drain_mode=grouped must produce the identical event stream as the
-    default bsearch select, including under storm paging (tiny max_events
-    forces many chunks through each mode's row-find and group/word
-    compares), and both row-finds must give the same stream: at this
-    256-row world max_events 8 takes the search row-find, 64 and 8192 the
-    step row-find."""
-    base = dict(
-        capacity=256, cell_size=100.0, grid_x=16, grid_z=16,
-        space_slots=4, cell_capacity=64,
-    )
-    # 8 and 64 force storm paging; 8192 covers the non-paging shape (> any
-    # event count this world produces) without the compile cost of a
-    # production-sized budget.
-    budgets = (8, 64, 8192)
-    assert [_row_find_steps(256, e) for e in budgets] == [False, True, True]
-    streams = {}
-    for max_events in budgets:
-        rng = np.random.default_rng(11)
-        engines = {}
-        for mode in ("bsearch", "grouped"):
-            p = NeighborParams(max_events=max_events, drain_mode=mode, **base)
-            engines[mode] = NeighborEngine(p, backend="pallas_interpret")
-            engines[mode].reset()
-        pos, active, space, radius = make_world(256, 200, seed=7)
-        stream = streams[max_events] = []
-        for tick in range(4):
-            results = {
-                m: e.step(pos, active, space, radius)
-                for m, e in engines.items()
-            }
-            for which in (0, 1):
-                a = np.asarray(results["bsearch"][which])
-                b = np.asarray(results["grouped"][which])
-                assert np.array_equal(a, b), (tick, which, max_events)
-                stream.append(a)
-            pos = pos + rng.uniform(-30, 30, pos.shape).astype(np.float32)
-    for max_events in budgets[1:]:
-        for i, (a, b) in enumerate(zip(streams[8], streams[max_events])):
-            assert np.array_equal(a, b), (i, max_events)
+DRAIN_WORLD = dict(
+    capacity=256, cell_size=100.0, grid_x=16, grid_z=16, space_slots=4,
+    cell_capacity=64,
+)
+
+
+# 8 and 64 force storm paging; 8192 covers the non-paging shape (> any
+# event count this world produces) without the compile cost of a
+# production-sized budget. At 256 rows 8 takes the search row-find, 64
+# and 8192 the step row-find.
+@pytest.mark.parametrize("max_events, steps", [
+    (8, False), (64, True), (8192, True),
+], ids=["8", "64", "8192"])
+def test_drain_matches_jnp(max_events, steps):
+    """The Pallas engine's event stream, paged at ``max_events``, is the
+    jnp engine's unpaged stream pair for pair and in the same order (row,
+    then halo cell, then lane), so every budget gives the same stream."""
+    assert _row_find_steps(DRAIN_WORLD["capacity"], max_events) is steps
+    eng = NeighborEngine(NeighborParams(max_events=max_events, **DRAIN_WORLD),
+                         backend="pallas_interpret")
+    ref = NeighborEngine(NeighborParams(max_events=8192, **DRAIN_WORLD),
+                         backend="jnp")
+    eng.reset()
+    ref.reset()
+    rng = np.random.default_rng(11)
+    pos, active, space, radius = make_world(256, 200, seed=7)
+    for tick in range(4):
+        got = eng.step(pos, active, space, radius)
+        want = ref.step(pos, active, space, radius)
+        for which in (0, 1):
+            np.testing.assert_array_equal(
+                np.asarray(got[which]), np.asarray(want[which]),
+                err_msg=f"tick {tick} {'enters leaves'.split()[which]}")
+        pos = pos + rng.uniform(-30, 30, pos.shape).astype(np.float32)
+
+
+def test_drain_crowded_row():
+    """One entity whose events fill several word groups (a group is one
+    halo cell's 128 lanes) and several words of one group: 24 neighbours
+    share its cell (lanes 0-24, three words) and others sit in three more
+    cells of its 3x3 block. Paged 16 events at a time, so its row
+    straddles pages; each tick's events are the brute-force diff."""
+    from goworld_tpu.ops.neighbor import bins_reference
+
+    n = 64
+    p = NeighborParams(capacity=n, cell_size=100.0, grid_x=8, grid_z=8,
+                       space_slots=1, cell_capacity=64, max_events=16)
+    rng = np.random.default_rng(21)
+    pos = rng.uniform(0, 800, (n, 2)).astype(np.float32)
+    pos[0] = (195.0, 195.0)  # near the corner of cells (1, 1) and (2, 2)
+    pos[1:25] = rng.uniform(110, 190, (24, 2))  # its own cell, in range
+    pos[25:31] = (205.0, 150.0) + rng.uniform(0, 20, (6, 2))
+    pos[31:37] = (150.0, 205.0) + rng.uniform(0, 20, (6, 2))
+    pos[37:40] = (205.0, 205.0) + rng.uniform(0, 20, (3, 2))
+    active = np.ones(n, bool)
+    space = np.zeros(n, np.int32)
+    radius = np.full(n, 99.0, np.float32)
+    cx, cz, _ = bins_reference(p, pos, space)
+    nbrs = brute_force_sets(pos, active, space, radius)[0]
+    cells = {(int(cx[j]), int(cz[j])) for j in nbrs}
+    assert len(nbrs) >= 36 and len(cells) >= 4
+    assert sum(1 for j in nbrs if (cx[j], cz[j]) == (cx[0], cz[0])) > 16
+
+    eng = NeighborEngine(p, backend="pallas_interpret")
+    eng.reset()
+    before = [set() for _ in range(n)]
+    for tick in range(3):
+        enters, leaves, dropped = eng.step(pos, active, space, radius)
+        assert dropped == 0
+        now = brute_force_sets(pos, active, space, radius)
+        assert pairs_to_setlist(enters, n) == [
+            a - b for a, b in zip(now, before)], tick
+        assert pairs_to_setlist(leaves, n) == [
+            b - a for a, b in zip(now, before)], tick
+        assert len(enters) == sum(len(x) for x in pairs_to_setlist(enters, n))
+        before = now
+        pos = (pos + rng.uniform(-40, 40, pos.shape)).astype(np.float32)
+        pos[0] = (195.0, 195.0)
+
+
+def _decode_strip(p, packed, cx, cz, sm, table, gx_ext):
+    """numpy: every set bit of the packed mask in rank order, as (row,
+    other) through the strip slab's cell arithmetic (x not wrapped)."""
+    out = []
+    for r in range(packed.shape[0]):
+        for c in range(packed.shape[1] * 16):
+            if (int(packed[r, c // 16]) >> (c % 16)) & 1:
+                hc, lane = divmod(c, LANES)
+                czz = (cz[r] + hc // 3 - 1) % p.grid_z
+                cxx = cx[r] + hc % 3 - 1
+                bucket = (sm[r] * p.grid_z + czz) * gx_ext + cxx
+                out.append((r, table[bucket * LANES + lane]))
+    return np.asarray(out, np.int32).reshape(-1, 2)
+
+
+def test_drain_strip_slab():
+    """``_drain_bits`` on a strip slab (``gx_ext``, ``wrap_x=False``, own
+    rows fewer than ``capacity``), paged, against a numpy decode of the
+    same packed mask; ranks past the total read the sentinel."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from goworld_tpu.ops.neighbor import _drain_bits
+
+    p = NeighborParams(capacity=256, cell_size=100.0, grid_x=16, grid_z=6,
+                       space_slots=2, cell_capacity=64, max_events=64)
+    rows, gx_ext, page = 40, 9, 64
+    rng = np.random.default_rng(5)
+    bits = rng.random((rows, 72 * 16)) < 0.01
+    bits[3, ::5] = True  # a crowded row: every group, every word
+    bits[7] = False
+    packed = np.zeros((rows, 72), np.int64)
+    for b in range(16):
+        packed |= bits[:, b::16].astype(np.int64) << b
+    packed = packed.astype(np.uint32).view(np.int32)
+    cx = rng.integers(1, gx_ext - 1, rows).astype(np.int32)
+    cz = rng.integers(0, p.grid_z, rows).astype(np.int32)
+    sm = rng.integers(0, p.space_slots, rows).astype(np.int32)
+    table = rng.integers(0, p.capacity, p.space_slots * p.grid_z * gx_ext
+                         * LANES).astype(np.int32)
+    want = _decode_strip(p, packed, cx, cz, sm, table, gx_ext)
+    drain = jax.jit(functools.partial(
+        _drain_bits, p, max_events=page, gx_ext=gx_ext, wrap_x=False))
+    args = [jnp.asarray(a) for a in (packed, cx, cz, sm, table)]
+    chunks = []
+    for start in range(0, len(want) + page, page):
+        pairs, total = drain(*args, jnp.int32(start))
+        assert int(total) == len(want)
+        chunks.append(np.asarray(pairs))
+    got = np.concatenate(chunks)
+    np.testing.assert_array_equal(got[:len(want)], want)
+    assert (got[len(want):] == p.capacity).all()
 
 
 def _row_of_rank_ref(counts, start, max_events):
@@ -355,13 +449,6 @@ def test_row_find_shape_rule(n_rows, max_events, steps):
     """Step when its n_rows scatter updates are no more than search's
     max_events * ceil(log2(n_rows + 1)) gathers."""
     assert _row_find_steps(n_rows, max_events) is steps
-
-
-@pytest.mark.parametrize("mode", ["scatter", "nonzero"])
-def test_drain_mode_rejects_unknown(mode):
-    """drain_mode chooses the word-find only; the row-find has no mode."""
-    with pytest.raises(ValueError, match="bsearch|grouped"):
-        NeighborParams(drain_mode=mode)
 
 
 @pytest.mark.slow

@@ -397,7 +397,6 @@ def test_pinned_floor_gate():
     params = NeighborParams(
         capacity=n, cell_size=cell, grid_x=grid, grid_z=grid,
         space_slots=1, cell_capacity=64, max_events=32768,
-        drain_mode="bsearch",
     )
     world = grid * cell
     retraces0 = sentinel.steady_state_retraces()

@@ -6,9 +6,10 @@ refuse — interpret-mode tests cannot see that. Nothing runs: these say
 nothing about results or times. The topology is described in a module
 fixture, never at import, so only the worker given this file loads the
 TPU library. Each test compiles one kernel (about a second), asserts the
-kernel is in the executable and prints its ``memory_analysis()``. One
-compiles the whole single-chip step at a tiny size and reads the stage
-names the profiler's trace will carry.
+kernel is in the executable and prints its ``memory_analysis()``. Two
+compile the whole single-chip step, at a tiny size and at the
+open_world_100k cell's, and read the stage names the profiler's trace
+will carry.
 """
 
 import os
@@ -126,3 +127,27 @@ def test_step_stage_names(one_chip):
     assert re.search(r'op_name="[^"]*/aoi\.drain/row_find/scatter', text)
     assert re.search(r"%aoi_event_kernel[.\d]* = .*"
                      r'custom_call_target="tpu_custom_call"', text)
+
+
+def test_step_drain_levels(one_chip):
+    """The single-chip step at the open_world_100k cell's shape (128,000
+    slots, grid 86, 65,536 events a side) compiles for the chip, and each
+    level of its drain after the row-find is a scope of its own."""
+    p = NeighborParams(capacity=128000, cell_size=300.0, grid_x=86,
+                       grid_z=86, space_slots=1, cell_capacity=64,
+                       max_events=65536)
+    n, buckets = p.capacity, p.num_buckets * LANES
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    epoch = (arg((n, 2), jnp.float32), arg((n,), jnp.bool_),
+             arg((n,), jnp.int32), arg((n,), jnp.float32))
+    grid = ((arg((n,), jnp.int32),) * 3 + (arg((buckets,), jnp.int32),)
+            + (arg((n,), jnp.int32),) * 3)
+    step = neighbor._jitted_step_packed(p, "pallas")._jitted
+    compiled = step.lower(*epoch, *grid, *epoch).compile()
+    print(compiled.memory_analysis())
+    text = compiled.as_text()
+    for level in ("row_find", "record", "word"):
+        assert re.search(rf'op_name="[^"]*/aoi\.drain/{level}/', text), level
