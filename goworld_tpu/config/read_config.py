@@ -766,8 +766,13 @@ def _validate(cfg: GoWorldConfig) -> None:
     a = cfg.aoi
     if a.max_entities < 8:
         raise ValueError("[aoi] max_entities must be >= 8")
-    if not (1 <= a.cell_capacity <= 128):
-        raise ValueError("[aoi] cell_capacity must be in [1, 128]")
+    # One 128-lane tile a grid cell, or a whole number of tiles up to
+    # four (ops/neighbor.py pallas_cell_capacity_ok).
+    if not (1 <= a.cell_capacity <= 128
+            or (a.cell_capacity % 128 == 0 and a.cell_capacity <= 512)):
+        raise ValueError(
+            "[aoi] cell_capacity must be in [1, 128] or a multiple of 128 "
+            "up to 512")
     if a.mesh_shards < 1:
         raise ValueError("[aoi] mesh_shards must be >= 1")
     if a.shard_mode not in ("spatial", "entity"):

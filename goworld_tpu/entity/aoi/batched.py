@@ -204,7 +204,7 @@ def params_from_config(aoi_cfg, params: NeighborParams | None = None) -> Neighbo
         grid_x=grid,
         grid_z=grid,
         space_slots=space_slots,
-        cell_capacity=min(int(getattr(aoi_cfg, "cell_capacity", 64)), 128),
+        cell_capacity=int(getattr(aoi_cfg, "cell_capacity", 64)),
         max_events=max_events,
     )
 

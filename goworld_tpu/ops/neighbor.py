@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from goworld_tpu.telemetry import sentinel
+from goworld_tpu.telemetry.metrics import REGISTRY
 from goworld_tpu.telemetry.phases import engine_span
 
 # Launch/trace accounting for every step jit built below; this module
@@ -73,10 +74,26 @@ from goworld_tpu.telemetry.phases import engine_span
 # compile-cache listener installs here too.
 sentinel.install_compile_cache_listener()
 
-LANES = 128  # Pallas cell capacity = one TPU lane dimension
+# The single-chip engine's grid and paging, read off each collected tick
+# (module-scope registration, gwlint R5).
+_M_OCCUPANCY = REGISTRY.gauge(
+    "aoi_cell_occupancy_max",
+    "Population of the fullest AOI grid cell on the last collected tick, "
+    "capacity-dropped entities included: the margin to cell_capacity.",
+)
+_M_PAGED = REGISTRY.counter(
+    "aoi_paged_events_total",
+    "Enter/leave events fetched by pager round trips beyond the inline "
+    "max_events window.",
+    ("side",),
+)
+_M_PAGED_SIDE = {side: _M_PAGED.labels(side) for side in ("enter", "leave")}
+
+LANES = 128  # one TPU lane dimension: a lane tile of a grid cell
+MAX_LANE_TILES = 4  # deepest Pallas cell: 4 lane tiles, cell_capacity 512
 _PACK = 16  # event-mask bits packed per i32 word
 _F = 8  # feature count (sublane multiple of 8)
-_GROUP = LANES // _PACK  # words per halo cell: the drain's word groups
+_GROUP = LANES // _PACK  # words per candidate lane tile: the drain's groups
 
 # Feature rows in the dense cell layout. Epoch A = the epoch whose positions
 # the grid is binned by; epoch B = the other epoch. The kernel computes
@@ -108,18 +125,37 @@ class NeighborParams:
             raise ValueError("grid_x and grid_z must be >= 4")
         if self.capacity % 8 != 0:
             raise ValueError("capacity must be a multiple of 8 (TPU sublanes)")
-        # The Pallas drain's flat event-index space is capacity*9*LANES held
-        # in int32 (ADVICE r2: overflow above ~1.86M slots must fail loudly).
-        if self.capacity * 9 * LANES >= 2**31:
+        # The Pallas drain's flat event-index space is capacity*9*cell_lanes
+        # held in int32 (ADVICE r2: overflow above ~1.86M slots at one lane
+        # tile a cell must fail loudly).
+        if self.capacity * 9 * self.cell_lanes >= 2**31:
             raise ValueError(
                 f"capacity {self.capacity} overflows the int32 event index "
-                f"space (capacity * 9 * {LANES} must be < 2^31); shard the "
-                f"engine instead (parallel.mesh)"
+                f"space (capacity * 9 * {self.cell_lanes} must be < 2^31); "
+                f"shard the engine instead (parallel.mesh)"
             )
 
     @property
     def num_buckets(self) -> int:
         return self.space_slots * self.grid_z * self.grid_x
+
+    @property
+    def lane_tiles(self) -> int:
+        """T: the 128-lane tiles one grid cell spans on the Pallas path."""
+        return -(-self.cell_capacity // LANES)
+
+    @property
+    def cell_lanes(self) -> int:
+        """A Pallas cell's lanes: the table's stride per bucket."""
+        return self.lane_tiles * LANES
+
+
+def pallas_cell_capacity_ok(cell_capacity: int) -> bool:
+    """The Pallas engine's cell depths: one lane tile holding 1-128
+    entities, or a whole number of tiles up to ``MAX_LANE_TILES``."""
+    return 1 <= cell_capacity <= LANES or (
+        cell_capacity % LANES == 0
+        and cell_capacity <= MAX_LANE_TILES * LANES)
 
 
 # --- shared binning ----------------------------------------------------------
@@ -230,22 +266,25 @@ def _build_table(
     invisible this tick, with the drop folded into the validity predicate so
     the event stream stays consistent. Returns
     (table i32[num_buckets*stride] with sentinel N, slot i32[N] with -1 for
-    dropped/inactive, dropped_count, order, dst) — order/dst let callers
-    scatter per-entity features into the same layout.
+    dropped/inactive, dropped_count, order, dst, occupancy) — order/dst let
+    callers scatter per-entity features into the same layout; occupancy is
+    the fullest cell's population, dropped entities included.
     """
     n = p.capacity
     cap = min(p.cell_capacity, stride)
     key = jnp.where(active, bucket, p.num_buckets)
     order, sorted_key, rank = sorted_ranks(key, n, p.num_buckets)
-    ok = (sorted_key < p.num_buckets) & (rank < cap)
-    dropped = jnp.sum((sorted_key < p.num_buckets) & ~ok).astype(jnp.int32)
+    binned = sorted_key < p.num_buckets
+    ok = binned & (rank < cap)
+    dropped = jnp.sum(binned & ~ok).astype(jnp.int32)
+    occupancy = jnp.max(jnp.where(binned, rank + 1, 0)).astype(jnp.int32)
     table_size = p.num_buckets * stride
     dst = jnp.where(ok, sorted_key * stride + rank, table_size)
     table = jnp.full((table_size,), n, dtype=jnp.int32)
     table = table.at[dst].set(order.astype(jnp.int32), mode="drop")
     slot_sorted = jnp.where(ok, dst, -1).astype(jnp.int32)
     slot = jnp.zeros((n,), jnp.int32).at[order].set(slot_sorted)
-    return table, slot, dropped, order, dst
+    return table, slot, dropped, order, dst, occupancy
 
 
 def _fast_guard(p: NeighborParams, ppos, pact, pspc, prad, pos, act, spc,
@@ -330,7 +369,8 @@ def _step_jnp(
     pos, act, spc, rad,  # current-tick inputs
 ):
     """Two-grid pairwise diff, jnp path. Returns
-    (enter_ids [N, 9M], leave_ids [N, 9M], n_enters, n_leaves, dropped)."""
+    (enter_ids [N, 9M], leave_ids [N, 9M], n_enters, n_leaves, dropped,
+    occupancy)."""
     n = p.capacity
     m = p.cell_capacity
     q_ids = jnp.arange(n, dtype=jnp.int32)
@@ -339,8 +379,8 @@ def _step_jnp(
     cxp, czp, smp = _bins(p, ppos, pspc)
     buc_c = (smc * p.grid_z + czc) * p.grid_x + cxc
     buc_p = (smp * p.grid_z + czp) * p.grid_x + cxp
-    table_c, slot_c, dropped_c, _, _ = _build_table(p, buc_c, act, m)
-    table_p, slot_p, _, _, _ = _build_table(p, buc_p, pact, m)
+    table_c, slot_c, dropped_c, _, _, occ_c = _build_table(p, buc_c, act, m)
+    table_p, slot_p, _, _, _, _ = _build_table(p, buc_p, pact, m)
     av_c = slot_c >= 0
     av_p = slot_p >= 0
 
@@ -372,7 +412,7 @@ def _step_jnp(
     leave_ids = jnp.where(leave_mask, cand_l, n)
     n_enters = jnp.sum(enter_mask).astype(jnp.int32)
     n_leaves = jnp.sum(leave_mask).astype(jnp.int32)
-    return enter_ids, leave_ids, n_enters, n_leaves, dropped_c
+    return enter_ids, leave_ids, n_enters, n_leaves, dropped_c, occ_c
 
 
 def _drain_ids(ids: jax.Array, n: int, max_events: int, start_flat: jax.Array):
@@ -407,12 +447,13 @@ def _drain_ids(ids: jax.Array, n: int, max_events: int, start_flat: jax.Array):
 
 
 def _pack_out(p: NeighborParams, enter_pairs, enter_idx, leave_pairs, leave_idx,
-              n_enters, n_leaves, dropped):
+              n_enters, n_leaves, dropped, occupancy):
     """Assemble the single packed host readback (ONE fetch per tick).
 
     out i32[3 + 2*max_events, 2]:
         out[0] = (n_enters, n_leaves)          total event counts
-        out[1] = (dropped, 0)                  grid-capacity drop diagnostic
+        out[1] = (dropped, occupancy)          grid-capacity diagnostics: the
+                                               drops, the fullest cell
         out[2] = (enter_last_flat, leave_last_flat)  resume cursors
         out[3          : 3+E]  = first E enter pairs (slot, other)
         out[3+E : 3+2E]        = first E leave pairs
@@ -421,7 +462,7 @@ def _pack_out(p: NeighborParams, enter_pairs, enter_idx, leave_pairs, leave_idx,
     header = jnp.stack(
         [
             jnp.stack([n_enters, n_leaves]),
-            jnp.stack([dropped, jnp.int32(0)]),
+            jnp.stack([dropped, occupancy]),
             jnp.stack([enter_idx[e - 1], leave_idx[e - 1]]),
         ]
     ).astype(jnp.int32)
@@ -429,13 +470,13 @@ def _pack_out(p: NeighborParams, enter_pairs, enter_idx, leave_pairs, leave_idx,
 
 
 def _step_packed_jnp(p: NeighborParams, ppos, pact, pspc, prad, pos, act, spc, rad):
-    enter_ids, leave_ids, n_e, n_l, dropped = _step_jnp(
+    enter_ids, leave_ids, n_e, n_l, dropped, occupancy = _step_jnp(
         p, ppos, pact, pspc, prad, pos, act, spc, rad
     )
     n = p.capacity
     ep, ei = _drain_ids(enter_ids, n, p.max_events, jnp.int32(0))
     lp, li = _drain_ids(leave_ids, n, p.max_events, jnp.int32(0))
-    out = _pack_out(p, ep, ei, lp, li, n_e, n_l, dropped)
+    out = _pack_out(p, ep, ei, lp, li, n_e, n_l, dropped, occupancy)
     return enter_ids, leave_ids, out
 
 
@@ -455,7 +496,7 @@ def _scatter_feats(p: NeighborParams, dst, order, feats_a, feats_b,
 
     feats_a = (x, z, space, radius) of the epoch the grid is binned by;
     feats_b = the same four for the other epoch. Returns
-    f32[space_slots, gz+2, gx+2, F, LANES] with a torus halo ring.
+    f32[space_slots, gz+2, gx+2, F, cell_lanes] with a torus halo ring.
 
     ``gx_ext`` generalizes the x extent to a STRIP-LOCAL slab
     (parallel/spatial.py's Pallas tier): the extent already INCLUDES its
@@ -464,7 +505,8 @@ def _scatter_feats(p: NeighborParams, dst, order, feats_a, feats_b,
     the full-torus layout (both dims wrap-padded).
     """
     gxe = p.grid_x if gx_ext is None else gx_ext
-    table_size = p.space_slots * p.grid_z * gxe * LANES
+    lanes = p.cell_lanes
+    table_size = p.space_slots * p.grid_z * gxe * lanes
     vals = jnp.stack(
         [f.astype(jnp.float32) for f in feats_a]
         + [f.astype(jnp.float32) for f in feats_b],
@@ -472,18 +514,113 @@ def _scatter_feats(p: NeighborParams, dst, order, feats_a, feats_b,
     )  # [N, F]
     flat = jnp.full((table_size, _F), jnp.nan, jnp.float32)
     flat = flat.at[dst].set(vals[order], mode="drop")
-    cells = flat.reshape(p.space_slots, p.grid_z, gxe, LANES, _F)
-    cells = cells.transpose(0, 1, 2, 4, 3)  # [S, gz, gxe, F, LANES]
+    cells = flat.reshape(p.space_slots, p.grid_z, gxe, lanes, _F)
+    cells = cells.transpose(0, 1, 2, 4, 3)  # [S, gz, gxe, F, lanes]
     # Halo ring per space slab: torus wrap on z always; on x only for the
     # full-torus layout (a strip slab's x halo holds real ghost rows).
     pad_x = (1, 1) if gx_ext is None else (0, 0)
     return jnp.pad(cells, ((0, 0), (1, 1), pad_x, (0, 0), (0, 0)), mode="wrap")
 
 
+def _pack_matrices():
+    """The pack's two half-word weight matrices, f32[9 * LANES, 72] each.
+
+    Bit-pack 16 candidate bits per i32 word via TWO half-word MXU matmuls.
+    Round 2's single matmul (weights up to 2^15) lost the LSB of sums near
+    2^16 on hardware (f32 MXU emulation); round 3's integer shift-add
+    rewrite was exact but needs a [LANES, W, 16] reshape Mosaic's
+    infer-vector-layout rejects ("unsupported shape cast", seen on-chip
+    2026-07-30). Splitting the word into 8-bit halves keeps the
+    Mosaic-supported matmul shape AND exactness: each half's weights are
+    2^0..2^7 (exact in bf16) and its per-word sum is <= 255, exactly
+    representable under any MXU accumulation scheme; lo + 256*hi <= 65535
+    is exact in f32 on the VPU.
+    """
+    w_words = 9 * LANES // _PACK
+    c_iota = jax.lax.broadcasted_iota(jnp.int32, (9 * LANES, w_words), 0)
+    w_iota = jax.lax.broadcasted_iota(jnp.int32, (9 * LANES, w_words), 1)
+    bit = c_iota - w_iota * _PACK  # bit index within the word, or out of range
+    half = _PACK // 2
+    pmat_lo = jnp.where(
+        (bit >= 0) & (bit < half), jnp.exp2(bit.astype(jnp.float32)), 0.0
+    )
+    pmat_hi = jnp.where(
+        (bit >= half) & (bit < _PACK),
+        jnp.exp2((bit - half).astype(jnp.float32)),
+        0.0,
+    )
+    return pmat_lo, pmat_hi
+
+
+def _cell_event_words(c, tiles: int, dual: bool):
+    """One grid cell's packed event words, inside the event kernel.
+
+    ``c`` is the cell's 3x3 block, f32[3, 3, F, tiles * LANES]. Each of
+    the center cell's ``tiles`` query tiles (LANES lanes) is tested
+    against the block's 9 * tiles candidate tiles one chunk of nine tiles
+    at a time, so each chunk has the one-tile cell's shapes: the pair
+    math, the pack matrices and their VMEM do not grow with the depth.
+    Candidate c of a query row is lane c % (tiles * LANES) of halo cell
+    c // (tiles * LANES) (row-major 3x3), and word w packs candidates
+    [16w, 16w + 16).
+
+    Returns (words, v_a, v_b, lane): words i32[tiles * LANES, W] holds
+    W = 9 * tiles * LANES / 16 enter words a row, then as many leave
+    words when ``dual``; v_a, v_b and the query-lane iota are the last
+    chunk's, which the one-tile in-kernel drain reads. The trace-time
+    unroll over the tiles² chunk pairs (at most 16) lives here, outside
+    the kernel body's loop-free contract.
+    """
+    cand = c.transpose(2, 0, 1, 3).reshape(_F, 9 * tiles * LANES)
+    qs = [c[1, 1, :, t * LANES:(t + 1) * LANES] for t in range(tiles)]
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, 9 * LANES), 0)
+    cidx = jax.lax.broadcasted_iota(jnp.int32, (LANES, 9 * LANES), 1)
+
+    def valid(q, cnd, not_self, fx, fz, fs, fr):
+        # Empty slots have NaN x (see _F comment): d2 goes NaN for any pair
+        # touching one, and `NaN <= r2` is false — no occupancy rows needed.
+        dx = cnd[fx][None, :] - q[fx][:, None]
+        dz = cnd[fz][None, :] - q[fz][:, None]
+        d2 = dx * dx + dz * dz
+        r2 = (q[fr] * q[fr])[:, None]
+        return (
+            (q[fs][:, None] == cnd[fs][None, :]) & (d2 <= r2) & not_self
+        )
+
+    pmat = None  # built after the first chunk's pair math
+
+    def pack(mask):
+        pmat_lo, pmat_hi = pmat
+        mf = mask.astype(jnp.float32)
+        lo = jnp.dot(mf, pmat_lo, preferred_element_type=jnp.float32)
+        hi = jnp.dot(mf, pmat_hi, preferred_element_type=jnp.float32)
+        return (lo + 256.0 * hi).astype(jnp.int32)  # [LANES, 72]
+
+    rows = []
+    for tq, q in enumerate(qs):
+        enters, leaves = [], []
+        for k in range(tiles):
+            cnd = cand[:, k * 9 * LANES:(k + 1) * 9 * LANES]
+            # Self-pairs: query tile tq is candidate tile 4 * tiles + tq
+            # (the center cell's), at a chunk-local offset.
+            not_self = cidx != (4 * tiles + tq - 9 * k) * LANES + lane
+            v_a = valid(q, cnd, not_self, _FX_A, _FZ_A, _FS_A, _FR_A)
+            v_b = valid(q, cnd, not_self, _FX_B, _FZ_B, _FS_B, _FR_B)
+            if pmat is None:
+                pmat = _pack_matrices()
+            enters.append(pack(v_a & ~v_b))
+            if dual:
+                leaves.append(pack(v_b & ~v_a))
+        rows.append(jnp.concatenate(enters + leaves, axis=1))
+    return jnp.concatenate(rows, axis=0), v_a, v_b, lane
+
+
 def _event_kernel(p: NeighborParams, dual: bool, drain_inline: int,
                   cells_hbm, *refs):
     """One program per grid cell: DMA the 3x3 halo block, evaluate
-    valid_A ∧ ¬valid_B for all 128 × 1152 pairs, bit-pack the mask.
+    valid_A ∧ ¬valid_B for all T·128 × 9·T·128 pairs of a cell T lane
+    tiles deep (``_cell_event_words``), bit-pack the mask.
 
     ``dual`` additionally emits valid_B ∧ ¬valid_A (the leave mask) into the
     second half of the output words — the single-launch fast path when every
@@ -566,64 +703,9 @@ def _event_kernel(p: NeighborParams, dual: bool, drain_inline: int,
             pairs_ref[...] = jnp.full(pairs_ref.shape, p.capacity, jnp.int32)
 
     halo_copy(lin, slot).wait()
-    c = scratch[slot]  # [3, 3, F, LANES]
-    cand = c.transpose(2, 0, 1, 3).reshape(_F, 9 * LANES)
-    q = c[1, 1]  # [F, LANES]
-
-    # Self-pairs: the center cell is candidate block 4 (row-major 3x3).
-    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, 9 * LANES), 0)
-    cidx = jax.lax.broadcasted_iota(jnp.int32, (LANES, 9 * LANES), 1)
-    not_self = cidx != 4 * LANES + lane
-
-    def valid(fx, fz, fs, fr):
-        # Empty slots have NaN x (see _F comment): d2 goes NaN for any pair
-        # touching one, and `NaN <= r2` is false — no occupancy rows needed.
-        dx = cand[fx][None, :] - q[fx][:, None]
-        dz = cand[fz][None, :] - q[fz][:, None]
-        d2 = dx * dx + dz * dz
-        r2 = (q[fr] * q[fr])[:, None]
-        return (
-            (q[fs][:, None] == cand[fs][None, :]) & (d2 <= r2) & not_self
-        )
-
-    v_a = valid(_FX_A, _FZ_A, _FS_A, _FR_A)
-    v_b = valid(_FX_B, _FZ_B, _FS_B, _FR_B)
-
-    # Bit-pack 16 candidate bits per i32 word via TWO half-word MXU matmuls.
-    # Round 2's single matmul (weights up to 2^15) lost the LSB of sums near
-    # 2^16 on hardware (f32 MXU emulation); round 3's integer shift-add
-    # rewrite was exact but needs a [LANES, W, 16] reshape Mosaic's
-    # infer-vector-layout rejects ("unsupported shape cast", seen on-chip
-    # 2026-07-30). Splitting the word into 8-bit halves keeps the
-    # Mosaic-supported matmul shape AND exactness: each half's weights are
-    # 2^0..2^7 (exact in bf16) and its per-word sum is <= 255, exactly
-    # representable under any MXU accumulation scheme; lo + 256*hi <= 65535
-    # is exact in f32 on the VPU.
-    w_words = 9 * LANES // _PACK
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (9 * LANES, w_words), 0)
-    w_iota = jax.lax.broadcasted_iota(jnp.int32, (9 * LANES, w_words), 1)
-    bit = c_iota - w_iota * _PACK  # bit index within the word, or out of range
-    half = _PACK // 2
-    pmat_lo = jnp.where(
-        (bit >= 0) & (bit < half), jnp.exp2(bit.astype(jnp.float32)), 0.0
-    )
-    pmat_hi = jnp.where(
-        (bit >= half) & (bit < _PACK),
-        jnp.exp2((bit - half).astype(jnp.float32)),
-        0.0,
-    )
-
-    def pack(mask):
-        mf = mask.astype(jnp.float32)
-        lo = jnp.dot(mf, pmat_lo, preferred_element_type=jnp.float32)
-        hi = jnp.dot(mf, pmat_hi, preferred_element_type=jnp.float32)
-        return (lo + 256.0 * hi).astype(jnp.int32)  # [LANES, W]
-
-    enter = pack(v_a & ~v_b)
-    if dual:
-        out_ref[0, 0, 0] = jnp.concatenate([enter, pack(v_b & ~v_a)], axis=1)
-    else:
-        out_ref[0, 0, 0] = enter
+    words, v_a, v_b, lane = _cell_event_words(scratch[slot], p.lane_tiles,
+                                              dual)
+    out_ref[0, 0, 0] = words
 
     if drain_inline:
         so_copy.wait()
@@ -704,12 +786,15 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
     local column slab per device (parallel/spatial.py); the kernel body is
     row/column symmetric, so both ride the same program. ``dual`` emits
     enter+leave masks in one launch (words [0, W) enter, [W, 2W) leave).
+    A cell is ``p.lane_tiles`` lane tiles deep (T): the words block of a
+    cell is [T * LANES query lanes, W = 9 * T * LANES / 16] and the halo
+    scratch [2, 3, 3, F, T * LANES]; T = 1 is the one-tile program.
     ``drain_inline`` adds the in-kernel event drain (see _event_kernel): a
     second input (the i32 slot/own plane, cells geometry with 2 planes in
     place of the F features) and a second output, the compacted pairs
     tiles i32[pair_tiles(cap), 8, LANES] with cap = drain_inline * (2 if
     dual else 1); its constant index map keeps the block VMEM-resident
-    across the whole sequential grid."""
+    across the whole sequential grid; it takes one-tile cells only."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -717,15 +802,18 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
         rows = p.grid_z
     if cols is None:
         cols = p.grid_x
-    w_words = (9 * LANES // _PACK) * (2 if dual else 1)
+    lanes = p.cell_lanes
+    if drain_inline and lanes != LANES:
+        raise ValueError("the in-kernel drain takes one-tile cells only")
+    w_words = (9 * lanes // _PACK) * (2 if dual else 1)
     kernel = functools.partial(_event_kernel, p, dual, drain_inline)
     words_spec = pl.BlockSpec(
-        (1, 1, 1, LANES, w_words),
+        (1, 1, 1, lanes, w_words),
         lambda s, i, j: (s, i, j, 0, 0),
         memory_space=pltpu.VMEM,
     )
     words_shape = jax.ShapeDtypeStruct(
-        (p.space_slots, rows, cols, LANES, w_words), jnp.int32
+        (p.space_slots, rows, cols, lanes, w_words), jnp.int32
     )
     if not drain_inline:
         return pl.pallas_call(
@@ -735,7 +823,7 @@ def _compiled_event_kernel(p: NeighborParams, interpret: bool,
             out_specs=words_spec,
             out_shape=words_shape,
             scratch_shapes=[
-                pltpu.VMEM((2, 3, 3, _F, LANES), jnp.float32),
+                pltpu.VMEM((2, 3, 3, _F, lanes), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
             interpret=interpret,
@@ -839,7 +927,7 @@ def _drain_bits(
     p: NeighborParams,
     packed_e: jax.Array,  # i32[N, W] per-entity packed event mask
     cx, cz, sm,  # i32[N] bin coords of the pass's grid
-    table: jax.Array,  # i32[num_buckets * LANES] id table of the pass's grid
+    table: jax.Array,  # i32[num_buckets * cell_lanes] id table of the grid
     start_flat: jax.Array,  # EVENT RANK to resume from (name kept for the
     max_events: int | None = None,  # shared pager call signature)
     gx_ext: int | None = None,  # strip-local x extent (parallel/spatial.py)
@@ -853,15 +941,18 @@ def _drain_bits(
     N * 9 * LANES flat space (118M elements at the headline config — a
     multi-second TPU scatter). Here the only full-size ops are popcounts
     and per-axis sums; each requested event then finds its row
-    (``_row_of_rank``, nested scope ``row_find``), its halo cell from the
-    row's record of per-cell cumsums (scope ``record``), its word among
-    the cell's ``_GROUP`` and its bit by prefix compares over the row's
-    words (scope ``word``). Each level is one row gather (the gather rule
-    beside ``_row_find_steps``); the compares run in registers.
+    (``_row_of_rank``, nested scope ``row_find``), its candidate lane tile
+    from the row's record of per-tile cumsums (scope ``record``), its word
+    among the tile's ``_GROUP`` and its bit by prefix compares over the
+    row's words (scope ``word``). Each level is one row gather (the gather
+    rule beside ``_row_find_steps``); the compares run in registers.
 
-    Candidate c of entity i maps to halo cell c // LANES (row-major 3x3) and
-    lane c % LANES. Returns (pairs i32[max_events, 2], row_counts' total) —
-    paging resumes at start_rank + max_events.
+    A row holds 9 * T candidate tiles for cells T lane tiles deep
+    (``p.lane_tiles``): candidate c of entity i maps to halo cell
+    c // (T * LANES) (row-major 3x3) and lane c % (T * LANES) of it, so
+    tile g is tile g % T of halo cell g // T. Returns (pairs
+    i32[max_events, 2], row_counts' total) — paging resumes at
+    start_rank + max_events.
 
     ``gx_ext``/``wrap_x`` generalize the candidate-cell arithmetic to a
     STRIP-LOCAL slab (parallel/spatial.py): ``cx`` is then the local slab
@@ -879,9 +970,10 @@ def _drain_bits(
         n = p.capacity
         gxl = p.grid_x if gx_ext is None else gx_ext
         n_rows, nw = packed_e.shape
-        ng = nw // _GROUP  # the 9 halo cells
+        tiles = p.lane_tiles
+        ng = nw // _GROUP  # the 9 * T candidate tiles
         pc = jax.lax.population_count(packed_e)  # [N, W]
-        cell_cum = jnp.cumsum(  # [N, 9] inclusive set bits per halo cell
+        cell_cum = jnp.cumsum(  # [N, 9T] inclusive set bits per tile
             jnp.sum(pc.reshape(n_rows, ng, _GROUP), axis=2), axis=1)
         row_counts = cell_cum[:, -1]  # [N]
         row_cum = jnp.cumsum(row_counts)  # inclusive
@@ -893,12 +985,12 @@ def _drain_bits(
         row = _row_of_rank(row_counts, row_cum, row_starts, start_rank,
                            max_events)
 
-        # A rank's halo cell is the count of the row's inclusive per-cell
-        # cumsums at or below it, and the largest of those is the cell's
-        # start; the same holds for its word among the cell's. Ranks past
-        # the total clip to the last cell, word and bit (masked).
+        # A rank's tile is the count of the row's inclusive per-tile
+        # cumsums at or below it, and the largest of those is the tile's
+        # start; the same holds for its word among the tile's. Ranks past
+        # the total clip to the last tile, word and bit (masked).
         with jax.named_scope("record"):
-            record = jnp.concatenate(  # i32[N, 4 + 9]
+            record = jnp.concatenate(  # i32[N, 4 + 9T]
                 [jnp.stack([row_starts, cz, cx, sm], axis=1), cell_cum],
                 axis=1)[row]
             k = j - record[:, 0]  # event rank within its row
@@ -924,7 +1016,10 @@ def _drain_bits(
         b = jnp.sum((bcum <= kk[:, None]).astype(jnp.int32), axis=1)
         b = jnp.minimum(b, _PACK - 1)
 
-        lane = wi * _PACK + b  # a group's words are its halo cell's lanes
+        lane = wi * _PACK + b  # a group's words are its tile's lanes
+        if tiles > 1:
+            lane = (g % tiles) * LANES + lane
+            g = g // tiles  # the halo cell
         dzo = g // 3 - 1
         dxo = g % 3 - 1
         czz = jnp.mod(record[:, 1] + dzo, p.grid_z)
@@ -933,7 +1028,7 @@ def _drain_bits(
         else:
             cxx = record[:, 2] + dxo  # strip slab: ghost columns are physical
         bucket = (record[:, 3] * p.grid_z + czz) * gxl + cxx
-        other = table[bucket * LANES + lane]
+        other = table[bucket * (tiles * LANES) + lane]
         ent = jnp.where(valid, row, n)
         other = jnp.where(valid, other, n)
         return jnp.stack([ent, other], axis=1), total
@@ -971,8 +1066,8 @@ def _step_pallas(
         cxc, czc, smc = _bins(p, pos, spc)
         cxp, czp, smp = pcx, pcz, psm
         buc_c = (smc * p.grid_z + czc) * p.grid_x + cxc
-        table_c, slot_c, dropped_c, order_c, dst_c = _build_table(
-            p, buc_c, act, LANES
+        table_c, slot_c, dropped_c, order_c, dst_c, occ_c = _build_table(
+            p, buc_c, act, p.cell_lanes
         )
         table_p, slot_p = ptable, pslot
 
@@ -996,7 +1091,7 @@ def _step_pallas(
         fast = _fast_guard(p, ppos, pact, pspc, prad, pos, act, spc,
                            dropped_c)
 
-    w_words = 9 * LANES // _PACK
+    w_words = 9 * p.cell_lanes // _PACK
 
     def per_entity(packed_cells, slot):
         with jax.named_scope("aoi.gather"):
@@ -1040,7 +1135,7 @@ def _step_pallas(
         header = jnp.stack(
             [
                 jnp.stack([n_enters, n_leaves]),
-                jnp.stack([dropped_c, zero]),
+                jnp.stack([dropped_c, occ_c]),
                 jnp.stack([zero, zero]),
             ]
         ).astype(jnp.int32)
@@ -1365,6 +1460,20 @@ def start_host_copy(arr: jax.Array) -> None:
         _async_copy_supported[platform] = False
 
 
+def _fill_pairs(head: np.ndarray, total: int, chunks) -> np.ndarray:
+    """``head`` then every chunk, in one array of ``total`` pairs, written
+    chunk by chunk as each lands: the copies, which fault in fresh host
+    pages, run while the device drains the pages after."""
+    out = np.empty((total, 2), np.int32)
+    out[:len(head)] = head
+    at = len(head)
+    for chunk in chunks:
+        out[at:at + len(chunk)] = chunk
+        at += len(chunk)
+    assert at == total, (at, total)
+    return out
+
+
 class PendingStep:
     """An in-flight tick: dispatched to the device, result not yet fetched.
 
@@ -1380,7 +1489,7 @@ class PendingStep:
 
     def __init__(self, engine: "NeighborEngine", pager, out) -> None:
         self._engine = engine
-        self._pager = pager  # pager(which, remaining, start_flat) -> pairs
+        self._pager = pager  # pager(which, remaining, start) -> chunks
         self._out = out
         self._collected = False
         # Fused-tick payload, set by the dispatching caller when the step
@@ -1433,23 +1542,30 @@ class PendingStep:
             out = np.asarray(self._out)  # THE round trip
             n_e, n_l = int(out[0, 0]), int(out[0, 1])
             dropped = int(out[1, 0])
+            _M_OCCUPANCY.set(int(out[1, 1]))
             enter_last, leave_last = int(out[2, 0]), int(out[2, 1])
             enters = out[3:3 + min(n_e, e)]
             leaves = out[3 + e:3 + e + min(n_l, e)]
-        # Storm paging (rare): the pallas drain pages by event RANK (resume
-        # at e), the jnp drain by flat matrix index (resume after the last
-        # drained position).
-        rank_paging = eng.backend != "jnp"
-        if n_e > e:
+        # Paging past the inline window: the pallas drain pages by event
+        # RANK (resume at e), the jnp drain by flat matrix index (resume
+        # after the last drained position). Both sides' pages are started
+        # before either is read back.
+        if n_e > e or n_l > e:
+            rank_paging = eng.backend != "jnp"
             with engine_span("page"):
-                more = self._pager(
-                    "enter", n_e - e, e if rank_paging else enter_last + 1)
-            enters = np.concatenate([enters, more])
-        if n_l > e:
-            with engine_span("page"):
-                more = self._pager(
-                    "leave", n_l - e, e if rank_paging else leave_last + 1)
-            leaves = np.concatenate([leaves, more])
+                pages = {}
+                if n_e > e:
+                    pages["enter"] = self._pager(
+                        "enter", n_e - e, e if rank_paging else enter_last + 1)
+                if n_l > e:
+                    pages["leave"] = self._pager(
+                        "leave", n_l - e, e if rank_paging else leave_last + 1)
+                if "enter" in pages:
+                    enters = _fill_pairs(enters, n_e, pages["enter"])
+                if "leave" in pages:
+                    leaves = _fill_pairs(leaves, n_l, pages["leave"])
+            _M_PAGED_SIDE["enter"].inc(max(n_e - e, 0))
+            _M_PAGED_SIDE["leave"].inc(max(n_l - e, 0))
         eng.last_grid_dropped = dropped
         if dropped:
             from goworld_tpu.utils import gwlog
@@ -1490,10 +1606,12 @@ class NeighborEngine:
             backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
         if backend not in ("jnp", "pallas", "pallas_interpret"):
             raise ValueError(f"unknown backend {backend!r}")
-        if backend != "jnp" and params.cell_capacity > LANES:
+        if backend != "jnp" and not pallas_cell_capacity_ok(
+                params.cell_capacity):
             raise ValueError(
-                f"pallas path supports cell_capacity <= {LANES}, "
-                f"got {params.cell_capacity}"
+                f"pallas path supports cell_capacity 1-{LANES} or a "
+                f"multiple of {LANES} up to {MAX_LANE_TILES * LANES}, got "
+                f"{params.cell_capacity}"
             )
         self.params = params
         self.backend = backend
@@ -1520,7 +1638,7 @@ class NeighborEngine:
             # sentinel table, -1 slots, all-dropped dst — exactly what
             # _build_table returns for active=False everywhere; bins and
             # order are irrelevant then.
-            table_size = self.params.num_buckets * LANES
+            table_size = self.params.num_buckets * self.params.cell_lanes
             self._state = self._state + (
                 jnp.zeros((n,), jnp.int32),  # pcx
                 jnp.zeros((n,), jnp.int32),  # pcz
@@ -1539,18 +1657,44 @@ class NeighborEngine:
         assert self._state is not None, "call reset() first"
         return tuple(np.asarray(a) for a in self._state[0:4])
 
-    def _page(self, ctx, remaining: int, start_flat: int) -> np.ndarray:
-        chunks = []
-        start = jnp.int32(start_flat)
-        rank_paging = self.backend != "jnp"
-        while remaining > 0:
-            pairs, aux = self._jit_drain(*ctx, start_flat=start)
-            take = min(self.params.max_events, remaining)
-            chunks.append(np.asarray(pairs[:take]))
-            remaining -= take
-            if remaining > 0:
-                start = start + take if rank_paging else aux[take - 1] + 1
-        return np.concatenate(chunks)
+    def _page(self, ctx, remaining: int, start_flat: int):
+        """Fetch ``remaining`` events from ``start_flat`` on, one drain
+        launch a page; returns an iterator of numpy chunks, the events in
+        order. Rank paging knows every page's start up front, so all its
+        launches queue now, before any readback, and the device drains
+        page after page with no host round trip between them. The jnp
+        drain's next start comes from the page before, so its pages run
+        as the iterator is read. Each page's buffer comes to the host
+        whole and is cut there: a cut on the device would compile one
+        program for each page length. Starts go in as numpy scalars, which
+        ride the launch, where a device scalar is one more launch."""
+        e = self.params.max_events
+        if self.backend != "jnp":
+            pages = [self._jit_drain(*ctx, start_flat=np.int32(start))[0]
+                     for start in range(start_flat, start_flat + remaining, e)]
+            for pairs in pages:
+                start_host_copy(pairs)
+
+            def rank_pages():
+                left = remaining
+                for pairs in pages:
+                    yield np.asarray(pairs)[:left]
+                    left -= e
+
+            return rank_pages()
+
+        def flat_pages():
+            start, left = start_flat, remaining
+            while left > 0:
+                pairs, aux = self._jit_drain(*ctx,
+                                             start_flat=np.int32(start))
+                take = min(e, left)
+                yield np.asarray(pairs)[:take]
+                left -= take
+                if left > 0:
+                    start = int(np.asarray(aux)[take - 1]) + 1
+
+        return flat_pages()
 
     # The batched service may hand this engine a fused-logic payload
     # (aoi/batched.py _build_logic); sharded variants opt in separately.
@@ -1715,7 +1859,7 @@ class NeighborEngine:
         )
         state: tuple = zeros
         if self.backend != "jnp":
-            table_size = self.params.num_buckets * LANES
+            table_size = self.params.num_buckets * self.params.cell_lanes
             state = state + (
                 jnp.zeros((n,), jnp.int32),
                 jnp.zeros((n,), jnp.int32),
@@ -1754,7 +1898,7 @@ class NeighborEngine:
         )
         state: tuple = zeros
         if self.backend != "jnp":
-            table_size = self.params.num_buckets * LANES
+            table_size = self.params.num_buckets * self.params.cell_lanes
             state = state + (
                 jnp.zeros((n,), jnp.int32),
                 jnp.zeros((n,), jnp.int32),

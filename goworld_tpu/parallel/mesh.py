@@ -141,8 +141,8 @@ def _sharded_step(
     buc_c = (smc * p.grid_z + czc) * p.grid_x + cxc
     buc_p = (smp * p.grid_z + czp) * p.grid_x + cxp
     # Replicated table builds (one N-key sort each); identical on all shards.
-    table_c, slot_c, dropped_c, _, _ = _build_table(p, buc_c, act, m)
-    table_p, slot_p, _, _, _ = _build_table(p, buc_p, pact, m)
+    table_c, slot_c, dropped_c, _, _, _ = _build_table(p, buc_c, act, m)
+    table_p, slot_p, _, _, _, _ = _build_table(p, buc_p, pact, m)
     av_c = slot_c >= 0
     av_p = slot_p >= 0
 
@@ -247,7 +247,8 @@ def _sharded_step_pallas(
     def one_grid(xpos, xact, xspc):
         cx, cz, sm = _bins(p, xpos, xspc)
         buc = (sm * p.grid_z + cz) * p.grid_x + cx
-        table, slot, dropped, order, dst = _build_table(p, buc, xact, LANES)
+        table, slot, dropped, order, dst, _ = _build_table(
+            p, buc, xact, LANES)
         return cx, cz, sm, table, slot, dropped, order, dst
 
     cxc, czc, smc, table_c, slot_c, dropped_c, order_c, dst_c = one_grid(
@@ -580,6 +581,11 @@ class ShardedNeighborEngine:
         if params.max_events % n_dev != 0:
             raise ValueError(
                 f"max_events {params.max_events} must be divisible by {n_dev}"
+            )
+        if backend != "jnp" and params.cell_capacity > LANES:
+            raise ValueError(
+                f"pallas path supports cell_capacity <= {LANES}, "
+                f"got {params.cell_capacity}"
             )
         if backend != "jnp" and params.grid_z % n_dev != 0:
             raise ValueError(

@@ -176,6 +176,28 @@ def test_aoi_delivery_knob(cfg, tmp_path):
         read_config.set_config_file(None)
 
 
+@pytest.mark.parametrize("cell_capacity, ok", [
+    (128, True), (256, True), (512, True),
+    (129, False), (200, False), (640, False),
+])
+def test_aoi_cell_capacity_lane_tiles(tmp_path, cell_capacity, ok):
+    """[aoi] cell_capacity is one 128-lane tile's 1-128 entities, or a
+    whole number of tiles up to four."""
+    p = tmp_path / "cells.ini"
+    p.write_text(SAMPLE.replace(
+        "backend = xzlist",
+        f"backend = xzlist\ncell_capacity = {cell_capacity}"))
+    read_config.set_config_file(str(p))
+    try:
+        if ok:
+            assert read_config.get().aoi.cell_capacity == cell_capacity
+        else:
+            with pytest.raises(ValueError, match="cell_capacity"):
+                read_config.get()
+    finally:
+        read_config.set_config_file(None)
+
+
 def test_aoi_fuse_logic_knob(cfg, tmp_path):
     """[aoi] fuse_logic parses (default off) — ISSUE 12."""
     assert cfg.aoi.fuse_logic is False  # default

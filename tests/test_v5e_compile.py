@@ -6,10 +6,10 @@ refuse — interpret-mode tests cannot see that. Nothing runs: these say
 nothing about results or times. The topology is described in a module
 fixture, never at import, so only the worker given this file loads the
 TPU library. Each test compiles one kernel (about a second), asserts the
-kernel is in the executable and prints its ``memory_analysis()``. Two
+kernel is in the executable and prints its ``memory_analysis()``. Three
 compile the whole single-chip step, at a tiny size and at the
-open_world_100k cell's, and read the stage names the profiler's trace
-will carry.
+open_world_100k and crowd_10k cells', and read the stage names the
+profiler's trace will carry.
 """
 
 import os
@@ -40,6 +40,10 @@ SEAMLESS = NeighborParams(capacity=312512, cell_size=300.0, grid_x=134,
 SEAMLESS_STRIP_COLS = 68
 TINY = NeighborParams(capacity=1024, cell_size=100.0, grid_x=8, grid_z=8,
                       space_slots=1, cell_capacity=64, max_events=1024)
+# The benchmark's crowd_10k cell: 12,504 slots, grid 6 of 150-unit cells,
+# each cell 4 lane tiles deep (cell_capacity 512), the game's window.
+CROWD = NeighborParams(capacity=12504, cell_size=150.0, grid_x=6, grid_z=6,
+                       space_slots=1, cell_capacity=512, max_events=65536)
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +74,7 @@ def compile_kernel(fn, *args):
 
 def cells(sharding, rows, cols, planes, dtype, p=HEADLINE):
     return jax.ShapeDtypeStruct(
-        (p.space_slots, rows + 2, cols, planes, LANES), dtype,
+        (p.space_slots, rows + 2, cols, planes, p.cell_lanes), dtype,
         sharding=sharding)
 
 
@@ -79,6 +83,17 @@ def test_event_kernel_headline(one_chip, dual):
     kernel = neighbor._compiled_event_kernel(HEADLINE, False, dual=dual)
     gz, gx = HEADLINE.grid_z, HEADLINE.grid_x
     compile_kernel(kernel, cells(one_chip, gz, gx + 2, _F, jnp.float32))
+
+
+@pytest.mark.parametrize("dual", [False, True], ids=["plain", "dual"])
+def test_event_kernel_deep_cells(one_chip, dual):
+    """Cells of 4 lane tiles: 512 query lanes against 36 candidate tiles
+    a cell, in nine-tile chunks of the one-tile cell's shapes."""
+    assert CROWD.lane_tiles == 4
+    kernel = neighbor._compiled_event_kernel(CROWD, False, dual=dual)
+    gz, gx = CROWD.grid_z, CROWD.grid_x
+    compile_kernel(kernel, cells(one_chip, gz, gx + 2, _F, jnp.float32,
+                                 CROWD))
 
 
 @pytest.mark.parametrize("dual", [False, True], ids=["plain", "dual"])
@@ -151,3 +166,24 @@ def test_step_drain_levels(one_chip):
     text = compiled.as_text()
     for level in ("row_find", "record", "word"):
         assert re.search(rf'op_name="[^"]*/aoi\.drain/{level}/', text), level
+
+
+def test_step_crowd_deep_cells(one_chip):
+    """The whole single-chip step at the crowd_10k cell's shape compiles
+    for the chip with the 4-tile dual kernel in it; its memory analysis
+    prints beside test_step_drain_levels' one-tile open_world_100k step."""
+    p = CROWD
+    n, buckets = p.capacity, p.num_buckets * p.cell_lanes
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    epoch = (arg((n, 2), jnp.float32), arg((n,), jnp.bool_),
+             arg((n,), jnp.int32), arg((n,), jnp.float32))
+    grid = ((arg((n,), jnp.int32),) * 3 + (arg((buckets,), jnp.int32),)
+            + (arg((n,), jnp.int32),) * 3)
+    step = neighbor._jitted_step_packed(p, "pallas")._jitted
+    compiled = step.lower(*epoch, *grid, *epoch).compile()
+    print(compiled.memory_analysis())
+    assert re.search(r"%aoi_event_kernel[.\d]* = s32\[1,6,6,512,576\]",
+                     compiled.as_text())
